@@ -41,6 +41,7 @@ import jax
 from repro.core import hypersense
 from repro.core.encoding import make_perm_base_rows
 from repro.core.sensor_control import ControllerConfig
+from repro.launch.mesh import make_mesh
 from repro.sensing.fleet import FleetRunner
 from repro.sensing.stream import StreamRunner
 
@@ -151,7 +152,7 @@ def run_mesh(reps: int = REPS, check: bool = False):
                            precision="int8")
 
     # sensor-axis sweep on the 8x1 mesh
-    mesh_s = jax.make_mesh((8, 1), ("data", "model"))
+    mesh_s = make_mesh((8, 1), ("data", "model"))
     for S in MESH_SWEEP_S:
         frames = jax.random.uniform(jax.random.PRNGKey(2),
                                     (S, MESH_FRAMES, MESH_FRAME,
@@ -207,7 +208,7 @@ def run_mesh(reps: int = REPS, check: bool = False):
     frames = jax.random.uniform(jax.random.PRNGKey(3),
                                 (MESH_BIG_S, MESH_FRAMES, MESH_BIG_FRAME,
                                  MESH_BIG_FRAME))
-    with shlib.use_mesh(jax.make_mesh((1, 8), ("data", "model"))):
+    with shlib.use_mesh(make_mesh((1, 8), ("data", "model"))):
         dt = _time(lambda: big.process(frames), reps)
         assert big._step_key[2] == ("model",), \
             "D=16384 fleet did not shard the hyperdim axis"

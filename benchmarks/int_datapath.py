@@ -45,12 +45,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas.tpu import CompilerParams
 
 from repro.core import fragment_model as fm, hypersense, metrics
 from repro.core.encoding import apply_nonlinearity, make_perm_base_rows
 from repro.kernels import ops
+from repro.kernels import sliding_scores as k_ss
 from repro.kernels import sliding_scores_int as k_int
-from repro.kernels.compat import CompilerParams
 from repro.sensing import adc, fragments, synthetic
 
 # CPU-tractable scale (interpret mode); chunk >= 8 is the claimed regime.
@@ -155,6 +156,10 @@ def _expanded_scores(codes, slab_mat, tiles, *, h: int, w: int,
     td = geom.block_d
     norms = k_int.window_norms_codes_batch(codes, h, w, stride)
     norms = jnp.maximum(norms, 1e-8) / geom.slab_scale
+    # win_mask[kx, i] = [kx*stride <= i < kx*stride + w]
+    i = jnp.arange(W)[None, :]
+    kx = jnp.arange(mx)[:, None] * stride
+    win_mask = ((i >= kx) & (i < kx + w)).astype(jnp.int8)    # (mx, W)
     kern = functools.partial(_expanded_kernel, h=h, stride=stride, w=w,
                              W=W, mx=mx, td=td, nonlinearity="rff")
     dpos, dneg, qq = pl.pallas_call(
@@ -175,9 +180,9 @@ def _expanded_scores(codes, slab_mat, tiles, *, h: int, w: int,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=True,
-    )(codes, slab_mat, geom.win_mask, geom.bias_t, tiles.cpos_t,
+    )(codes, slab_mat, win_mask, geom.bias_t, tiles.cpos_t,
       tiles.cneg_t, norms)
-    return k_int._cosine_epilogue(dpos, dneg, qq, tiles, False, 0)
+    return k_ss._cosine_epilogue(dpos, dneg, qq, tiles, False, 0)
 
 
 def throughput(n_frames: int = CHUNK, reps: int = 8) -> dict:

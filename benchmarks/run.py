@@ -13,6 +13,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 SUITES = ["table1_auc", "fig12_thresholds", "fig13_stride",
           "fig15_fragsize_dim", "fig16_speedup", "stream_throughput",
           "fleet_throughput", "serve_throughput", "adaptation",
@@ -27,6 +29,7 @@ def main() -> int:
                     help="write each suite's rows as DIR/BENCH_<suite>"
                          ".json in addition to the CSV stdout")
     args = ap.parse_args()
+    enable_compile_cache()
 
     failures = []
     for suite in SUITES:

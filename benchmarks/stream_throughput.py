@@ -7,7 +7,7 @@ frame_detection_score) under three execution strategies:
 * ``jnp-vmap``     — pure-jnp scoring vmapped over the chunk
 * ``seq-kernel``   — the sliding-scores kernel, one launch PER FRAME
   (the pre-batching hot path: O(N) dispatches)
-* ``batch-kernel`` — ONE launch per chunk, grid ``(N, my, n_dt)``,
+* ``batch-kernel`` — ONE launch per chunk, grid ``(N, n_dt)``,
   sharing a single ScoreTiles precompute
 
 On CPU the kernel paths run in Pallas interpret mode, so absolute numbers

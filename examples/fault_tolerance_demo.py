@@ -18,11 +18,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
 from repro.ckpt import checkpoint as ckpt
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.train import loop as train_loop
 
 
 def main() -> None:
+    enable_compile_cache()
     ckpt_dir = tempfile.mkdtemp(prefix="repro_ft_")
     cfg = configs.get_smoke("internlm2-1.8b")
     model = lm.build(cfg)
@@ -48,7 +51,7 @@ def main() -> None:
     # --- phase 3: elastic restore onto a different mesh ---
     from repro.train import optim
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = model.init(jax.random.PRNGKey(0))
     opt = optim.AdamW(lr=1e-3, weight_decay=0.1)
     like = (params, opt.init(params))

@@ -16,6 +16,7 @@ from repro.core import encoding, hypersense
 from repro.core.sensor_control import CaptureConfig, ControllerConfig
 from repro.launch import steps
 from repro.launch.cascade import CascadeService
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sensing import synthetic
 from repro.sensing.stream import StreamRunner
 
@@ -23,6 +24,7 @@ FRAME, CHUNK, BATCH = 32, 16, 8
 
 
 def main() -> None:
+    enable_compile_cache()
     # a tiny gate (untrained weights are fine for the plumbing demo);
     # threshold at the open-loop score q75 so only score peaks fire
     # (closed-loop decimation skips idle frames, thinning high scores)

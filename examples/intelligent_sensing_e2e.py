@@ -25,6 +25,7 @@ from repro.core import energy, fragment_model as fm, hypersense, metrics
 from repro.core.online import AdaptConfig
 from repro.core.sensor_control import (CaptureConfig, ControllerConfig,
                                        decimation, stats_from)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sensing import adc, fragments, synthetic
 from repro.sensing.fleet import simulate_fleet
 from repro.sensing.stream import StreamRunner, simulate_stream_batched
@@ -59,6 +60,7 @@ def train_gate(key, cfg, frag, dim, stride):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sensors", type=int, default=1,
                     help="number of concurrent sensor streams (>1 uses "

@@ -10,10 +10,12 @@ import numpy as np
 from repro.core import fragment_model as fm
 from repro.core import hypersense, metrics
 from repro.core.encoding import encode_fragments
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sensing import adc, fragments, synthetic
 
 
 def main() -> None:
+    enable_compile_cache()
     key = jax.random.PRNGKey(0)
 
     # 1. sense: synthetic radar frames through the low-precision ADC path

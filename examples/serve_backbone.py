@@ -11,11 +11,13 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.decode import greedy_decode
 from repro.models import lm
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = configs.get_smoke("internlm2-1.8b").replace(
         n_layers=4, d_model=128, n_heads=4, kv_heads=2, d_ff=512)
     model = lm.build(cfg)

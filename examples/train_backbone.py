@@ -9,11 +9,13 @@ Run:  PYTHONPATH=src python examples/train_backbone.py [--steps 200]
 import argparse
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import common, lm
 from repro.train import loop as train_loop
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)

@@ -6,5 +6,4 @@
 * :mod:`repro.kernels.similarity`     — fused cosine classifier
 * :mod:`repro.kernels.ops`            — jit'd public wrappers
 * :mod:`repro.kernels.ref`            — pure-jnp oracles for all of the above
-* :mod:`repro.kernels.compat`         — jax version-compat shims
 """

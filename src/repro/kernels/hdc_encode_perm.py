@@ -30,7 +30,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.encoding import SHIFT, NonLin
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas.tpu import CompilerParams
 
 
 def _kernel(x_ref, b0p_ref, bias_ref, o_ref, acc_ref, btile_ref, *,

@@ -1,8 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Auto-selects ``interpret=True`` on non-TPU backends so the same call sites
-work on CPU (validation) and TPU (deployment). Also hosts the per-model
-precompute cache used by the HyperSense scoring hot path.
+The kernels run compiled on a TPU and in Pallas interpret mode on the CPU
+backend (where the tests run); on any other backend the wrappers raise
+rather than hide the device behind the interpreter.
 """
 
 from __future__ import annotations
@@ -20,7 +20,14 @@ Array = jax.Array
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on TPU, interpreted on CPU; any other backend raises."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"the Pallas kernels target TPU (and run interpreted "
+                       f"on CPU for tests); backend {backend!r} has neither")
 
 
 def hdc_encode(x: Array, B: Array, b: Array, *,

@@ -5,13 +5,17 @@ One kernel maps a sensor frame directly to the fragment score map:
 
   frame (H, W)  ->  scores-ingredients (my, mx) x 3
 
-fusing, per grid cell:
+fusing, per grid cell (one frame x one hyperdimension tile):
 
-  1. *rolled products + prefix sum* — each input element is multiplied with
-     base-hypervector material exactly once per base row (the paper's
-     computation reuse; the systolic FIFO becomes a running sum),
-  2. *window differences* — every fragment's projection is
-     ``P[kx+w] - P[kx]`` (the reuse of overlapping fragments),
+  1. *projection reuse on the MXU* — for each row band ``ky`` the band's
+     ``h`` rows are contracted against the circularly padded base slab in
+     ONE matmul, ``P[i, q] = sum_r x[r, i] * slab[r, q]``: every input
+     element meets the base material exactly once per base row (the
+     paper's computation reuse). Rolling row ``i`` left by ``i``
+     (:func:`_rows_to_diagonals`) turns ``P`` into the per-column rolled
+     products ``G[i, j] = P[i, i + j]``.
+  2. *window sums* — every fragment's projection is the sum of the ``w``
+     rows of ``G`` its window covers (the reuse of overlapping fragments).
   3. *normalization + RFF nonlinearity* — in the *unrolled* orientation:
      instead of cyclically rotating every (mx, D) projection back (the
      naive inverse of the permutation trick), the per-column *bias* and
@@ -20,16 +24,25 @@ fusing, per grid cell:
      replaces an (mx, D) data rotation per frame — a beyond-paper
      optimization available because similarity is permutation-invariant.
   4. *classifier dot products* — positive/negative class dots and the query
-     sum-of-squares accumulate across D tiles; the cosine epilogue runs
-     host-side on the tiny (my, mx) outputs.
+     sum-of-squares per D tile; the tiles fold and the cosine epilogue
+     runs outside the kernel on the tiny (my, mx) outputs.
 
-Grid: ``(N, my, n_dt)`` — frames and fragment rows parallel, hyperdimension
-tiles as the sequential reduction. The batch axis is the streaming hot path:
-one ``pallas_call`` scores a whole chunk of frames against a single
-:class:`ScoreTiles` precompute (slabs/bias/class tiles are per-model, not
-per-frame), replacing O(N) kernel launches with one. VMEM per step: frame
-(H, W) + slab (h, TD+W) + bias/class tiles (mx, TD) + P scratch (W+1, TD) +
-acc (mx, TD) — independent of N.
+The same kernel body serves the integer datapath
+(:mod:`repro.kernels.sliding_scores_int`): integer codes take the int8
+MXU path of :func:`_project` with exact int32 accumulation, float frames
+the f32 one.
+
+Grid: ``(N, n_dt)`` — frames and hyperdimension tiles, both parallel;
+the row bands loop inside the kernel, so the frame block is fetched once
+per frame. The batch axis is the streaming hot path: one ``pallas_call``
+scores a whole chunk of frames against a single :class:`ScoreTiles`
+precompute (slabs/bias/class tiles are per-model, not per-frame). VMEM per
+step: frame (H, W) + slab (h, TD+W-1) + bias/class tiles (mx, TD) + the
+``(W_CHUNK, TD+W_CHUNK)`` projection of one band chunk — independent of N.
+
+A hypervector dimensionality the tile width does not divide (the paper's
+D=5000) is padded up to whole lane-aligned tiles; the padded tail's
+``valid`` mask zeroes its contribution, so scores are those of the true D.
 
 ``fragment_scores`` (single frame) is a batch-of-1 call into the same
 kernel; ``fragment_scores_batch`` is the chunked entry point used by
@@ -71,9 +84,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.encoding import SHIFT, NonLin, apply_nonlinearity
-from repro.kernels.compat import CompilerParams
 
 Array = jax.Array
+
+#: static W-axis chunk of the projection: bounds the per-band product
+#: ``(W_CHUNK, TD + W_CHUNK - 1)`` independent of the frame width
+W_CHUNK = 128
+
+#: lane width of a TPU vector register: padded tile widths are multiples
+_LANES = 128
 
 
 @jax.tree_util.register_dataclass
@@ -89,6 +108,7 @@ class ScoreGeometry:
     slabs: Array      # (n_dt, h, TD + W - 1) circularly padded base rows
     bias_t: Array     # (n_dt, mx, TD) pre-rotated RFF bias tiles
     idx: Array        # (n_dt, mx, TD) i32 rotation gather into a (D,) vector
+    valid: Array      # (n_dt, 1, TD) f32: 1 on real components, 0 on padding
     block_d: int = dataclasses.field(metadata={"static": True})
     w: int = dataclasses.field(metadata={"static": True})
     stride: int = dataclasses.field(metadata={"static": True})
@@ -131,34 +151,51 @@ class ScoreTiles:
         return self.geom.stride
 
 
+def tile_layout(dim: int, block_d: int) -> tuple[int, int]:
+    """``(TD, n_dt)``: tile width and tile count covering ``dim``.
+
+    ``block_d`` tiles when it divides ``dim``; otherwise ``dim`` is padded
+    up to whole tiles of ``min(block_d, dim rounded up to a lane
+    multiple)`` — never one ``dim``-wide tile, whose width the TPU's
+    128-lane registers cannot tile and whose slab outgrows VMEM.
+    """
+    if dim % block_d == 0:
+        return block_d, dim // block_d
+    td = min(block_d, -(-dim // _LANES) * _LANES)
+    return td, -(-dim // td)
+
+
 def precompute_geometry(B0: Array, b: Array, *, W: int, w: int, stride: int,
                         block_d: int = 512) -> ScoreGeometry:
     """Host-side, once per (model-geometry, frame-width): slabs + bias + idx.
 
     The expensive precompute. Everything class-dependent is deferred to
     :func:`retile_classes` so the classifier can change without re-running
-    this.
+    this. Padded components (``dim`` not a multiple of the tile) read
+    real, cyclically wrapped values and are zeroed by ``valid``.
     """
     h, dim = B0.shape
     assert SHIFT == -1, "precompute assumes the paper's left-shift"
-    td = block_d if dim % block_d == 0 else dim
-    n_dt = dim // td
+    td, n_dt = tile_layout(dim, block_d)
     mx = (W - w) // stride + 1
 
-    pad = td + W - 1
-    B0P = jnp.concatenate([B0, B0[:, :pad]], axis=1)
-    slabs = jnp.stack([B0P[:, dt * td: dt * td + pad]
-                       for dt in range(n_dt)])               # (n_dt,h,TD+W-1)
+    # slab column q of tile dt is base column (dt*TD + q) % D: the cyclic
+    # shift every fragment column needs, wrapped past the end of B0
+    cols = (jnp.arange(n_dt)[:, None] * td
+            + jnp.arange(td + W - 1)[None, :]) % dim
+    slabs = jnp.moveaxis(B0[:, cols], 1, 0)                 # (n_dt,h,TD+W-1)
 
     # idx[dt, kx, j] = (dt*TD + j + kx*stride) % D   (rotation by fragment col)
     dts = jnp.arange(n_dt)[:, None, None] * td
     kxs = jnp.arange(mx)[None, :, None] * stride
     js = jnp.arange(td)[None, None, :]
     idx = (dts + js + kxs) % dim                            # (n_dt, mx, TD)
+    comp = jnp.arange(n_dt * td).reshape(n_dt, 1, td)
     return ScoreGeometry(
         slabs=slabs.astype(jnp.float32),
         bias_t=b[idx].astype(jnp.float32),
         idx=idx,
+        valid=(comp < dim).astype(jnp.float32),
         block_d=td,
         w=w,
         stride=stride,
@@ -241,58 +278,143 @@ def window_norms_batch(frames: Array, h: int, w: int, stride: int) -> Array:
     return jax.vmap(lambda f: window_norms(f, h, w, stride))(frames)
 
 
-def _score_kernel(frame_ref, slab_ref, bias_ref, cpos_ref, cneg_ref,
-                  norm_ref, dpos_ref, dneg_ref, qq_ref, p_ref, acc_ref, *,
-                  h: int, w: int, stride: int, W: int, mx: int, td: int,
-                  n_dt: int, nonlinearity: NonLin):
-    ky = pl.program_id(1)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+def _project(cols: Array, slab: Array, *, planes: int) -> Array:
+    """``P[l, q] = sum_r cols[r, l] * slab[r, q]`` — one MXU contraction.
 
-    def row_body(r, _):
-        row = frame_ref[0, pl.ds(ky * stride + r, 1), :]     # (1, W)
-        row = row.astype(jnp.float32)
-        slab = slab_ref[0, pl.ds(r, 1), :][0]
-        slab = slab.astype(jnp.float32)                      # (TD + W - 1,)
+    ``planes == 0``: float operands, f32 accumulation at full precision.
+    ``planes >= 1``: non-negative integer codes, split into ``planes``
+    bytes. The MXU multiplies int8, so each byte is offset by -128 and the
+    offset is added back as ``128 * colsum(slab)`` — exact int32
+    arithmetic, whatever the byte values.
+    """
+    dims = (((0,), (0,)), ((), ()))
+    if planes == 0:
+        return jax.lax.dot_general(cols, slab, dims,
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=jnp.float32)
+    offset = 128 * jnp.sum(slab.astype(jnp.int32), axis=0, keepdims=True)
+    out = None
+    for k in range(planes):
+        byte = cols if planes == 1 else jnp.right_shift(cols, 8 * k) & 0xFF
+        part = jax.lax.dot_general((byte - 128).astype(jnp.int8), slab, dims,
+                                   preferred_element_type=jnp.int32) + offset
+        out = part if out is None else out + jnp.left_shift(part, 8 * k)
+    return out
 
-        # prefix sum of rolled products (the computation reuse)
-        p_ref[pl.ds(0, 1), :] = jnp.zeros((1, td), jnp.float32)
 
-        def i_body(i, running):
-            seg = jax.lax.dynamic_slice(slab, (i,), (td,))
-            x_i = jax.lax.dynamic_slice(row, (0, i), (1, 1))[0, 0]
-            running = running + x_i * seg
-            p_ref[pl.ds(i + 1, 1), :] = running[None, :]
-            return running
+def _rows_to_diagonals(p: Array, shift: Array, *, max_shift: int,
+                       td: int) -> Array:
+    """``g[l, j] = p[l, shift[l] + j]`` for ``j < td``, by rolling.
 
-        jax.lax.fori_loop(0, W, i_body, jnp.zeros((td,), jnp.float32))
+    One roll+select pass per bit of ``max_shift`` aligns row ``l`` left by
+    ``shift[l]`` (composition of circular rolls is the roll by the sum);
+    ``shift[l] + j <= max_shift + td - 1 < p.shape[1]``, so no wrapped
+    element is ever kept.
+    """
+    bit = 1
+    while bit <= max_shift:
+        rolled = jnp.concatenate([p[:, bit:], p[:, :bit]], axis=1)
+        p = jnp.where((shift & bit) != 0, rolled, p)
+        bit *= 2
+    return p[:, :td]
 
-        # window differences: every fragment reuses the shared prefix sum
-        def k_body(kx, _):
-            lo = p_ref[pl.ds(kx * stride, 1), :]
-            hi = p_ref[pl.ds(kx * stride + w, 1), :]
-            acc_ref[pl.ds(kx, 1), :] = acc_ref[pl.ds(kx, 1), :] + hi - lo
-            return 0
 
-        jax.lax.fori_loop(0, mx, k_body, 0)
-        return 0
+def _window_sums(g: Array, col: Array, *, first: int, last: int, w: int,
+                 stride: int, mx: int) -> Array:
+    """``(mx, TD)``: per fragment column, the sum of the rows of ``g``
+    whose frame column ``col`` lies in its window. Windows that miss the
+    static column range ``[first, last]`` of ``g`` contribute zeros."""
+    rows = []
+    for kx in range(mx):
+        lo = kx * stride
+        if lo + w <= first or lo > last:
+            rows.append(jnp.zeros((1, g.shape[1]), g.dtype))
+            continue
+        inside = (col >= lo) & (col < lo + w)
+        rows.append(jnp.sum(jnp.where(inside, g, 0), axis=0, keepdims=True))
+    return jnp.concatenate(rows, axis=0)
 
-    jax.lax.fori_loop(0, h, row_body, 0)
 
-    # normalization + nonlinearity + classifier dots (unrolled orientation)
-    # — the nonlinearity is the ONE definition in repro.core.encoding,
-    # shared with the int kernel and both jnp oracles (identical
-    # expression, so this path stays bitwise-frozen)
-    norms = norm_ref[0].astype(jnp.float32)                  # (1, mx)
-    s_n = acc_ref[...] / jnp.maximum(norms[0][:, None], 1e-8)
-    phi = apply_nonlinearity(s_n, bias_ref[0], nonlinearity)
-    # Per-tile partial sums, one (1, 1, 1, mx) output block per D-tile.
-    # The tiles are reduced OUTSIDE the kernel by _ordered_tile_fold so the
+def _window_acc(band: Array, slabs: Array, *, W: int, td: int, w: int,
+                stride: int, mx: int, packed: bool = False) -> Array:
+    """One row band ``(h, W)`` -> its ``(mx, TD)`` fragment projections.
+
+    The paper's computation reuse with an O(window) live set: summing over
+    base rows commutes with shift extraction, so ONE matmul per band chunk
+    (:func:`_project`) multiplies each input element once per base row,
+    :func:`_rows_to_diagonals` turns it into the per-column rolled sums,
+    and :func:`_window_sums` aggregates every fragment. The ``W`` axis is
+    chunked statically (:data:`W_CHUNK`) so the scratch stays bounded.
+    Float bands accumulate in f32; integer codes (``< 2**16``; uint8 in
+    one byte plane) exactly in int32. ``packed`` bands are the int4 wire
+    format ``(h, W/2)``: low nibbles are the even columns, high nibbles
+    the odd ones, projected separately — nothing is interleaved.
+    """
+    if jnp.issubdtype(band.dtype, jnp.integer):
+        planes = 1 if band.dtype.itemsize == 1 else 2
+        band = band.astype(jnp.int32)
+    else:
+        planes = 0
+    acc = None
+    for c0 in range(0, W, W_CHUNK):
+        cw = min(W_CHUNK, W - c0)
+        slab = slabs[:, c0:c0 + td + cw - 1]
+        # (first column offset, column step, byte planes) per column group
+        groups = ((0, 2, 1), (1, 2, 1)) if packed else ((0, 1, planes),)
+        for first, step, n_planes in groups:
+            if packed:
+                byte = band[:, c0 // 2:(c0 + cw) // 2]
+                cols = byte & 0xF if first == 0 else jnp.right_shift(byte, 4)
+            else:
+                cols = band[:, c0:c0 + cw]
+            p = _project(cols, slab, planes=n_planes)
+            last = first + step * (cols.shape[1] - 1)
+            shift = first + step * jax.lax.broadcasted_iota(
+                jnp.int32, p.shape, 0)
+            g = _rows_to_diagonals(p, shift, max_shift=last, td=td)
+            col = c0 + first + step * jax.lax.broadcasted_iota(
+                jnp.int32, g.shape, 0)
+            part = _window_sums(g, col, first=c0 + first, last=c0 + last,
+                                w=w, stride=stride, mx=mx)
+            acc = part if acc is None else acc + part
+    return acc
+
+
+def _score_kernel(x_ref, slab_ref, bias_ref, valid_ref, cpos_ref, cneg_ref,
+                  norm_ref, dpos_ref, dneg_ref, qq_ref, *, h: int, w: int,
+                  stride: int, W: int, my: int, mx: int, td: int,
+                  nonlinearity: NonLin, packed: bool):
+    slabs = slab_ref[0]                                      # (h, TD+W-1)
+    bias = bias_ref[0]                                       # (mx, TD)
+    valid = valid_ref[0]                                     # (1, TD)
+    cpos = cpos_ref[0].astype(jnp.float32)
+    cneg = cneg_ref[0].astype(jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (my, mx), 0)
+
+    def band(ky, outs):
+        x = x_ref[0, pl.ds(ky * stride, h), :]               # (h, W[/2])
+        acc = _window_acc(x, slabs, W=W, td=td, w=w, stride=stride, mx=mx,
+                          packed=packed)                     # (mx, TD)
+        norms = norm_ref[0, pl.ds(ky, 1), :]                 # (1, mx)
+        s_n = acc.astype(jnp.float32) / norms.T
+        # the ONE nonlinearity definition (repro.core.encoding), shared
+        # with the jnp oracles; padded components are zeroed by `valid`
+        phi = apply_nonlinearity(s_n, bias, nonlinearity) * valid
+        sums = (jnp.sum(phi * cpos, axis=1), jnp.sum(phi * cneg, axis=1),
+                jnp.sum(phi * phi, axis=1))
+        return tuple(jnp.where(row == ky, v[None, :], o)
+                     for v, o in zip(sums, outs))
+
+    zero = jnp.zeros((my, mx), jnp.float32)
+    dpos, dneg, qq = jax.lax.fori_loop(0, my, band, (zero, zero, zero))
+    # Per-tile partial sums, one (my, mx) block per (D-tile, frame). The
+    # tiles are reduced OUTSIDE the kernel by _ordered_tile_fold so the
     # combine order is a fixed left-to-right fold regardless of how the
     # n_dt axis is sharded across devices — the basis of the bitwise
     # sharded == unsharded guarantee (see fragment_scores_batch).
-    dpos_ref[...] = jnp.sum(phi * cpos_ref[0], axis=1)[None, None, None, :]
-    dneg_ref[...] = jnp.sum(phi * cneg_ref[0], axis=1)[None, None, None, :]
-    qq_ref[...] = jnp.sum(phi * phi, axis=1)[None, None, None, :]
+    dpos_ref[0, 0] = dpos
+    dneg_ref[0, 0] = dneg
+    qq_ref[0, 0] = qq
 
 
 def _ordered_tile_fold(parts: Array,
@@ -315,6 +437,89 @@ def _ordered_tile_fold(parts: Array,
     return out
 
 
+def _cosine_epilogue(dpos, dneg, qq, tiles, per_stream: bool, C: int):
+    """Folded dots -> ``sim(pos) - sim(neg)``; per-stream class norms
+    broadcast over that stream's ``C`` frames."""
+    qn = jnp.maximum(jnp.sqrt(qq), 1e-9)
+    if per_stream:
+        rep = lambda v: jnp.repeat(v, C)[:, None, None]       # (N, 1, 1)
+        return (dpos / (qn * jnp.maximum(rep(tiles.cpos_norm), 1e-9))
+                - dneg / (qn * jnp.maximum(rep(tiles.cneg_norm), 1e-9)))
+    return (dpos / (qn * jnp.maximum(tiles.cpos_norm, 1e-9))
+            - dneg / (qn * jnp.maximum(tiles.cneg_norm, 1e-9)))
+
+
+def scores_from_tiles(x: Array, slabs: Array, tiles, norms: Array, *,
+                      h: int, w: int, stride: int, W: int,
+                      nonlinearity: NonLin, interpret: bool,
+                      frames_per_stream: int | None, packed: bool,
+                      hyperdim_axes: tuple[str, ...] | None) -> Array:
+    """The one ``pallas_call`` behind the float and integer entry points.
+
+    ``x`` is ``(N, H, W)`` frames or integer codes (``(N, H, W/2)`` bytes
+    when ``packed``), ``slabs`` the geometry's (float or int8) slabs,
+    ``norms`` the ``(N, my, mx)`` window norms the projections divide by.
+    """
+    geom = tiles.geom
+    N, H, Wx = x.shape
+    n_dt, h_b, slab_len = slabs.shape
+    td = geom.block_d
+    my, mx = norms.shape[1:]
+    # repro-lint: disable=RA001 (td is a static aux field of the tile pytree — concrete at trace time)
+    assert h_b == h and slab_len == td + W - 1, (slabs.shape, td, W)
+
+    per_stream = tiles.cpos_t.ndim == 4
+    C = 0
+    if per_stream:
+        if frames_per_stream is None:
+            raise ValueError("per-stream class tiles need frames_per_stream")
+        C = frames_per_stream
+        S = tiles.cpos_t.shape[0]
+        if S * C != N:
+            raise ValueError(f"per-stream tiles: S={S} streams x "
+                             f"C={C} frames != batch N={N}")
+        # (S, n_dt, mx, td) -> (S*n_dt, mx, td): batch n reads stream n//C.
+        cpos_t = tiles.cpos_t.reshape(S * n_dt, mx, td)
+        cneg_t = tiles.cneg_t.reshape(S * n_dt, mx, td)
+        class_spec = pl.BlockSpec(
+            (1, mx, td), lambda n, j: ((n // C) * n_dt + j, 0, 0))
+    else:
+        cpos_t, cneg_t = tiles.cpos_t, tiles.cneg_t
+        class_spec = pl.BlockSpec((1, mx, td), lambda n, j: (j, 0, 0))
+
+    kern = functools.partial(
+        _score_kernel, h=h, w=w, stride=stride, W=W, my=my, mx=mx, td=td,
+        nonlinearity=nonlinearity, packed=packed)
+    tile = lambda n, j: (j, 0, 0)
+    dpos, dneg, qq = pl.pallas_call(
+        kern,
+        grid=(N, n_dt),
+        in_specs=[
+            pl.BlockSpec((1, H, Wx), lambda n, j: (n, 0, 0)),      # frame
+            pl.BlockSpec((1, h, slab_len), tile),                  # slabs
+            pl.BlockSpec((1, mx, td), tile),                       # bias
+            pl.BlockSpec((1, 1, td), tile),                        # valid
+            class_spec,                                            # cpos
+            class_spec,                                            # cneg
+            pl.BlockSpec((1, my, mx), lambda n, j: (n, 0, 0)),     # norms
+        ],
+        out_specs=[pl.BlockSpec((1, 1, my, mx),
+                                lambda n, j: (j, n, 0, 0))] * 3,
+        out_shape=[jax.ShapeDtypeStruct((n_dt, N, my, mx),
+                                        jnp.float32)] * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+        ),
+        interpret=interpret,
+        name="hypersense_scores",
+    )(x, slabs, geom.bias_t, geom.valid, cpos_t, cneg_t, norms)
+
+    dpos = _ordered_tile_fold(dpos, hyperdim_axes)
+    dneg = _ordered_tile_fold(dneg, hyperdim_axes)
+    qq = _ordered_tile_fold(qq, hyperdim_axes)
+    return _cosine_epilogue(dpos, dneg, qq, tiles, per_stream, C)
+
+
 @functools.partial(jax.jit, static_argnames=("h", "w", "stride",
                                              "nonlinearity", "interpret",
                                              "frames_per_stream",
@@ -329,9 +534,9 @@ def fragment_scores_batch(frames: Array, tiles: ScoreTiles, *, h: int,
     """(N, H, W) frames -> (N, my, mx) score maps in one kernel launch.
 
     The whole batch shares one :class:`ScoreGeometry` precompute; the
-    Pallas grid is ``(N, my, n_dt)`` with the batch/row axes parallel.
-    Each D-tile emits its own partial dot products; the tiles are folded
-    outside the kernel in fixed left-to-right order (bitwise-stable).
+    Pallas grid is ``(N, n_dt)``, both axes parallel. Each D-tile emits
+    its own partial dot products; the tiles are folded outside the kernel
+    in fixed left-to-right order (bitwise-stable).
 
     Inside a ``shard_map`` whose mesh partitions the tile axis over
     ``hyperdim_axes``, pass those axis names: ``tiles`` then holds this
@@ -348,79 +553,14 @@ def fragment_scores_batch(frames: Array, tiles: ScoreTiles, *, h: int,
     class tiles via the BlockSpec index map — same grid, same kernel body,
     still ONE launch. That is the fleet's per-stream online-learning path.
     """
-    N, H, W = frames.shape
-    my = (H - h) // stride + 1
-    mx = (W - w) // stride + 1
-    n_dt, h_b, slab_len = tiles.slabs.shape
-    td = tiles.block_d
-    # repro-lint: disable=RA001 (td/tiles.w/tiles.stride are static aux fields of the tile pytree — concrete at trace time)
-    assert h_b == h and slab_len == td + W - 1, (tiles.slabs.shape, td, W)
-    assert tiles.w == w and tiles.stride == stride  # repro-lint: disable=RA001 (same static aux fields)
-
-    per_stream = tiles.cpos_t.ndim == 4
-    if per_stream:
-        if frames_per_stream is None:
-            raise ValueError("per-stream class tiles need frames_per_stream")
-        C = frames_per_stream
-        S = tiles.cpos_t.shape[0]
-        if S * C != N:
-            raise ValueError(f"per-stream tiles: S={S} streams x "
-                             f"C={C} frames != batch N={N}")
-        # (S, n_dt, mx, td) -> (S*n_dt, mx, td): batch n reads stream n//C.
-        cpos_t = tiles.cpos_t.reshape(S * n_dt, mx, td)
-        cneg_t = tiles.cneg_t.reshape(S * n_dt, mx, td)
-        class_spec = pl.BlockSpec(
-            (1, mx, td), lambda n, i, j: ((n // C) * n_dt + j, 0, 0))
-    else:
-        cpos_t, cneg_t = tiles.cpos_t, tiles.cneg_t
-        class_spec = pl.BlockSpec((1, mx, td), lambda n, i, j: (j, 0, 0))
-
-    norms = window_norms_batch(frames, h, w, stride)         # (N, my, mx)
-
-    kern = functools.partial(
-        _score_kernel, h=h, w=w, stride=stride, W=W, mx=mx, td=td,
-        n_dt=n_dt, nonlinearity=nonlinearity)
-
-    dpos, dneg, qq = pl.pallas_call(
-        kern,
-        grid=(N, my, n_dt),
-        in_specs=[
-            pl.BlockSpec((1, H, W), lambda n, i, j: (n, 0, 0)),    # frame
-            pl.BlockSpec((1, h, slab_len), lambda n, i, j: (j, 0, 0)),
-            pl.BlockSpec((1, mx, td), lambda n, i, j: (j, 0, 0)),  # bias
-            class_spec,                                            # cpos
-            class_spec,                                            # cneg
-            pl.BlockSpec((1, 1, mx), lambda n, i, j: (n, i, 0)),   # norms
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, mx), lambda n, i, j: (j, n, i, 0)),
-            pl.BlockSpec((1, 1, 1, mx), lambda n, i, j: (j, n, i, 0)),
-            pl.BlockSpec((1, 1, 1, mx), lambda n, i, j: (j, n, i, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((n_dt, N, my, mx),
-                                        jnp.float32)] * 3,
-        scratch_shapes=[
-            pltpu.VMEM((W + 1, td), jnp.float32),
-            pltpu.VMEM((mx, td), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"),
-        ),
-        interpret=interpret,
-    )(frames, tiles.slabs, tiles.bias_t, cpos_t, cneg_t, norms)
-
-    dpos = _ordered_tile_fold(dpos, hyperdim_axes)
-    dneg = _ordered_tile_fold(dneg, hyperdim_axes)
-    qq = _ordered_tile_fold(qq, hyperdim_axes)
-
-    qn = jnp.maximum(jnp.sqrt(qq), 1e-9)
-    if per_stream:
-        # per-stream classifier norms broadcast over that stream's frames
-        rep = lambda v: jnp.repeat(v, C)[:, None, None]       # (N, 1, 1)
-        return (dpos / (qn * jnp.maximum(rep(tiles.cpos_norm), 1e-9))
-                - dneg / (qn * jnp.maximum(rep(tiles.cneg_norm), 1e-9)))
-    return (dpos / (qn * jnp.maximum(tiles.cpos_norm, 1e-9))
-            - dneg / (qn * jnp.maximum(tiles.cneg_norm, 1e-9)))
+    W = frames.shape[-1]
+    assert tiles.w == w and tiles.stride == stride  # repro-lint: disable=RA001 (static aux fields of the tile pytree)
+    norms = jnp.maximum(window_norms_batch(frames, h, w, stride), 1e-8)
+    return scores_from_tiles(
+        frames, tiles.slabs, tiles, norms, h=h, w=w, stride=stride, W=W,
+        nonlinearity=nonlinearity, interpret=interpret,
+        frames_per_stream=frames_per_stream, packed=False,
+        hyperdim_axes=hyperdim_axes)
 
 
 def fragment_scores(frame: Array, tiles: ScoreTiles, *, h: int, w: int,

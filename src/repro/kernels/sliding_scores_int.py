@@ -17,13 +17,16 @@ Why the integer path is *structurally* different (not just a dtype swap):
   systolic FIFO in loop form). The int kernel instead stores only the
   int8-quantized *base* slabs — the same circularly padded
   ``(n_dt, h, TD + W - 1)`` rows the float geometry keeps — and
-  materializes every shifted view **inside** the grid step: one int32 MXU
-  matmul ``codesᵀ (W, h) @ slabs (h, TD + W - 1)`` folds the ``h`` reused
-  rolled products per column (summing over base rows *before* the shift is
-  valid because shift extraction is linear), then ``log2(W)`` vectorized
-  roll+select passes align row ``i`` by ``i`` so the per-column rolled
-  sums ``G (W, TD)`` fall out as diagonals, and the fragment windows are
-  ONE small integer matmul ``win_mask (mx, W) @ G``. The live set is
+  materializes every shifted view **inside** the grid step: one int8 MXU
+  matmul ``codesᵀ (W, h) @ slabs (h, TD + W - 1)`` with int32
+  accumulation folds the ``h`` reused rolled products per column (summing
+  over base rows *before* the shift is valid because shift extraction is
+  linear; codes are offset by -128 into int8 and the offset added back
+  exactly), then ``log2(W)`` vectorized roll+select passes align row
+  ``i`` by ``i`` so the per-column rolled sums ``G (W, TD)`` fall out as
+  diagonals, and each fragment window sums its ``w`` rows of ``G``. This
+  is the float kernel's body (:mod:`repro.kernels.sliding_scores`) on
+  integer operands — one kernel serves every precision. The live set is
   ``O(window)`` in ``W`` — base slabs + a bounded per-chunk scratch —
   never the old all-``W`` pre-expanded ``(h*W, TD)`` operand whose VMEM
   footprint grew linearly in ``W`` and overran the budget exactly at
@@ -34,9 +37,11 @@ Why the integer path is *structurally* different (not just a dtype swap):
   layout stays under it.
 * **Sub-byte precisions.** ``packed=True`` consumes the int4 wire format
   (two 4-bit codes per byte, :func:`repro.sensing.adc.pack_nibbles`) and
-  unpacks nibbles in-kernel — halved code traffic, int32 accumulation
-  unchanged. ``mode="binary"`` geometry sign-quantizes slabs to ±1 (scale
-  = mean |slab|, the L2-optimal 1-bit approximation) and class HVs to ±1
+  splits nibbles in-kernel — low nibbles are the even columns, high
+  nibbles the odd ones, each projected on its own — halved code traffic,
+  int32 accumulation unchanged. ``mode="binary"`` geometry sign-quantizes
+  slabs to ±1 (scale = mean |slab|, the L2-optimal 1-bit approximation)
+  and class HVs to ±1
   (norm ``sqrt(D)``): the XOR-popcount similarity of binarized HDC
   expressed as the same int8 matmuls, enabling reduced-D operating points
   (D-vs-AUC curve reported by ``benchmarks/int_datapath.py``).
@@ -70,8 +75,8 @@ expanded-slab layout: same quantized int8 values, same exact integer sums,
 same float epilogue — the golden int8 fixtures did not move.)
 
 Precompute mirrors the float path's mutability split: class-independent
-:class:`IntScoreGeometry` (quantized base slabs, window mask, rotation
-gather) vs the jitted device-side :func:`retile_classes_int` /
+:class:`IntScoreGeometry` (quantized base slabs, rotation gather) vs the
+jitted device-side :func:`retile_classes_int` /
 :func:`retile_classes_int_fleet` (classifier install = gather + int8
 quantize per class), so online adaptation never re-runs the host
 precompute mid-stream.
@@ -84,11 +89,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core.encoding import NonLin, apply_nonlinearity
 from repro.kernels import sliding_scores as _ss
-from repro.kernels.compat import CompilerParams
 
 Array = jax.Array
 
@@ -97,10 +100,6 @@ INT32_MAX = 2**31 - 1
 #: int8 symmetric quantization range (saturating at +-127 keeps the
 #: representation sign-symmetric; -128 is never produced)
 _QMAX = 127
-
-#: static W-axis chunk of the in-kernel rolling-shift pass: bounds the
-#: int32 scratch at O(_W_CHUNK * (TD + _W_CHUNK)) independent of W
-_W_CHUNK = 128
 
 #: per-grid-step VMEM working-set budget the int geometry must fit (half a
 #: typical 16 MB TPU core VMEM, leaving room for double buffering). The old
@@ -125,14 +124,12 @@ class IntScoreGeometry:
     ±1 with ``slab_scale = mean |slab|`` (``mode="binary"``). Every
     shifted view ``slabs_q[dt, r, i + j]`` the projection needs is built
     *inside* the kernel by rolling — nothing grows with ``W`` beyond the
-    ``W - 1`` halo columns. ``win_mask[kx, i] = [kx*stride <= i <
-    kx*stride + w]`` aggregates the rolled sums into fragment windows as
-    one small matmul.
+    ``W - 1`` halo columns.
     """
     slabs_q: Array     # (n_dt, h, TD + W - 1) int8 quantized base slabs
-    win_mask: Array    # (mx, W) int8 window-membership indicator
     bias_t: Array      # (n_dt, mx, TD) f32 pre-rotated RFF bias tiles
     idx: Array         # (n_dt, mx, TD) i32 rotation gather into a (D,) vec
+    valid: Array       # (n_dt, 1, TD) f32: 1 on real components, 0 on pad
     slab_scale: Array  # () f32: slab ~= slabs_q * slab_scale
     block_d: int = dataclasses.field(metadata={"static": True})
     w: int = dataclasses.field(metadata={"static": True})
@@ -198,8 +195,8 @@ def int_datapath_bounds(adc_bits: int, H: int, W: int, h: int, w: int,
     guard for the expanded-slab blow-up this layout replaced):
 
     * ``vmem_bytes`` — the rolling-shift layout: codes block + base slabs
-      ``h * (TD + W - 1)`` + the bounded ``O(_W_CHUNK * TD)`` roll
-      scratch + mask/bias/class/acc tiles. O(window) in ``W``.
+      ``h * (TD + W - 1)`` + the bounded ``O(W_CHUNK * TD)`` roll
+      scratch + bias/class/acc tiles. O(window) in ``W``.
     * ``vmem_expanded_bytes`` — what the old all-``W`` pre-expanded
       ``(h*W, TD)`` slab operand would have needed at the same config:
       linear in ``W``.
@@ -220,12 +217,12 @@ def int_datapath_bounds(adc_bits: int, H: int, W: int, h: int, w: int,
 
     td = block_d
     mx = max((W - w) // stride + 1, 1)
-    wc = min(W, _W_CHUNK)
+    wc = min(W, _ss.W_CHUNK)
     codes_bytes = H * (W // 2 if packed else W)           # uint8 wire codes
     slab_bytes = h * (td + W - 1)                         # int8 base slabs
     scratch_bytes = 3 * wc * (td + wc - 1) * 4            # P + roll + select
-    common = (codes_bytes + mx * W                        # codes + win_mask
-              + mx * td * 4                               # f32 bias tile
+    common = (codes_bytes                                 # codes block
+              + mx * td * 4 + td * 4                      # f32 bias + valid
               + 2 * mx * td                               # int8 class tiles
               + mx * td * 4)                              # int32 acc
     vmem = common + slab_bytes + scratch_bytes
@@ -290,7 +287,8 @@ def precompute_geometry_int(B0: Array, b: Array, *, W: int, w: int,
     (``mode="binary"``, the L2-optimal 1-bit scale a la XNOR-Net — it
     keeps the normalized projection on the float path's scale, which the
     RFF nonlinearity is sensitive to). No shift is ever materialized here:
-    the kernel rolls them out per grid step.
+    the kernel rolls them out per grid step. The tile layout (and the
+    padded tail's ``valid`` mask) is the float geometry's.
     """
     if mode not in INT_MODES:
         raise ValueError(f"mode must be one of {INT_MODES}, got {mode!r}")
@@ -302,15 +300,8 @@ def precompute_geometry_int(B0: Array, b: Array, *, W: int, w: int,
     else:
         scale = jnp.maximum(jnp.max(jnp.abs(geom.slabs)), 1e-12) / _QMAX
         slabs_q = _quantize_sym(geom.slabs, scale)
-
-    # win_mask[kx, i] = [kx*stride <= i < kx*stride + w]
-    mx = (W - w) // stride + 1
-    i = jnp.arange(W)[None, :]
-    kx = jnp.arange(mx)[:, None] * stride
-    win_mask = ((i >= kx) & (i < kx + w)).astype(jnp.int8)  # (mx, W)
-
-    return IntScoreGeometry(slabs_q=slabs_q, win_mask=win_mask,
-                            bias_t=geom.bias_t, idx=geom.idx,
+    return IntScoreGeometry(slabs_q=slabs_q, bias_t=geom.bias_t,
+                            idx=geom.idx, valid=geom.valid,
                             slab_scale=scale.astype(jnp.float32),
                             block_d=geom.block_d, w=w, stride=stride,
                             mode=mode)
@@ -401,102 +392,8 @@ def window_norms_codes_batch(codes: Array, h: int, w: int,
 
 
 # ---------------------------------------------------------------------------
-# The kernel
+# The kernel (shared with the float path: repro.kernels.sliding_scores)
 # ---------------------------------------------------------------------------
-
-def _roll_diagonals(p: Array, rows: int, td: int) -> Array:
-    """Extract ``g[l, j] = p[l, l + j]`` for ``j < td`` by rolling.
-
-    ``log2(rows)`` vectorized roll+select passes align row ``l`` left by
-    ``l`` (log-doubling over the bits of ``l``); composition of circular
-    rolls is the circular roll of the sum, and ``l + j <= (rows - 1) +
-    (td - 1) < p.shape[1]``, so no wrapped element is ever kept. Plain
-    concatenate/where — TPU- and interpret-mode-safe, no scalar loops.
-    """
-    width = p.shape[1]
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
-    shift = 1
-    while shift < rows:
-        rolled = jnp.concatenate([p[:, shift:], p[:, :shift]], axis=1)
-        p = jnp.where((row_iota & shift) != 0, rolled, p)
-        shift *= 2
-    return p[:, :td]
-
-
-def _int_window_acc(block, slabs_q, win_mask, *, h: int, W: int,
-                    td: int) -> Array:
-    """Shared int32 projection core: ``(h, W) codes -> (mx, TD)`` sums.
-
-    The paper's computation reuse with an O(window) live set: summing over
-    base rows commutes with shift extraction, so ONE int32 matmul
-    ``codesᵀ @ slabs_q`` produces ``P[i, p] = Σ_r codes[r, i] *
-    slabs_q[r, p]``; rolling row ``i`` left by ``i``
-    (:func:`_roll_diagonals`) yields the per-column rolled sums
-    ``G[i, j] = P[i, i + j]`` — each code multiplied once per base row,
-    never materializing ``(h, W, TD)`` or the old pre-expanded
-    ``(h*W, TD)`` slab — then ONE small integer matmul against the window
-    indicator aggregates every fragment. The ``W`` axis is chunked
-    statically (:data:`_W_CHUNK`) so the int32 scratch stays bounded
-    regardless of frame width. Exact int32 arithmetic throughout, in a
-    fixed association order (bitwise deterministic, and bit-identical to
-    the retired expanded-slab accumulation).
-    """
-    codes = block.astype(jnp.int32)                       # (h, W)
-    slabs = slabs_q.astype(jnp.int32)                     # (h, TD + W - 1)
-    mask = win_mask.astype(jnp.int32)                     # (mx, W)
-    acc = None
-    for c0 in range(0, W, _W_CHUNK):
-        cw = min(_W_CHUNK, W - c0)
-        # P[l, p] = sum_r codes[r, c0 + l] * slabs[r, c0 + p]
-        p = jax.lax.dot_general(
-            codes[:, c0:c0 + cw], slabs[:, c0:c0 + td + cw - 1],
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)             # (cw, td+cw-1)
-        g = _roll_diagonals(p, cw, td)                    # (cw, td)
-        part = jax.lax.dot_general(
-            mask[:, c0:c0 + cw], g, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)             # (mx, td)
-        acc = part if acc is None else acc + part
-    return acc
-
-
-def _score_kernel_int(codes_ref, slab_ref, mask_ref, bias_ref, cpos_ref,
-                      cneg_ref, norm_ref, dpos_ref, dneg_ref, qq_ref, *,
-                      h: int, stride: int, w: int, W: int, mx: int,
-                      td: int, nonlinearity: NonLin, packed: bool):
-    ky = pl.program_id(1)
-    block = codes_ref[0, pl.ds(ky * stride, h), :]        # (h, W[/2]) codes
-    if packed:
-        block = _unpack_nibbles_i32(block)                # (h, W) 4-bit
-    acc = _int_window_acc(block, slab_ref[0], mask_ref[...],
-                          h=h, W=W, td=td)                # (mx, TD) int32
-
-    # float epilogue: normalization (slab scale folded into norm_ref by the
-    # caller), nonlinearity, classifier dots (class scale cancels in cosine)
-    # the ONE nonlinearity definition (repro.core.encoding), shared with
-    # the float kernel and the jnp oracle — plain jnp ops, pallas-safe
-    norms = norm_ref[0].astype(jnp.float32)               # (1, mx)
-    s_n = acc.astype(jnp.float32) / norms[0][:, None]
-    phi = apply_nonlinearity(s_n, bias_ref[0], nonlinearity)  # (mx, TD)
-    # Per-tile partials, folded OUTSIDE the kernel in fixed order (shared
-    # _ordered_tile_fold with the float kernel) — the D-tile axis can then
-    # shard over the "hyperdim" mesh axis with bitwise-identical scores.
-    dpos_ref[...] = jnp.sum(phi * cpos_ref[0].astype(jnp.float32),
-                            axis=1)[None, None, None, :]  # (1, 1, 1, mx)
-    dneg_ref[...] = jnp.sum(phi * cneg_ref[0].astype(jnp.float32),
-                            axis=1)[None, None, None, :]
-    qq_ref[...] = jnp.sum(phi * phi, axis=1)[None, None, None, :]
-
-
-def _cosine_epilogue(dpos, dneg, qq, tiles, per_stream: bool, C: int):
-    qn = jnp.maximum(jnp.sqrt(qq), 1e-9)
-    if per_stream:
-        rep = lambda v: jnp.repeat(v, C)[:, None, None]   # (N, 1, 1)
-        return (dpos / (qn * jnp.maximum(rep(tiles.cpos_norm), 1e-9))
-                - dneg / (qn * jnp.maximum(rep(tiles.cneg_norm), 1e-9)))
-    return (dpos / (qn * jnp.maximum(tiles.cpos_norm, 1e-9))
-            - dneg / (qn * jnp.maximum(tiles.cneg_norm, 1e-9)))
-
 
 def _check_codes_integer(codes: Array) -> None:
     if not jnp.issubdtype(codes.dtype, jnp.integer):
@@ -521,12 +418,12 @@ def fragment_scores_batch_int(codes: Array, tiles: IntScoreTiles, *, h: int,
 
     The fused encode->score entry point of the int datapath: raw codes in,
     float score maps out — no float frame is ever materialized, and no
-    shifted slab either (rolled out in-kernel, see :func:`_int_window_acc`).
-    With ``packed=True`` the input is the int4 wire format ``(N, H, W/2)``
-    (two codes per byte, low nibble first); nibbles are unpacked inside
-    the kernel, so the HBM->VMEM code traffic is halved. Grid and
-    BlockSpec layout mirror the float :func:`~repro.kernels.
-    sliding_scores.fragment_scores_batch`, including the per-stream
+    shifted slab either (rolled out in-kernel, see
+    ``sliding_scores._window_acc``). With ``packed=True`` the input is the
+    int4 wire format ``(N, H, W/2)`` (two codes per byte, low nibble
+    first); nibbles are split inside the kernel, so the HBM->VMEM code
+    traffic is halved. The launch is the float path's
+    (``sliding_scores.scores_from_tiles``), including the per-stream
     class-tile indexing (``frames_per_stream``) used by adapting fleets.
 
     Inside a ``shard_map`` that partitions the D-tile axis, pass the mesh
@@ -536,78 +433,20 @@ def fragment_scores_batch_int(codes: Array, tiles: IntScoreTiles, *, h: int,
     the unsharded launch (see ``sliding_scores._ordered_tile_fold``).
     """
     _check_codes_integer(codes)
-    N, H, Wc = codes.shape
-    W = Wc * 2 if packed else Wc
-    my = (H - h) // stride + 1
-    mx = (W - w) // stride + 1
+    W = codes.shape[-1] * (2 if packed else 1)
     geom = tiles.geom
-    n_dt, gh, slab_len = geom.slabs_q.shape
-    td = geom.block_d
-    # repro-lint: disable=RA001 (td/geom.w/geom.stride are static aux fields of the geometry pytree — concrete at trace time)
-    assert gh == h and slab_len == td + W - 1, (geom.slabs_q.shape, h, W)
-    assert geom.win_mask.shape == (mx, W), (geom.win_mask.shape, mx, W)
-    assert geom.w == w and geom.stride == stride  # repro-lint: disable=RA001 (same static aux fields)
-
-    per_stream = tiles.cpos_t.ndim == 4
-    if per_stream:
-        if frames_per_stream is None:
-            raise ValueError("per-stream class tiles need frames_per_stream")
-        C = frames_per_stream
-        S = tiles.cpos_t.shape[0]
-        if S * C != N:
-            raise ValueError(f"per-stream tiles: S={S} streams x "
-                             f"C={C} frames != batch N={N}")
-        cpos_t = tiles.cpos_t.reshape(S * n_dt, mx, td)
-        cneg_t = tiles.cneg_t.reshape(S * n_dt, mx, td)
-        class_spec = pl.BlockSpec(
-            (1, mx, td), lambda n, i, j: ((n // C) * n_dt + j, 0, 0))
-    else:
-        C = 0
-        cpos_t, cneg_t = tiles.cpos_t, tiles.cneg_t
-        class_spec = pl.BlockSpec((1, mx, td), lambda n, i, j: (j, 0, 0))
+    assert geom.w == w and geom.stride == stride  # repro-lint: disable=RA001 (static aux fields of the geometry pytree)
 
     # LSB-free normalization with the slab scale folded in:
     #   s_n = (acc * slab_scale) / ||codes||  =  acc / (||codes|| / scale)
     full = _unpack_nibbles_i32(codes) if packed else codes
     norms = window_norms_codes_batch(full, h, w, stride)      # (N, my, mx)
     norms = jnp.maximum(norms, 1e-8) / geom.slab_scale
-
-    kern = functools.partial(_score_kernel_int, h=h, stride=stride, w=w,
-                             W=W, mx=mx, td=td, nonlinearity=nonlinearity,
-                             packed=packed)
-
-    dpos, dneg, qq = pl.pallas_call(
-        kern,
-        grid=(N, my, n_dt),
-        in_specs=[
-            pl.BlockSpec((1, H, Wc), lambda n, i, j: (n, 0, 0)),   # codes
-            pl.BlockSpec((1, h, slab_len),
-                         lambda n, i, j: (j, 0, 0)),               # slabs
-            pl.BlockSpec((mx, W), lambda n, i, j: (0, 0)),         # mask
-            pl.BlockSpec((1, mx, td), lambda n, i, j: (j, 0, 0)),  # bias
-            class_spec,                                            # cpos
-            class_spec,                                            # cneg
-            pl.BlockSpec((1, 1, mx), lambda n, i, j: (n, i, 0)),   # norms
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, mx), lambda n, i, j: (j, n, i, 0)),
-            pl.BlockSpec((1, 1, 1, mx), lambda n, i, j: (j, n, i, 0)),
-            pl.BlockSpec((1, 1, 1, mx), lambda n, i, j: (j, n, i, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((n_dt, N, my, mx),
-                                        jnp.float32)] * 3,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"),
-        ),
-        interpret=interpret,
-    )(codes, geom.slabs_q, geom.win_mask, geom.bias_t, cpos_t, cneg_t,
-      norms)
-
-    dpos = _ss._ordered_tile_fold(dpos, hyperdim_axes)
-    dneg = _ss._ordered_tile_fold(dneg, hyperdim_axes)
-    qq = _ss._ordered_tile_fold(qq, hyperdim_axes)
-
-    return _cosine_epilogue(dpos, dneg, qq, tiles, per_stream, C)
+    return _ss.scores_from_tiles(
+        codes, geom.slabs_q, tiles, norms, h=h, w=w, stride=stride, W=W,
+        nonlinearity=nonlinearity, interpret=interpret,
+        frames_per_stream=frames_per_stream, packed=packed,
+        hyperdim_axes=hyperdim_axes)
 
 
 # ---------------------------------------------------------------------------
@@ -621,7 +460,7 @@ def _int_scores_shared(codes, geom: IntScoreGeometry, cpos_t, cneg_t, *,
     """Shared-classifier jnp int path -> ``(dpos, dneg, qq) (N, my, mx)``.
 
     Same quantized operands and the same int32 accumulation as the kernel
-    (the identical :func:`_int_window_acc` core, vmapped); only the
+    (the identical ``sliding_scores._window_acc`` core, vmapped); only the
     (float) epilogue can differ by rounding. The classifier dots reduce
     per D-tile first and then fold the tiles in the kernel's fixed
     left-to-right order (``_ordered_tile_fold``) — so this path, too, is
@@ -632,22 +471,24 @@ def _int_scores_shared(codes, geom: IntScoreGeometry, cpos_t, cneg_t, *,
     N, H, W = codes.shape
     my = (H - h) // stride + 1
     mx = (W - w) // stride + 1
-    n_dt = geom.slabs_q.shape[0]
     td = geom.block_d
     ky = jnp.arange(my) * stride
     blocks = codes[:, ky[:, None] + jnp.arange(h)[None, :], :]  # (N,my,h,W)
 
-    # same reuse core as the kernel, vmapped over (frame, row-band, D-tile)
-    acc = jax.vmap(jax.vmap(lambda blk: jax.vmap(
-        lambda slab: _int_window_acc(blk, slab, geom.win_mask, h=h, W=W,
-                                     td=td))(geom.slabs_q)))(
-                                         blocks)   # (N, my, n_dt, mx, TD)
+    # same reuse core as the kernel, vmapped over (row-band, D-tile) and
+    # mapped over frames in small batches: the per-band products are
+    # (W, TD + W - 1) int32 each, too many to hold for a whole chunk
+    acc = jax.lax.map(jax.vmap(lambda blk: jax.vmap(
+        lambda slab: _ss._window_acc(blk, slab, W=W, td=td, w=w,
+                                     stride=stride, mx=mx))(geom.slabs_q)),
+        blocks, batch_size=8)                      # (N, my, n_dt, mx, TD)
     acc = acc.transpose(0, 1, 3, 2, 4)             # (N, my, mx, n_dt, TD)
     norms = window_norms_codes_batch(codes, h, w, stride)
     norms = jnp.maximum(norms, 1e-8) / geom.slab_scale
     s_n = acc.astype(jnp.float32) / norms[..., None, None]
     bias = geom.bias_t.transpose(1, 0, 2)[None, None]     # (1,1,mx,n_dt,TD)
-    phi = apply_nonlinearity(s_n, bias, nonlinearity)
+    valid = geom.valid.transpose(1, 0, 2)[None, None]     # (1,1,1,n_dt,TD)
+    phi = apply_nonlinearity(s_n, bias, nonlinearity) * valid
     cpos = cpos_t.transpose(1, 0, 2)[None, None].astype(jnp.float32)
     cneg = cneg_t.transpose(1, 0, 2)[None, None].astype(jnp.float32)
     # per-tile partials (reduce TD only), then the shared fixed-order fold
@@ -704,5 +545,5 @@ def fragment_scores_batch_int_ref(codes: Array, tiles: IntScoreTiles, *,
             codes, geom, tiles.cpos_t, tiles.cneg_t, h=h, w=w,
             stride=stride, nonlinearity=nonlinearity,
             hyperdim_axes=hyperdim_axes)
-    return _cosine_epilogue(dpos, dneg, qq, tiles, per_stream,
-                            frames_per_stream or 0)
+    return _ss._cosine_epilogue(dpos, dneg, qq, tiles, per_stream,
+                                frames_per_stream or 0)

@@ -396,6 +396,30 @@ class FleetService:
         fn = step.func if isinstance(step, functools.partial) else step
         return fn._cache_size()
 
+    def compiled_step_text(self) -> str:
+        """HLO text of the fleet step compiled at this service's shapes.
+
+        The witness of where scoring runs: a kernel compiled for the TPU
+        appears as a ``tpu_custom_call``; an interpreted one does not.
+        Needs a dispatched tick (the first arrival fixes the frame shape).
+        """
+        if self._frame_hw is None:
+            raise RuntimeError("dispatch a tick first: the frame shape "
+                               "fixes the step")
+        step, tiles = self._ensure_step(self._frame_hw[1])
+        fn, kw = ((step.func, step.keywords)
+                  if isinstance(step, functools.partial) else (step, {}))
+        S, C = self.n_slots, self.chunk_size
+        dtype = (adc_sim.codes_dtype(self.adc_bits)
+                 if self.precision in adc_sim.INT_PRECISIONS
+                 else jnp.float32)
+        sds = jax.ShapeDtypeStruct
+        m = self.model
+        return fn.lower(sds((S, C, *self._frame_hw), dtype), self._state,
+                        m.B0, m.b, tiles, self._t_score, self._n_valid,
+                        sds((S, C), jnp.int32), sds((S,), jnp.bool_),
+                        **kw).compile().as_text()
+
     def _put(self, x, spec=None):
         if self._mesh is None or spec is None:
             return jax.device_put(x)

@@ -93,28 +93,6 @@ def _tf_layer_spec(cfg: ModelConfig) -> dict:
     return s
 
 
-@jax.custom_vjp
-def _opt_barrier(x: Array) -> Array:
-    """``optimization_barrier`` with an explicit gradient.
-
-    jax 0.4.37 has no differentiation rule for the barrier primitive
-    (added upstream later); the barrier is an optimization hint, so the
-    cotangent passes straight through.
-    """
-    return jax.lax.optimization_barrier(x)
-
-
-def _opt_barrier_fwd(x):
-    return jax.lax.optimization_barrier(x), None
-
-
-def _opt_barrier_bwd(_, g):
-    return (g,)
-
-
-_opt_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
-
-
 def _seq_gather(x: Array) -> Array:
     """Explicit bf16 gather point for the sequence-parallel residual.
 
@@ -123,7 +101,7 @@ def _seq_gather(x: Array) -> Array:
     all-gather, doubling SP collective bytes (§Perf hillclimb C3).
     """
     xg = shard(x, "act_batch", "act_seq", "act_embed")
-    return _opt_barrier(xg)
+    return jax.lax.optimization_barrier(xg)
 
 
 def _to_resid(y: Array) -> Array:

@@ -8,7 +8,7 @@ along a sensor axis without multiplying kernel launches:
 
 * ``(S, C, H, W)`` **super-chunks** — S concurrent streams, C frames each —
   are flattened to an ``S*C`` batch and scored by ONE ``pallas_call``
-  (grid ``(S*C, my, n_dt)``) against one shared
+  (grid ``(S*C, n_dt)``) against one shared
   :class:`~repro.kernels.sliding_scores.ScoreTiles` precompute
   (:func:`repro.kernels.ops.fragment_score_map_fleet`);
 * per-stream controller hysteresis is ``vmap(gate_scan)`` — S independent
@@ -100,8 +100,8 @@ def _hyperdim_axes(mesh, tiles, backend: str,
 def _tiles_specs(tiles, hd: tuple[str, ...] | None):
     """PartitionSpec pytree for the step's ``tiles`` argument.
 
-    Only the D-tile-leading arrays (slabs, bias/idx, class tiles) shard
-    over the hyperdim axes; window masks, scales and the full-D class
+    Only the D-tile-leading arrays (slabs, bias/idx/valid, class tiles)
+    shard over the hyperdim axes; scales and the full-D class
     norms stay replicated — norms ARE full-D quantities, which is what
     keeps the sharded cosine epilogue exact. Built by
     ``dataclasses.replace`` on the live tiles instance so static fields
@@ -114,9 +114,10 @@ def _tiles_specs(tiles, hd: tuple[str, ...] | None):
 
     def geom_specs(g):
         if hasattr(g, "slabs_q"):
-            return dataclasses.replace(g, slabs_q=hd3, win_mask=rep,
-                                       bias_t=hd3, idx=hd3, slab_scale=rep)
-        return dataclasses.replace(g, slabs=hd3, bias_t=hd3, idx=hd3)
+            return dataclasses.replace(g, slabs_q=hd3, bias_t=hd3, idx=hd3,
+                                       valid=hd3, slab_scale=rep)
+        return dataclasses.replace(g, slabs=hd3, bias_t=hd3, idx=hd3,
+                                   valid=hd3)
 
     if hasattr(tiles, "geom"):
         cls = (P(None, hd, None, None) if hd else P()) \
@@ -155,7 +156,7 @@ def _build_step(mesh, axes, hd_axes, tiles_spec, donate: bool = False,
       device (``stream.super_chunk_fn._shared_fold``) — the former
       "falls back to unsharded" case, now sharded and still bitwise.
 
-    ``check_rep=False`` because replicated outputs (shared classifiers)
+    ``check_vma=False`` because replicated outputs (shared classifiers)
     are produced by identical replicated folds the checker can't see
     through.
     """
@@ -163,7 +164,6 @@ def _build_step(mesh, axes, hd_axes, tiles_spec, donate: bool = False,
         return functools.partial(
             stream_mod.super_chunk_step_donated if donate
             else super_chunk_step, **static)
-    from jax.experimental.shard_map import shard_map
     s4, s3, s2, s1 = (P(axes, None, None, None), P(axes, None, None),
                       P(axes, None), P(axes))
     rep = P()
@@ -171,12 +171,12 @@ def _build_step(mesh, axes, hd_axes, tiles_spec, donate: bool = False,
                   and static["adapt"].scope == "per-stream")
     state_in = StreamState(class_hvs=s3 if per_stream else rep,
                            holds=s1, phases=s1, frame_idx=rep)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         functools.partial(super_chunk_fn, sensor_axes=axes,
                           hyperdim_axes=hd_axes, **static), mesh=mesh,
         in_specs=(s4, state_in, rep, rep, tiles_spec, rep, rep, s2, s1),
         out_specs=(s2, s2, s2, s2, state_in),
-        check_rep=False), donate_argnums=(1,) if donate else ())
+        check_vma=False), donate_argnums=(1,) if donate else ())
 
 
 @dataclasses.dataclass(frozen=True)

@@ -12,7 +12,7 @@ chunk runs
   ->  (optionally) an online classifier update
 
 inside a single jitted step. On the ``pallas`` backend the whole chunk is
-ONE kernel launch (grid ``(N, my, n_dt)``).
+ONE kernel launch (grid ``(N, n_dt)``).
 
 **Mutable model state.** The model is no longer frozen at construction:
 every chunk threads a :class:`StreamState` pytree — class hypervectors,
@@ -492,12 +492,16 @@ def super_chunk_fn(frames, state: StreamState, B0, b, tiles, t_score,
         maps = jax.vmap(lambda fs, cv: jax.vmap(
             lambda f: hypersense.fragment_score_map(
                 f, cv, B0, b, h=h, w=w, stride=stride,
-                nonlinearity=nonlinearity, backend=backend))(fs))(
-                    frames, class_hvs)
+                nonlinearity=nonlinearity, reuse=False,
+                backend=backend))(fs))(frames, class_hvs)
     else:
+        # the plain reference: crop every fragment and encode it against
+        # the materialized base (one matmul) — the reuse formulation
+        # materializes (H, W, D) per base row, which no device holds at
+        # the paper's frame size
         maps = jax.vmap(lambda f: hypersense.fragment_score_map(
             f, class_hvs, B0, b, h=h, w=w, stride=stride,
-            nonlinearity=nonlinearity, backend=backend))(
+            nonlinearity=nonlinearity, reuse=False, backend=backend))(
                 frames.reshape(S * C, H, W)).reshape(S, C, my, mx)
 
     scores = jax.vmap(jax.vmap(

@@ -12,13 +12,14 @@ import pytest
 from repro import configs
 from repro.configs.base import applicable_shapes
 from repro.launch import steps
+from repro.launch.mesh import make_mesh
 
 jax.config.update("jax_platform_name", "cpu")
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 ALL_CELLS = [

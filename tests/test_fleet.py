@@ -16,6 +16,7 @@ import pytest
 from repro.core import encoding, energy, hypersense
 from repro.core.sensor_control import ControllerConfig
 from repro.distributed import sharding as shlib
+from repro.launch.mesh import make_mesh
 from repro.sensing import adc, synthetic
 from repro.sensing.fleet import (FleetRunner, fleet_report, simulate_fleet)
 from repro.sensing.stream import StreamRunner, simulate_stream_batched
@@ -172,7 +173,7 @@ def test_fleet_sharded_matches_unsharded(backend):
     s0, f0, g0 = plain.process(frames)
     n_dev = jax.device_count()
     data = n_dev if S % n_dev == 0 else 1
-    mesh = jax.make_mesh((data, n_dev // data), ("data", "model"))
+    mesh = make_mesh((data, n_dev // data), ("data", "model"))
     with shlib.use_mesh(mesh):
         sharded = FleetRunner(model, cfg, chunk_size=4, backend=backend,
                               block_d=64)
@@ -223,7 +224,7 @@ def test_fleet_closed_loop_sharded_matches_unsharded():
     s0, f0, g0 = plain.process(frames)
     n_dev = jax.device_count()
     data = n_dev if S % n_dev == 0 else 1
-    mesh = jax.make_mesh((data, n_dev // data), ("data", "model"))
+    mesh = make_mesh((data, n_dev // data), ("data", "model"))
     with shlib.use_mesh(mesh):
         sharded = FleetRunner(model, cfg, chunk_size=4, block_d=64,
                               control=CaptureConfig(hp_buffer=0))
@@ -249,7 +250,7 @@ def test_fleet_int8_sharded_matches_unsharded():
     s0, f0, g0 = plain.process(frames)
     n_dev = jax.device_count()
     data = n_dev if S % n_dev == 0 else 1
-    mesh = jax.make_mesh((data, n_dev // data), ("data", "model"))
+    mesh = make_mesh((data, n_dev // data), ("data", "model"))
     with shlib.use_mesh(mesh):
         sharded = FleetRunner(model, cfg, chunk_size=4, block_d=64,
                               adc_bits=8, precision="int8")
@@ -266,7 +267,7 @@ def test_fleet_int8_sharded_matches_unsharded():
 def test_fleet_sensor_axis_actually_partitioned():
     """With a real multi-device mesh the "sensors" rule claims the data
     axis — the step's sharded inputs split S across devices."""
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     with shlib.use_mesh(mesh):
         spec = shlib.spec_for((jax.device_count() * 2,), ("sensors",))
     assert spec[0] is not None
@@ -287,7 +288,7 @@ def test_fleet_non_divisible_sensor_axis_pads_and_shards(S):
         pytest.skip(f"device count divisible by {S}")
     plain = FleetRunner(model, cfg, chunk_size=4)
     s0, f0, g0 = plain.process(frames)
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     with shlib.use_mesh(mesh):
         r = FleetRunner(model, cfg, chunk_size=4)
         # the sensors axis must still be claimed (padding, not fallback)
@@ -316,7 +317,7 @@ def test_fleet_shared_adapt_sharded_no_fallback():
     ad = AdaptConfig(mode="label", lr=0.5, scope="shared")
     plain = FleetRunner(model, cfg, chunk_size=4, adapt=ad)
     s0, f0, g0 = plain.process(frames, labels=labels)
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     with shlib.use_mesh(mesh):
         r = FleetRunner(model, cfg, chunk_size=4, adapt=ad)
         s1, f1, g1 = r.process(frames, labels=labels)

@@ -26,6 +26,7 @@ import pytest
 from repro.core import encoding, hypersense
 from repro.core.online import AdaptConfig
 from repro.core.sensor_control import ControllerConfig, stats_from
+from repro.launch.mesh import make_mesh
 from repro.sensing import synthetic
 from repro.sensing.fleet import FleetRunner, fleet_report
 from repro.sensing.stream import StreamRunner
@@ -180,7 +181,7 @@ def scenario_fleet_sharded():
     frames = jnp.stack([st[0] for st in sets])
     labels = np.stack([np.asarray(st[2]) for st in sets])
     model = make_model()
-    with shlib.use_mesh(jax.make_mesh((4, 2), ("data", "model"))):
+    with shlib.use_mesh(make_mesh((4, 2), ("data", "model"))):
         r = FleetRunner(model,
                         ControllerConfig(base_rate_hz=20.0,
                                          active_rate_hz=60.0,

@@ -1,6 +1,6 @@
 """Batched sliding-scores kernel: parity vs per-frame and pure-jnp paths.
 
-The batched kernel (grid ``(N, my, n_dt)``) must agree with (a) the
+The batched kernel (grid ``(N, n_dt)``) must agree with (a) the
 per-frame kernel it generalizes, and (b) the pure-jnp
 ``fragment_score_map`` oracle — across dtypes, strides, and non-divisible
 ``D % block_d``. Plus edge cases of ``frame_detection_score``.

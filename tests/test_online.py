@@ -30,6 +30,7 @@ from repro.core.online import AdaptConfig
 from repro.core.sensor_control import ControllerConfig
 from repro.kernels import ops as kops
 from repro.kernels import sliding_scores as k_ss
+from repro.launch.mesh import make_mesh
 from repro.sensing import synthetic
 from repro.sensing.fleet import FleetRunner
 from repro.sensing.stream import (StreamRunner, _top_fragment_hvs,
@@ -394,7 +395,7 @@ def test_fleet_shared_adapt_sharded_folds_time_ordered():
                 fr.process(frames, labels=labels)
         return fr
 
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     fr = run(mesh)
     # the step really sharded: no shared-scope fallback survives
     assert fr._step_key[1] == ("data",)

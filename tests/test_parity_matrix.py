@@ -42,6 +42,7 @@ import pytest
 from repro.core import fragment_model as fm, hypersense
 from repro.core.online import AdaptConfig
 from repro.core.sensor_control import ControllerConfig
+from repro.launch.mesh import make_mesh
 from repro.sensing import fragments, synthetic
 from repro.sensing.fleet import FleetRunner
 from repro.sensing.stream import StreamRunner
@@ -277,7 +278,7 @@ def _mesh_or_skip(name: str):
     if shape[0] * shape[1] > jax.device_count():
         pytest.skip(f"mesh {name} needs {shape[0] * shape[1]} devices "
                     "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
 def _run_fleet_mesh(precision, scope, mesh_name=None):

@@ -29,6 +29,7 @@ import pytest
 from repro.core import encoding, hypersense
 from repro.core.online import AdaptConfig
 from repro.core.sensor_control import CaptureConfig, ControllerConfig
+from repro.launch.mesh import make_mesh
 from repro.launch.serve import FleetService
 from repro.sensing.fleet import FleetRunner
 from repro.sensing.stream import StreamRunner
@@ -422,7 +423,7 @@ def test_mesh_sharded_service_matches_unsharded():
     from repro.distributed import sharding as shlib
     model = make_model()
     trace = make_trace(3, 4 * C)
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
 
     def play(svc):
         svc.attach(0)

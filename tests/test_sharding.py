@@ -8,6 +8,7 @@ from jax.sharding import PartitionSpec as P
 from repro import configs
 from repro.distributed import roofline as rl
 from repro.distributed import sharding as shlib
+from repro.launch.mesh import make_mesh
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -16,7 +17,7 @@ jax.config.update("jax_platform_name", "cpu")
 def mesh():
     # 1 real device: mesh (1, 1) exercises the rules code paths; axis
     # sizes of 1 accept any dim, so specs resolve like the big mesh.
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_spec_for_basic(mesh):
@@ -25,7 +26,7 @@ def test_spec_for_basic(mesh):
 
 
 def test_spec_for_drops_non_divisible():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # simulate divisibility drop via a fake 16-wide axis: use rules math
     # directly through _axis_for
     taken = set()
@@ -181,7 +182,7 @@ def test_mesh_extent_ignores_divisibility():
 
 def test_mesh_extent_unknown_or_meshless():
     assert shlib.mesh_extent("no_such_axis",
-                             jax.make_mesh((1, 1), ("data", "model"))) \
+                             make_mesh((1, 1), ("data", "model"))) \
         == ((), 1)
     assert shlib.mesh_extent("hyperdim", None) == ((), 1)
 

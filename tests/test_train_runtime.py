@@ -14,6 +14,7 @@ import pytest
 
 from repro import configs
 from repro.ckpt import checkpoint as ckpt
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.train import compress, loop as train_loop, optim
 
@@ -112,7 +113,7 @@ def test_elastic_reshard_restore(tmp_path):
     d = str(tmp_path / "ck")
     tree = _tree(jax.random.PRNGKey(4))
     ckpt.save(d, 1, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = jax.tree.map(lambda x: NamedSharding(mesh, P()), tree)
     restored, _ = ckpt.restore(d, tree, shardings=sh)
     assert restored["w"].sharding == NamedSharding(mesh, P())
