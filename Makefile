@@ -19,7 +19,8 @@ SHARD1_FILES = tests/test_kernels.py tests/test_kernels_batch.py \
 	tests/test_stream.py tests/test_fleet.py \
 	tests/test_sensing.py tests/test_adc_quantize.py tests/test_golden.py \
 	tests/test_sharding.py tests/test_control_loop.py tests/test_serve.py \
-	tests/test_cascade.py tests/test_tpu_compile.py tests/test_chip_smoke.py
+	tests/test_cascade.py tests/test_telemetry.py tests/test_tpu_compile.py \
+	tests/test_chip_smoke.py
 SHARD2_FILES = tests/test_arch_smoke.py tests/test_cells.py \
 	tests/test_data_pipeline.py tests/test_gate.py tests/test_hdc_core.py \
 	tests/test_hypersense.py tests/test_online.py tests/test_system.py \

@@ -1,7 +1,8 @@
 """Fleet serving throughput: async double-buffered service vs sync runner.
 
 The serving-layer measurement (``repro.launch.serve.FleetService``):
-sustained frames/sec and p99 per-chunk latency of the always-on service
+sustained frames/sec and the p99 host time of collecting one tick
+(``serve.collect`` in ``repro.launch.telemetry``) of the always-on service
 against the synchronous ``FleetRunner`` baseline on the SAME trace.
 
 * ``sync-runner`` — ``FleetRunner.process`` per tick with the results
@@ -35,6 +36,7 @@ import numpy as np
 from repro.core import hypersense
 from repro.core.encoding import make_perm_base_rows
 from repro.core.sensor_control import ControllerConfig
+from repro.launch import telemetry
 from repro.launch.serve import FleetService
 from repro.sensing.fleet import FleetRunner
 
@@ -107,10 +109,12 @@ def run(slots: int = SLOTS, ticks: int = TICKS, chunk: int = CHUNK,
         # dispatch-only loop: dispatch's own back-pressure collects the
         # oldest tick once max_inflight are queued, keeping the pipeline
         # exactly max_inflight deep; flush() drains the tail
+        telemetry.reset()
         for t in range(ticks):
             svc.dispatch({i: trace[i, t * chunk:(t + 1) * chunk]
                           for i in range(slots)})
-        return [c.latency_s for c in svc.flush()]
+        svc.flush()
+        return telemetry.durations("serve.collect")
 
     def best_of(fn):
         best, best_out = float("inf"), None
@@ -134,7 +138,7 @@ def run(slots: int = SLOTS, ticks: int = TICKS, chunk: int = CHUNK,
     rows.append({"name": "serve_throughput/async-serve",
                  "frames_per_sec": f"{fps_async:.1f}",
                  "ms_per_pass": f"{dt_async * 1e3:.1f}",
-                 "p99_chunk_latency_ms": f"{p99:.1f}",
+                 "p99_collect_ms": f"{p99:.1f}",
                  "sensors": slots, "backend": backend})
     rows.append({"name": "serve_throughput/async_vs_sync_speedup",
                  "value": f"{fps_async / fps_sync:.2f}x",
@@ -188,7 +192,7 @@ def _churn_phase(model, config, slots, chunk, frame, check):
     c0 = svc.compile_count()
     live = {0}
     n_frames = chunk
-    lat = []
+    telemetry.reset()
     t0 = time.perf_counter()
     for t in range(1, CHURN_TICKS):
         if t % 3 == 0 and len(live) < slots:        # arrivals...
@@ -204,13 +208,14 @@ def _churn_phase(model, config, slots, chunk, frame, check):
                if t % 7 != 0 or i % 2 == 0}          # ragged arrival
         svc.dispatch(arr)
         n_frames += chunk * len(arr)
-    lat.extend(c.latency_s for c in svc.flush())
+    svc.flush()
+    lat = telemetry.durations("serve.collect")
     dt = time.perf_counter() - t0
     recompiles = svc.compile_count() - c0
     p99 = float(np.percentile(np.asarray(lat) * 1e3, 99)) if lat else 0.0
     rows = [{"name": "serve_throughput/churn",
              "frames_per_sec": f"{n_frames / dt:.1f}",
-             "p99_chunk_latency_ms": f"{p99:.1f}",
+             "p99_collect_ms": f"{p99:.1f}",
              "ticks": CHURN_TICKS, "recompiles_after_warmup": recompiles,
              "backend": "jnp"}]
     if check and recompiles != 0:

@@ -429,24 +429,28 @@ def _ordered_tile_fold(parts: Array,
     ``jnp.sum``/``psum`` would let XLA reassociate the adds and break
     that guarantee — do not "simplify" this into one.
     """
-    if hyperdim_axes:
-        parts = jax.lax.all_gather(parts, hyperdim_axes, axis=0, tiled=True)
-    out = parts[0]
-    for i in range(1, parts.shape[0]):
-        out = out + parts[i]
-    return out
+    with jax.named_scope("tile_fold"):
+        if hyperdim_axes:
+            parts = jax.lax.all_gather(parts, hyperdim_axes, axis=0,
+                                       tiled=True)
+        out = parts[0]
+        for i in range(1, parts.shape[0]):
+            out = out + parts[i]
+        return out
 
 
 def _cosine_epilogue(dpos, dneg, qq, tiles, per_stream: bool, C: int):
     """Folded dots -> ``sim(pos) - sim(neg)``; per-stream class norms
     broadcast over that stream's ``C`` frames."""
-    qn = jnp.maximum(jnp.sqrt(qq), 1e-9)
-    if per_stream:
-        rep = lambda v: jnp.repeat(v, C)[:, None, None]       # (N, 1, 1)
-        return (dpos / (qn * jnp.maximum(rep(tiles.cpos_norm), 1e-9))
-                - dneg / (qn * jnp.maximum(rep(tiles.cneg_norm), 1e-9)))
-    return (dpos / (qn * jnp.maximum(tiles.cpos_norm, 1e-9))
-            - dneg / (qn * jnp.maximum(tiles.cneg_norm, 1e-9)))
+    with jax.named_scope("cosine_epilogue"):
+        qn = jnp.maximum(jnp.sqrt(qq), 1e-9)
+        if per_stream:
+            rep = lambda v: jnp.repeat(v, C)[:, None, None]   # (N, 1, 1)
+            return (dpos / (qn * jnp.maximum(rep(tiles.cpos_norm), 1e-9))
+                    - dneg / (qn * jnp.maximum(rep(tiles.cneg_norm),
+                                               1e-9)))
+        return (dpos / (qn * jnp.maximum(tiles.cpos_norm, 1e-9))
+                - dneg / (qn * jnp.maximum(tiles.cneg_norm, 1e-9)))
 
 
 def scores_from_tiles(x: Array, slabs: Array, tiles, norms: Array, *,

@@ -29,7 +29,11 @@ that closes the loop:
   backbone compute overlaps the gate's next ticks; up to
   ``max_inflight`` batches pipeline before the oldest is drained
   (back-pressure), and :meth:`~CascadeService.collect` blocks only on
-  the oldest in-flight batch.
+  the oldest in-flight batch. Each batch records the spans
+  ``cascade.launch`` (block assembly, upload, step enqueue) and
+  ``cascade.wait`` (the host blocked on its logits) in
+  :mod:`repro.launch.telemetry`, under the batch's ``seq``, and adds the
+  block's bytes to the counter ``cascade.h2d_bytes``.
 
 * **System accounting.** :meth:`~CascadeService.backbone_cost` reads
   the compiled step's XLA ``cost_analysis()`` (the roofline model's
@@ -45,7 +49,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from typing import Hashable
 
 import jax
@@ -55,7 +58,7 @@ import numpy as np
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import energy
 from repro.distributed import roofline as roofline_mod
-from repro.launch import steps
+from repro.launch import steps, telemetry
 
 Array = jax.Array
 
@@ -66,21 +69,20 @@ class CascadeBatch:
 
     Row ``j`` of ``logits`` is the detector output for the frame the
     gate captured at absolute index ``frame_idx[j]`` on sensor
-    ``sids[j]``; pad rows are already dropped. ``latency_s`` is wall
-    time from the batch's dispatch to its outputs being host-resident.
+    ``sids[j]``; pad rows are already dropped. Where the host's time
+    went for the batch is in :mod:`repro.launch.telemetry`, under its
+    ``seq``.
     """
     seq: int
     sids: tuple
     frame_idx: np.ndarray          # (m,) int64 absolute gate indices
     logits: np.ndarray             # (m, n_out) float32
     n_padded: int                  # zero rows the fixed batch carried
-    latency_s: float
 
 
 @dataclasses.dataclass
 class _InFlightBatch:
     seq: int
-    t0: float
     logits: Array                  # (batch_size, n_out) device future
     rows: list                     # [(sid, abs_idx), ...] valid rows
 
@@ -197,14 +199,16 @@ class CascadeService:
 
     def _launch(self, rows: list) -> None:
         B = self.batch_size
-        block = np.zeros((B, *self.frame_hw), np.float32)
-        for j, (_, _, frame) in enumerate(rows):
-            block[j] = frame
-        dev = (jax.device_put(block) if self._mesh is None
-               else jax.device_put(block, self._cell.in_shardings[1]))
-        logits = self._jit(self._params, dev)      # async: enqueued, not run
+        with telemetry.span("cascade.launch", self._seq):
+            block = np.zeros((B, *self.frame_hw), np.float32)
+            for j, (_, _, frame) in enumerate(rows):
+                block[j] = frame
+            telemetry.count("cascade.h2d_bytes", block.nbytes)
+            dev = (jax.device_put(block) if self._mesh is None
+                   else jax.device_put(block, self._cell.in_shardings[1]))
+            logits = self._jit(self._params, dev)  # async: enqueued, not run
         self._pending.append(_InFlightBatch(
-            seq=self._seq, t0=time.perf_counter(), logits=logits,
+            seq=self._seq, logits=logits,
             rows=[(sid, idx) for sid, idx, _ in rows]))
         self._seq += 1
         self.batches += 1
@@ -213,16 +217,16 @@ class CascadeService:
             self._ready.append(self._finish(self._pending.popleft()))
 
     def _finish(self, rec: _InFlightBatch) -> CascadeBatch:
-        # repro-lint: disable=RA003 (designed sync point: blocks on the oldest in-flight batch only)
-        logits = np.asarray(rec.logits)            # blocks on THIS batch
+        with telemetry.span("cascade.wait", rec.seq):
+            # repro-lint: disable=RA003 (designed sync point: blocks on the oldest in-flight batch only)
+            logits = np.asarray(rec.logits)        # blocks on THIS batch
         m = len(rec.rows)
         return CascadeBatch(
             seq=rec.seq,
             sids=tuple(sid for sid, _ in rec.rows),
             frame_idx=np.asarray([i for _, i in rec.rows], np.int64),
             logits=logits[:m],
-            n_padded=self.batch_size - m,
-            latency_s=time.perf_counter() - rec.t0)
+            n_padded=self.batch_size - m)
 
     def collect(self) -> CascadeBatch | None:
         """Oldest finished batch (FIFO), or None with nothing in flight."""

@@ -47,6 +47,20 @@ background thread; ``ckpt_every=N`` automates it per N chunks). Restore
 into a freshly constructed service resumes the trace bitwise-identical
 to an uninterrupted run (``tests/test_serve.py``).
 
+**Telemetry** (:mod:`repro.launch.telemetry`). Each tick records spans
+under its sequence number: ``serve.dispatch`` (up to the enqueue; the
+back-pressure collect and the checkpoint come after it) and its parts
+``.assemble`` (the host super-chunk), ``.upload`` (enqueueing dispatch's
+``device_put`` calls, which return before the bytes have moved) and
+``.launch`` (key fold, ADC convert, fleet-step enqueue; a compile shows
+here); ``serve.collect`` and its parts ``.wait`` (the host reads of the
+step's outputs) and ``.hp_capture``. Counters: ``serve.h2d_bytes``, every
+array the service puts on the device (dispatch's, and the raw
+super-chunk the HP capture uploads again), ``serve.arrival_frames``
+delivered, ``serve.scored_frames`` (slot-frames the step ran),
+``serve.sampled_frames`` (of them, converted by an active sensor's LP
+ADC) and ``serve.hp_frames`` captured. None of it waits for the device.
+
 ``benchmarks/serve_throughput.py --check`` gates the service ≥ the
 synchronous ``FleetRunner`` on frames/sec with bitwise-equal outputs on
 the same churn-free trace, zero recompiles across a churn trace, and
@@ -58,7 +72,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-import time
 from typing import Any, Hashable
 
 import jax
@@ -73,6 +86,7 @@ from repro.core.sensor_control import (CaptureConfig, CaptureLog,
                                        ControllerConfig,
                                        assemble_capture_log, decimation)
 from repro.distributed import sharding as shlib
+from repro.launch import telemetry
 from repro.sensing import adc as adc_sim
 from repro.sensing import fleet as fleet_mod
 from repro.sensing import stream as stream_mod
@@ -83,18 +97,17 @@ Array = jax.Array
 
 @dataclasses.dataclass(frozen=True)
 class ServedChunk:
-    """One collected tick: per-sensor outputs + the dispatch→collect lag.
+    """One collected tick: per-sensor outputs.
 
     ``outputs[sid]`` is ``(scores (C,), fired (C,), gated (C,))`` numpy
     arrays for every sensor that delivered frames in the tick;
     ``sampled[sid]`` marks the frames its LP ADC actually converted
-    (closed-loop mode). ``latency_s`` is wall time from ``dispatch``
-    returning to the results being host-resident.
+    (closed-loop mode). Where the host's time went for the tick is in
+    :mod:`repro.launch.telemetry`, under the tick's ``seq``.
     """
     seq: int
     outputs: dict[Hashable, tuple[np.ndarray, np.ndarray, np.ndarray]]
     sampled: dict[Hashable, np.ndarray]
-    latency_s: float
 
 
 @dataclasses.dataclass
@@ -111,7 +124,6 @@ class _Parked:
 class _InFlight:
     """A dispatched, not-yet-collected tick (device futures + host meta)."""
     seq: int
-    t0: float
     scores: Array
     fired: Array
     gated: Array
@@ -421,6 +433,7 @@ class FleetService:
                         **kw).compile().as_text()
 
     def _put(self, x, spec=None):
+        telemetry.count("serve.h2d_bytes", x.nbytes)
         if self._mesh is None or spec is None:
             return jax.device_put(x)
         return jax.device_put(x, NamedSharding(self._mesh, spec))
@@ -444,108 +457,126 @@ class FleetService:
         the host assembling + transferring the next ones. Results come
         back through :meth:`collect`, oldest first.
         """
-        C, S = self.chunk_size, self.n_slots
-        label_mode = self.adapt is not None and self.adapt.mode == "label"
-        if labels is not None and not label_mode:
-            raise ValueError("labels passed without adapt.mode == 'label'")
-        first = None
-        for sid, fr in arrivals.items():
-            if sid not in self._by_sid:
-                raise ValueError(f"sensor {sid!r} is not attached")
-            first = fr if first is None else first
-        if first is not None and self._frame_hw is None:
-            # shape peek only — np.shape reads .shape without pulling a
-            # device arrival to host (the upload happens once, batched)
-            shp = np.shape(first)
-            if len(shp) != 3:
-                raise ValueError(f"expected (chunk_size, H, W) arrival, "
-                                 f"got shape {shp}")
-            self._frame_hw = (int(shp[1]), int(shp[2]))
-            self._frame_pixels = self._frame_hw[0] * self._frame_hw[1]
-            if self.precision in adc_sim.INT_PRECISIONS:
-                from repro.kernels import ops as kops
-                kops.assert_int_datapath_fits(
-                    self.adc_bits, *self._frame_hw, self.model.h,
-                    self.model.w, stride=self.model.stride,
-                    block_d=self.block_d,
-                    packed=self.precision == "int4")
-        H, W = self._frame_hw if self._frame_hw else (0, 0)
-        if self._frame_hw is None:
-            raise ValueError("first dispatch needs at least one arrival "
-                             "to fix the frame shape")
-
-        int_codes = (self.precision in adc_sim.INT_PRECISIONS
-                     and all(np.issubdtype(np.result_type(f), np.integer)
-                             for f in arrivals.values()) and arrivals)
-        assemble = np.zeros((S, C, H, W),
-                            np.int32 if int_codes else np.float32)
-        mask_np = np.zeros((S,), bool)
-        starts = np.zeros((S,), np.int32)
-        uids = np.zeros((S,), np.int32)
-        lab_np = np.zeros((S, C), np.int32)
-        hp_k = stream_mod.resolve_hp_buffer(
-            self.control, C,
-            np.int32 if int_codes else np.float32)
-        for sid, fr in arrivals.items():
-            # repro-lint: disable=RA003 (admission boundary: ragged arrivals are normalized into the host assemble buffer, then uploaded once, batched)
-            fr = np.asarray(fr)
-            if fr.shape != (C, H, W):
+        with telemetry.span("serve.dispatch", self._seq):
+            C, S = self.chunk_size, self.n_slots
+            label_mode = (self.adapt is not None
+                          and self.adapt.mode == "label")
+            if labels is not None and not label_mode:
                 raise ValueError(
-                    f"arrival for {sid!r} has shape {fr.shape}, expected "
-                    f"(chunk_size, H, W) = {(C, H, W)} — a service tick "
-                    f"is exactly one chunk; buffer partial chunks at the "
-                    f"edge")
-            slot = self._by_sid[sid]
-            assemble[slot] = fr
-            mask_np[slot] = True
-            starts[slot] = self._n_seen[sid]
-            uids[slot] = self._uids[sid]
-            self._n_seen[sid] += C
-            if label_mode:
-                if labels is None or sid not in labels:
-                    raise ValueError(f'adapt.mode == "label": arrival for '
-                                     f"{sid!r} needs labels[{sid!r}]")
-                # repro-lint: disable=RA003 (labels are caller-side host metadata, folded into the batched upload)
-                lab_np[slot] = np.asarray(labels[sid], np.int32)
+                    "labels passed without adapt.mode == 'label'")
+            first = None
+            for sid, fr in arrivals.items():
+                if sid not in self._by_sid:
+                    raise ValueError(f"sensor {sid!r} is not attached")
+                first = fr if first is None else first
+            if first is not None and self._frame_hw is None:
+                # shape peek only — np.shape reads .shape without pulling
+                # a device arrival to host (the upload happens once,
+                # batched)
+                shp = np.shape(first)
+                if len(shp) != 3:
+                    raise ValueError(f"expected (chunk_size, H, W) "
+                                     f"arrival, got shape {shp}")
+                self._frame_hw = (int(shp[1]), int(shp[2]))
+                self._frame_pixels = (self._frame_hw[0]
+                                      * self._frame_hw[1])
+                if self.precision in adc_sim.INT_PRECISIONS:
+                    from repro.kernels import ops as kops
+                    kops.assert_int_datapath_fits(
+                        self.adc_bits, *self._frame_hw, self.model.h,
+                        self.model.w, stride=self.model.stride,
+                        block_d=self.block_d,
+                        packed=self.precision == "int4")
+            H, W = self._frame_hw if self._frame_hw else (0, 0)
+            if self._frame_hw is None:
+                raise ValueError("first dispatch needs at least one "
+                                 "arrival to fix the frame shape")
 
-        axes = self._step_axes[0] if self._step_axes else \
-            fleet_mod._sensor_axes(self._mesh)[0]
-        s4 = P(axes, None, None, None) if axes else None
-        s2 = P(axes, None) if axes else None
-        s1 = P(axes) if axes else None
-        frames = self._put(assemble, s4)      # H2D begins here, async
-        mask = self._put(mask_np, s1)
-        lab = self._put(lab_np, s2)
+            int_codes = (self.precision in adc_sim.INT_PRECISIONS
+                         and all(np.issubdtype(np.result_type(f),
+                                               np.integer)
+                                 for f in arrivals.values()) and arrivals)
+            with telemetry.span("serve.dispatch.assemble"):
+                assemble = np.zeros((S, C, H, W),
+                                    np.int32 if int_codes else np.float32)
+                mask_np = np.zeros((S,), bool)
+                starts = np.zeros((S,), np.int32)
+                uids = np.zeros((S,), np.int32)
+                lab_np = np.zeros((S, C), np.int32)
+                hp_k = stream_mod.resolve_hp_buffer(
+                    self.control, C,
+                    np.int32 if int_codes else np.float32)
+                for sid, fr in arrivals.items():
+                    # repro-lint: disable=RA003 (admission boundary: ragged arrivals are normalized into the host assemble buffer, then uploaded once, batched)
+                    fr = np.asarray(fr)
+                    if fr.shape != (C, H, W):
+                        raise ValueError(
+                            f"arrival for {sid!r} has shape {fr.shape}, "
+                            f"expected (chunk_size, H, W) = {(C, H, W)} "
+                            f"— a service tick is exactly one chunk; "
+                            f"buffer partial chunks at the edge")
+                    slot = self._by_sid[sid]
+                    assemble[slot] = fr
+                    mask_np[slot] = True
+                    starts[slot] = self._n_seen[sid]
+                    uids[slot] = self._uids[sid]
+                    self._n_seen[sid] += C
+                    if label_mode:
+                        if labels is None or sid not in labels:
+                            raise ValueError(
+                                f'adapt.mode == "label": arrival for '
+                                f"{sid!r} needs labels[{sid!r}]")
+                        # repro-lint: disable=RA003 (labels are caller-side host metadata, folded into the batched upload)
+                        lab_np[slot] = np.asarray(labels[sid], np.int32)
+            telemetry.count("serve.arrival_frames", C * len(arrivals))
 
-        if self.precision in adc_sim.INT_PRECISIONS and int_codes:
-            # already-converted codes: concrete range check + pack (the
-            # noise, if configured, applies before conversion — integer
-            # input with sigma > 0 raises, as on the runners)
-            frames = stream_mod.adc_view_codes(frames, self.adc_bits,
-                                               sigma=self.adc_sigma)
-        elif self.adc_bits is not None:
-            keys = _fold_uid_keys(self._adc_key, self._put(uids, s1))
-            codes = self.precision in adc_sim.INT_PRECISIONS
-            conv = _adc_convert_codes if codes else _adc_convert
-            frames = conv(frames, keys, self._put(starts, s1),
-                          bits=self.adc_bits, sigma=self.adc_sigma,
-                          codes=codes)
+            axes = self._step_axes[0] if self._step_axes else \
+                fleet_mod._sensor_axes(self._mesh)[0]
+            s4 = P(axes, None, None, None) if axes else None
+            s2 = P(axes, None) if axes else None
+            s1 = P(axes) if axes else None
+            convert = self.adc_bits is not None and not int_codes
+            with telemetry.span("serve.dispatch.upload"):
+                frames = self._put(assemble, s4)  # H2D begins here, async
+                mask = self._put(mask_np, s1)
+                lab = self._put(lab_np, s2)
+                if convert:
+                    uids_d = self._put(uids, s1)
+                    starts_d = self._put(starts, s1)
 
-        step, tiles = self._ensure_step(W)
-        m = self.model
-        s, f, g, smp, new_state = step(
-            frames, self._state, m.B0, m.b, tiles, self._t_score,
-            self._n_valid, lab, mask)
-        self._state = new_state
-        self._seq += 1
-        rec = _InFlight(
-            seq=self._seq - 1, t0=time.perf_counter(), scores=s, fired=f,
-            gated=g, sampled=smp,
-            sids=tuple(sid if mask_np[i] else None
-                       for i, sid in enumerate(self._slots)),
-            starts=starts,
-            raw=assemble if hp_k > 0 else None)
-        self._pending.append(rec)
+            with telemetry.span("serve.dispatch.launch"):
+                if int_codes:
+                    # already-converted codes: concrete range check +
+                    # pack (the noise, if configured, applies before
+                    # conversion — integer input with sigma > 0 raises, as
+                    # on the runners)
+                    frames = stream_mod.adc_view_codes(
+                        frames, self.adc_bits, sigma=self.adc_sigma)
+                elif convert:
+                    keys = _fold_uid_keys(self._adc_key, uids_d)
+                    codes = self.precision in adc_sim.INT_PRECISIONS
+                    conv = _adc_convert_codes if codes else _adc_convert
+                    frames = conv(frames, keys, starts_d,
+                                  bits=self.adc_bits, sigma=self.adc_sigma,
+                                  codes=codes)
+
+                step, tiles = self._ensure_step(W)
+                m = self.model
+                s, f, g, smp, new_state = step(
+                    frames, self._state, m.B0, m.b, tiles, self._t_score,
+                    self._n_valid, lab, mask)
+            telemetry.count("serve.scored_frames", S * C)
+            self._state = new_state
+            self._seq += 1
+            rec = _InFlight(
+                seq=self._seq - 1, scores=s, fired=f, gated=g, sampled=smp,
+                sids=tuple(sid if mask_np[i] else None
+                           for i, sid in enumerate(self._slots)),
+                starts=starts,
+                raw=assemble if hp_k > 0 else None)
+            self._pending.append(rec)
+        # back-pressure and the checkpoint sit outside ``serve.dispatch``:
+        # a tick collected here records its own top-level ``serve.collect``
         while len(self._pending) > self.max_inflight:
             self._ready.append(self._finish(self._pending.popleft()))
         if self.ckpt_every and self._seq % self.ckpt_every == 0:
@@ -553,39 +584,47 @@ class FleetService:
         return rec.seq
 
     def _finish(self, rec: _InFlight) -> ServedChunk:
-        # collect IS the deliberate sync point of the pipeline: these
-        # block only on the OLDEST in-flight tick, after max_inflight
-        # newer ticks were already enqueued behind it.
-        s = np.asarray(rec.scores)  # repro-lint: disable=RA003 (designed sync point: blocks on the oldest in-flight tick only)
-        f = np.asarray(rec.fired)  # repro-lint: disable=RA003 (same designed sync point)
-        g = np.asarray(rec.gated)  # repro-lint: disable=RA003 (same designed sync point)
-        smp = np.asarray(rec.sampled)  # repro-lint: disable=RA003 (same designed sync point)
-        latency = time.perf_counter() - rec.t0
-        outputs, sampled = {}, {}
-        for slot, sid in enumerate(rec.sids):
-            if sid is None:
-                continue
-            outputs[sid] = (s[slot], f[slot], g[slot])
-            sampled[sid] = smp[slot]
-            logs = self._logs[sid]
-            logs[0].append(smp[slot])
-            logs[1].append(g[slot])
-        if rec.raw is not None:
-            hp_k = stream_mod.resolve_hp_buffer(self.control,
-                                                self.chunk_size,
-                                                rec.raw.dtype)
-            # a detached-but-still-holding slot's gated output is masked
-            # noise — it must not be HP-captured or counted as dropped
-            act = np.array([sid is not None for sid in rec.sids])
-            entries, dropped = stream_mod.collect_hp(
-                rec.raw, g & act[:, None], self.chunk_size, hp_k,
-                self.control.hp_bits, rec.starts)
+        with telemetry.span("serve.collect", rec.seq):
+            # collect IS the deliberate sync point of the pipeline: these
+            # block only on the OLDEST in-flight tick, after max_inflight
+            # newer ticks were already enqueued behind it.
+            with telemetry.span("serve.collect.wait"):
+                s = np.asarray(rec.scores)  # repro-lint: disable=RA003 (designed sync point: blocks on the oldest in-flight tick only)
+                f = np.asarray(rec.fired)  # repro-lint: disable=RA003 (same designed sync point)
+                g = np.asarray(rec.gated)  # repro-lint: disable=RA003 (same designed sync point)
+                smp = np.asarray(rec.sampled)  # repro-lint: disable=RA003 (same designed sync point)
+            telemetry.count("serve.sampled_frames", smp.sum())
+            outputs, sampled = {}, {}
             for slot, sid in enumerate(rec.sids):
-                if sid is not None:
-                    self._hp[sid].extend(entries[slot])
-            self.hp_dropped += dropped
-        return ServedChunk(seq=rec.seq, outputs=outputs, sampled=sampled,
-                           latency_s=latency)
+                if sid is None:
+                    continue
+                outputs[sid] = (s[slot], f[slot], g[slot])
+                sampled[sid] = smp[slot]
+                logs = self._logs[sid]
+                logs[0].append(smp[slot])
+                logs[1].append(g[slot])
+            if rec.raw is not None:
+                with telemetry.span("serve.collect.hp_capture"):
+                    hp_k = stream_mod.resolve_hp_buffer(self.control,
+                                                        self.chunk_size,
+                                                        rec.raw.dtype)
+                    # a detached-but-still-holding slot's gated output is
+                    # masked noise — it must not be HP-captured or counted
+                    # as dropped
+                    act = np.array([sid is not None for sid in rec.sids])
+                    # the raw super-chunk goes up again: dispatch's copy
+                    # was donated into the ADC convert
+                    entries, dropped = stream_mod.collect_hp(
+                        self._put(rec.raw), self._put(g & act[:, None]),
+                        self.chunk_size, hp_k, self.control.hp_bits,
+                        rec.starts)
+                    for slot, sid in enumerate(rec.sids):
+                        if sid is not None:
+                            self._hp[sid].extend(entries[slot])
+                            telemetry.count("serve.hp_frames",
+                                            len(entries[slot]))
+                self.hp_dropped += dropped
+            return ServedChunk(seq=rec.seq, outputs=outputs, sampled=sampled)
 
     def collect(self) -> ServedChunk | None:
         """Oldest finished tick (FIFO), or None when nothing is in flight.

@@ -525,9 +525,11 @@ def super_chunk_fn(frames, state: StreamState, B0, b, tiles, t_score,
             lambda f, h0: gate_scan(f, hold_frames, h0))(fired, state.holds)
         phase_out = state.phases
     else:
-        sampled, gated, holds_seq, phases_seq = jax.vmap(
-            lambda f, h0, p0: control_scan(f, hold_frames, decim, h0, p0))(
-                fired, state.holds, state.phases)
+        with jax.named_scope("control_scan"):
+            sampled, gated, holds_seq, phases_seq = jax.vmap(
+                lambda f, h0, p0: control_scan(f, hold_frames, decim, h0,
+                                               p0))(
+                    fired, state.holds, state.phases)
         fired = fired & sampled
         if slot_mask is not None:
             sampled = sampled & slot_mask[:, None]
