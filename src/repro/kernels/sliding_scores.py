@@ -11,9 +11,14 @@ fusing, per grid cell (one frame x one hyperdimension tile):
      ``h`` rows are contracted against the circularly padded base slab in
      ONE matmul, ``P[i, q] = sum_r x[r, i] * slab[r, q]``: every input
      element meets the base material exactly once per base row (the
-     paper's computation reuse). Rolling row ``i`` left by ``i``
-     (:func:`_rows_to_diagonals`) turns ``P`` into the per-column rolled
-     products ``G[i, j] = P[i, i + j]``.
+     paper's computation reuse). Aligning row ``i`` left by its frame
+     column turns ``P`` into the per-column rolled products
+     ``G[i, j] = P[i, i + j]``. Where every band chunk is a full
+     :data:`W_CHUNK` columns of unpacked input (:func:`strided_alignment`)
+     the frame's columns arrive reversed within each chunk and ONE
+     sublane-strided lane rotate of the lane-aligned product does it
+     (:func:`_rotate_to_diagonals`); otherwise a log-step roll-and-select
+     per bit of the largest shift (:func:`_rows_to_diagonals`).
   2. *window sums* — every fragment's projection is the sum of the ``w``
      rows of ``G`` its window covers (the reuse of overlapping fragments).
   3. *normalization + RFF nonlinearity* — in the *unrolled* orientation:
@@ -37,8 +42,9 @@ the row bands loop inside the kernel, so the frame block is fetched once
 per frame. The batch axis is the streaming hot path: one ``pallas_call``
 scores a whole chunk of frames against a single :class:`ScoreTiles`
 precompute (slabs/bias/class tiles are per-model, not per-frame). VMEM per
-step: frame (H, W) + slab (h, TD+W-1) + bias/class tiles (mx, TD) + the
-``(W_CHUNK, TD+W_CHUNK)`` projection of one band chunk — independent of N.
+step: frame (H, W) + slab (h, :func:`slab_width`) + bias/class tiles
+(mx, TD) + the ``(W_CHUNK, TD+W_CHUNK)`` projection of one band chunk —
+independent of N.
 
 A hypervector dimensionality the tile width does not divide (the paper's
 D=5000) is padded up to whole lane-aligned tiles; the padded tail's
@@ -95,6 +101,35 @@ W_CHUNK = 128
 _LANES = 128
 
 
+def _round_lanes(n: int) -> int:
+    return -(-n // _LANES) * _LANES
+
+
+def slab_width(td: int, W: int) -> int:
+    """Columns of one D-tile's base slab: a lane multiple ``>= td + W``.
+
+    Band chunk ``c0`` reads ``[c0, c0 + L)`` with ``L`` the lane multiple
+    ``>= td + cw``, which the strided alignment needs; the log-step one
+    reads the first ``td + cw - 1`` of them. Columns past ``td + W - 1``
+    are cyclic base columns that no kept lane reads.
+    """
+    return _round_lanes(td + W)
+
+
+def strided_alignment(W: int, *, packed: bool = False) -> bool:
+    """Whether the kernel aligns its band products with one strided
+    lane rotate (:func:`_rotate_to_diagonals`) rather than the log-step
+    roll-and-select (:func:`_rows_to_diagonals`).
+
+    Needs every band chunk to be a full :data:`W_CHUNK` columns of one
+    code or value each: the product is then ``(W_CHUNK, L)`` with ``L``
+    a lane multiple, which the TPU rotates in one op. Packed int4 bytes
+    (two column groups of stride 2) and frame widths that leave a partial
+    chunk take the log-step path.
+    """
+    return not packed and W % W_CHUNK == 0
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class ScoreGeometry:
@@ -105,7 +140,7 @@ class ScoreGeometry:
     stored rotation gather ``idx`` is what makes class updates cheap:
     re-tiling a new classifier is one gather through it per class.
     """
-    slabs: Array      # (n_dt, h, TD + W - 1) circularly padded base rows
+    slabs: Array      # (n_dt, h, slab_width(TD, W)) circular base rows
     bias_t: Array     # (n_dt, mx, TD) pre-rotated RFF bias tiles
     idx: Array        # (n_dt, mx, TD) i32 rotation gather into a (D,) vector
     valid: Array      # (n_dt, 1, TD) f32: 1 on real components, 0 on padding
@@ -161,7 +196,7 @@ def tile_layout(dim: int, block_d: int) -> tuple[int, int]:
     """
     if dim % block_d == 0:
         return block_d, dim // block_d
-    td = min(block_d, -(-dim // _LANES) * _LANES)
+    td = min(block_d, _round_lanes(dim))
     return td, -(-dim // td)
 
 
@@ -182,8 +217,8 @@ def precompute_geometry(B0: Array, b: Array, *, W: int, w: int, stride: int,
     # slab column q of tile dt is base column (dt*TD + q) % D: the cyclic
     # shift every fragment column needs, wrapped past the end of B0
     cols = (jnp.arange(n_dt)[:, None] * td
-            + jnp.arange(td + W - 1)[None, :]) % dim
-    slabs = jnp.moveaxis(B0[:, cols], 1, 0)                 # (n_dt,h,TD+W-1)
+            + jnp.arange(slab_width(td, W))[None, :]) % dim
+    slabs = jnp.moveaxis(B0[:, cols], 1, 0)           # (n_dt, h, slab_width)
 
     # idx[dt, kx, j] = (dt*TD + j + kx*stride) % D   (rotation by fragment col)
     dts = jnp.arange(n_dt)[:, None, None] * td
@@ -306,10 +341,13 @@ def _rows_to_diagonals(p: Array, shift: Array, *, max_shift: int,
                        td: int) -> Array:
     """``g[l, j] = p[l, shift[l] + j]`` for ``j < td``, by rolling.
 
-    One roll+select pass per bit of ``max_shift`` aligns row ``l`` left by
-    ``shift[l]`` (composition of circular rolls is the roll by the sum);
-    ``shift[l] + j <= max_shift + td - 1 < p.shape[1]``, so no wrapped
-    element is ever kept.
+    The log-step alignment, for any shape: one roll+select pass per bit
+    of ``max_shift`` aligns row ``l`` left by ``shift[l]`` (composition
+    of circular rolls is the roll by the sum); ``shift[l] + j <=
+    max_shift + td - 1 < p.shape[1]``, so no wrapped element is ever
+    kept. The kernel uses it where :func:`strided_alignment` is false,
+    the jnp int twin always; it is the oracle of
+    :func:`_rotate_to_diagonals`.
     """
     bit = 1
     while bit <= max_shift:
@@ -317,6 +355,21 @@ def _rows_to_diagonals(p: Array, shift: Array, *, max_shift: int,
         p = jnp.where((shift & bit) != 0, rolled, p)
         bit *= 2
     return p[:, :td]
+
+
+def _rotate_to_diagonals(p: Array, *, td: int) -> Array:
+    """``g[i, j] = p[i, (n - 1 - i) + j]`` for ``j < td``: one rotate.
+
+    ``p`` is ``(n, L)`` with ``L`` a lane multiple, and its rows are the
+    band chunk's frame columns in reverse, so row ``i`` needs a LEFT
+    alignment by ``n - 1 - i``. ``pltpu.roll`` with a sublane stride
+    rotates row ``i`` RIGHT by ``shift + i``, which is the left rotation
+    by ``n - 1 - i`` when ``shift = L - (n - 1)``. As in the log-step
+    path, ``n - 1 + td - 1 < L`` keeps every wrapped lane out of ``g``.
+    Only values move: bitwise the log-step result of the unreversed rows.
+    """
+    n, L = p.shape
+    return pltpu.roll(p, L - (n - 1), 1, stride=1, stride_axis=0)[:, :td]
 
 
 def _window_sums(g: Array, col: Array, *, first: int, last: int, w: int,
@@ -336,19 +389,23 @@ def _window_sums(g: Array, col: Array, *, first: int, last: int, w: int,
 
 
 def _window_acc(band: Array, slabs: Array, *, W: int, td: int, w: int,
-                stride: int, mx: int, packed: bool = False) -> Array:
+                stride: int, mx: int, packed: bool = False,
+                strided: bool = False) -> Array:
     """One row band ``(h, W)`` -> its ``(mx, TD)`` fragment projections.
 
     The paper's computation reuse with an O(window) live set: summing over
     base rows commutes with shift extraction, so ONE matmul per band chunk
     (:func:`_project`) multiplies each input element once per base row,
-    :func:`_rows_to_diagonals` turns it into the per-column rolled sums,
-    and :func:`_window_sums` aggregates every fragment. The ``W`` axis is
+    the alignment turns it into the per-column rolled sums, and
+    :func:`_window_sums` aggregates every fragment. The ``W`` axis is
     chunked statically (:data:`W_CHUNK`) so the scratch stays bounded.
     Float bands accumulate in f32; integer codes (``< 2**16``; uint8 in
     one byte plane) exactly in int32. ``packed`` bands are the int4 wire
     format ``(h, W/2)``: low nibbles are the even columns, high nibbles
     the odd ones, projected separately — nothing is interleaved.
+    ``strided`` bands hold each chunk's columns in reverse
+    (:func:`_reverse_chunks`) and align by one strided rotate
+    (:func:`strided_alignment` says where that applies).
     """
     if jnp.issubdtype(band.dtype, jnp.integer):
         planes = 1 if band.dtype.itemsize == 1 else 2
@@ -358,7 +415,6 @@ def _window_acc(band: Array, slabs: Array, *, W: int, td: int, w: int,
     acc = None
     for c0 in range(0, W, W_CHUNK):
         cw = min(W_CHUNK, W - c0)
-        slab = slabs[:, c0:c0 + td + cw - 1]
         # (first column offset, column step, byte planes) per column group
         groups = ((0, 2, 1), (1, 2, 1)) if packed else ((0, 1, planes),)
         for first, step, n_planes in groups:
@@ -367,24 +423,41 @@ def _window_acc(band: Array, slabs: Array, *, W: int, td: int, w: int,
                 cols = byte & 0xF if first == 0 else jnp.right_shift(byte, 4)
             else:
                 cols = band[:, c0:c0 + cw]
-            p = _project(cols, slab, planes=n_planes)
             last = first + step * (cols.shape[1] - 1)
-            shift = first + step * jax.lax.broadcasted_iota(
-                jnp.int32, p.shape, 0)
-            g = _rows_to_diagonals(p, shift, max_shift=last, td=td)
-            col = c0 + first + step * jax.lax.broadcasted_iota(
-                jnp.int32, g.shape, 0)
+            if strided:
+                p = _project(cols, slabs[:, c0:c0 + _round_lanes(td + cw)],
+                             planes=n_planes)
+                g = _rotate_to_diagonals(p, td=td)
+                # row i holds frame column c0 + cw - 1 - i
+                col = c0 + cw - 1 - jax.lax.broadcasted_iota(
+                    jnp.int32, g.shape, 0)
+            else:
+                p = _project(cols, slabs[:, c0:c0 + td + cw - 1],
+                             planes=n_planes)
+                shift = first + step * jax.lax.broadcasted_iota(
+                    jnp.int32, p.shape, 0)
+                g = _rows_to_diagonals(p, shift, max_shift=last, td=td)
+                col = c0 + first + step * jax.lax.broadcasted_iota(
+                    jnp.int32, g.shape, 0)
             part = _window_sums(g, col, first=c0 + first, last=c0 + last,
                                 w=w, stride=stride, mx=mx)
             acc = part if acc is None else acc + part
     return acc
 
 
+def _reverse_chunks(x: Array) -> Array:
+    """Reverse the last axis within each :data:`W_CHUNK` block: the input
+    layout of the strided alignment (one XLA pass, outside the kernel)."""
+    *lead, W = x.shape
+    return jnp.flip(x.reshape(*lead, W // W_CHUNK, W_CHUNK),
+                    axis=-1).reshape(x.shape)
+
+
 def _score_kernel(x_ref, slab_ref, bias_ref, valid_ref, cpos_ref, cneg_ref,
                   norm_ref, dpos_ref, dneg_ref, qq_ref, *, h: int, w: int,
                   stride: int, W: int, my: int, mx: int, td: int,
-                  nonlinearity: NonLin, packed: bool):
-    slabs = slab_ref[0]                                      # (h, TD+W-1)
+                  nonlinearity: NonLin, packed: bool, strided: bool):
+    slabs = slab_ref[0]                                      # (h, slab_width)
     bias = bias_ref[0]                                       # (mx, TD)
     valid = valid_ref[0]                                     # (1, TD)
     cpos = cpos_ref[0].astype(jnp.float32)
@@ -394,7 +467,7 @@ def _score_kernel(x_ref, slab_ref, bias_ref, valid_ref, cpos_ref, cneg_ref,
     def band(ky, outs):
         x = x_ref[0, pl.ds(ky * stride, h), :]               # (h, W[/2])
         acc = _window_acc(x, slabs, W=W, td=td, w=w, stride=stride, mx=mx,
-                          packed=packed)                     # (mx, TD)
+                          packed=packed, strided=strided)    # (mx, TD)
         norms = norm_ref[0, pl.ds(ky, 1), :]                 # (1, mx)
         s_n = acc.astype(jnp.float32) / norms.T
         # the ONE nonlinearity definition (repro.core.encoding), shared
@@ -463,6 +536,9 @@ def scores_from_tiles(x: Array, slabs: Array, tiles, norms: Array, *,
     ``x`` is ``(N, H, W)`` frames or integer codes (``(N, H, W/2)`` bytes
     when ``packed``), ``slabs`` the geometry's (float or int8) slabs,
     ``norms`` the ``(N, my, mx)`` window norms the projections divide by.
+    Where :func:`strided_alignment` holds, ``x``'s columns are reversed
+    within each band chunk here, before the launch; ``norms`` come from
+    the frames as they are.
     """
     geom = tiles.geom
     N, H, Wx = x.shape
@@ -470,7 +546,10 @@ def scores_from_tiles(x: Array, slabs: Array, tiles, norms: Array, *,
     td = geom.block_d
     my, mx = norms.shape[1:]
     # repro-lint: disable=RA001 (td is a static aux field of the tile pytree — concrete at trace time)
-    assert h_b == h and slab_len == td + W - 1, (slabs.shape, td, W)
+    assert h_b == h and slab_len == slab_width(td, W), (slabs.shape, td, W)
+    strided = strided_alignment(W, packed=packed)
+    if strided:
+        x = _reverse_chunks(x)
 
     per_stream = tiles.cpos_t.ndim == 4
     C = 0
@@ -493,7 +572,7 @@ def scores_from_tiles(x: Array, slabs: Array, tiles, norms: Array, *,
 
     kern = functools.partial(
         _score_kernel, h=h, w=w, stride=stride, W=W, my=my, mx=mx, td=td,
-        nonlinearity=nonlinearity, packed=packed)
+        nonlinearity=nonlinearity, packed=packed, strided=strided)
     tile = lambda n, j: (j, 0, 0)
     dpos, dneg, qq = pl.pallas_call(
         kern,

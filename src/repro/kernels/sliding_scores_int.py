@@ -16,17 +16,19 @@ Why the integer path is *structurally* different (not just a dtype swap):
   frame row with an ``O(h*(W+mx))``-step scalar prefix-sum loop (the
   systolic FIFO in loop form). The int kernel instead stores only the
   int8-quantized *base* slabs — the same circularly padded
-  ``(n_dt, h, TD + W - 1)`` rows the float geometry keeps — and
+  ``(n_dt, h, slab_width(TD, W))`` rows the float geometry keeps — and
   materializes every shifted view **inside** the grid step: one int8 MXU
-  matmul ``codesᵀ (W, h) @ slabs (h, TD + W - 1)`` with int32
+  matmul ``codesᵀ (W, h) @ slabs (h, ~TD + W)`` with int32
   accumulation folds the ``h`` reused rolled products per column (summing
   over base rows *before* the shift is valid because shift extraction is
   linear; codes are offset by -128 into int8 and the offset added back
-  exactly), then ``log2(W)`` vectorized roll+select passes align row
-  ``i`` by ``i`` so the per-column rolled sums ``G (W, TD)`` fall out as
-  diagonals, and each fragment window sums its ``w`` rows of ``G``. This
-  is the float kernel's body (:mod:`repro.kernels.sliding_scores`) on
-  integer operands — one kernel serves every precision. The live set is
+  exactly), then one strided lane rotate (unpacked codes in whole
+  128-column chunks) or ``log2(W)`` vectorized roll+select passes align
+  row ``i`` by its column so the per-column rolled sums ``G (W, TD)``
+  fall out as diagonals, and each fragment window sums its ``w`` rows of
+  ``G``. This is the float kernel's body
+  (:mod:`repro.kernels.sliding_scores`) on integer operands — one kernel
+  serves every precision. The live set is
   ``O(window)`` in ``W`` — base slabs + a bounded per-chunk scratch —
   never the old all-``W`` pre-expanded ``(h*W, TD)`` operand whose VMEM
   footprint grew linearly in ``W`` and overran the budget exactly at
@@ -118,15 +120,15 @@ class IntScoreGeometry:
     """Class-independent int-kernel precompute (see module docstring).
 
     ``slabs_q`` is the quantized **base** slab — the same circularly padded
-    ``(n_dt, h, TD + W - 1)`` layout as the float
+    ``(n_dt, h, slab_width(TD, W))`` layout as the float
     :class:`~repro.kernels.sliding_scores.ScoreGeometry`, int8-quantized
     with the shared ``slab_scale`` (``mode="int8"``) or sign-quantized to
     ±1 with ``slab_scale = mean |slab|`` (``mode="binary"``). Every
     shifted view ``slabs_q[dt, r, i + j]`` the projection needs is built
     *inside* the kernel by rolling — nothing grows with ``W`` beyond the
-    ``W - 1`` halo columns.
+    ``W`` halo columns, rounded up to a lane multiple.
     """
-    slabs_q: Array     # (n_dt, h, TD + W - 1) int8 quantized base slabs
+    slabs_q: Array     # (n_dt, h, slab_width(TD, W)) int8 base slabs
     bias_t: Array      # (n_dt, mx, TD) f32 pre-rotated RFF bias tiles
     idx: Array         # (n_dt, mx, TD) i32 rotation gather into a (D,) vec
     valid: Array       # (n_dt, 1, TD) f32: 1 on real components, 0 on pad
@@ -195,7 +197,7 @@ def int_datapath_bounds(adc_bits: int, H: int, W: int, h: int, w: int,
     guard for the expanded-slab blow-up this layout replaced):
 
     * ``vmem_bytes`` — the rolling-shift layout: codes block + base slabs
-      ``h * (TD + W - 1)`` + the bounded ``O(W_CHUNK * TD)`` roll
+      ``h * slab_width(TD, W)`` + the bounded ``O(W_CHUNK * TD)`` roll
       scratch + bias/class/acc tiles. O(window) in ``W``.
     * ``vmem_expanded_bytes`` — what the old all-``W`` pre-expanded
       ``(h*W, TD)`` slab operand would have needed at the same config:
@@ -219,7 +221,7 @@ def int_datapath_bounds(adc_bits: int, H: int, W: int, h: int, w: int,
     mx = max((W - w) // stride + 1, 1)
     wc = min(W, _ss.W_CHUNK)
     codes_bytes = H * (W // 2 if packed else W)           # uint8 wire codes
-    slab_bytes = h * (td + W - 1)                         # int8 base slabs
+    slab_bytes = h * _ss.slab_width(td, W)                # int8 base slabs
     scratch_bytes = 3 * wc * (td + wc - 1) * 4            # P + roll + select
     common = (codes_bytes                                 # codes block
               + mx * td * 4 + td * 4                      # f32 bias + valid
@@ -294,11 +296,14 @@ def precompute_geometry_int(B0: Array, b: Array, *, W: int, w: int,
         raise ValueError(f"mode must be one of {INT_MODES}, got {mode!r}")
     geom = _ss.precompute_geometry(B0, b, W=W, w=w, stride=stride,
                                    block_d=block_d)
+    # the scales weigh the columns a kept lane reads: the slab's lane
+    # padding repeats base columns and would shift the binary mean
+    read = geom.slabs[..., :geom.block_d + W - 1]
     if mode == "binary":
-        scale = jnp.maximum(jnp.mean(jnp.abs(geom.slabs)), 1e-12)
+        scale = jnp.maximum(jnp.mean(jnp.abs(read)), 1e-12)
         slabs_q = jnp.where(geom.slabs >= 0, 1, -1).astype(jnp.int8)
     else:
-        scale = jnp.maximum(jnp.max(jnp.abs(geom.slabs)), 1e-12) / _QMAX
+        scale = jnp.maximum(jnp.max(jnp.abs(read)), 1e-12) / _QMAX
         slabs_q = _quantize_sym(geom.slabs, scale)
     return IntScoreGeometry(slabs_q=slabs_q, bias_t=geom.bias_t,
                             idx=geom.idx, valid=geom.valid,
@@ -475,9 +480,11 @@ def _int_scores_shared(codes, geom: IntScoreGeometry, cpos_t, cneg_t, *,
     ky = jnp.arange(my) * stride
     blocks = codes[:, ky[:, None] + jnp.arange(h)[None, :], :]  # (N,my,h,W)
 
-    # same reuse core as the kernel, vmapped over (row-band, D-tile) and
-    # mapped over frames in small batches: the per-band products are
-    # (W, TD + W - 1) int32 each, too many to hold for a whole chunk
+    # same reuse core as the kernel, with the log-step alignment (the
+    # strided rotate has no batching rule), vmapped over (row-band,
+    # D-tile) and mapped over frames in small batches: the per-band
+    # products are (W_CHUNK, TD + W_CHUNK - 1) int32 each, too many to
+    # hold for a whole chunk
     acc = jax.lax.map(jax.vmap(lambda blk: jax.vmap(
         lambda slab: _ss._window_acc(blk, slab, W=W, td=td, w=w,
                                      stride=stride, mx=mx))(geom.slabs_q)),

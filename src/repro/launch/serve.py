@@ -58,6 +58,11 @@ step's outputs) and ``.hp_capture``. Counters: ``serve.h2d_bytes``, every
 array the service puts on the device (dispatch's, and the raw
 super-chunk the HP capture uploads again), ``serve.arrival_frames``
 delivered, ``serve.scored_frames`` (slot-frames the step ran),
+``serve.strided_align_frames`` (of them, scored by a kernel that aligns
+its band products with one strided lane rotate; derived from the step's
+backend, precision and frame width through the kernel's own choice,
+:func:`repro.kernels.sliding_scores.strided_alignment`, not read back
+from the compiled kernel),
 ``serve.sampled_frames`` (of them, converted by an active sensor's LP
 ADC) and ``serve.hp_frames`` captured. None of it waits for the device.
 
@@ -86,6 +91,7 @@ from repro.core.sensor_control import (CaptureConfig, CaptureLog,
                                        ControllerConfig,
                                        assemble_capture_log, decimation)
 from repro.distributed import sharding as shlib
+from repro.kernels import sliding_scores as kernel_ss
 from repro.launch import telemetry
 from repro.sensing import adc as adc_sim
 from repro.sensing import fleet as fleet_mod
@@ -566,6 +572,11 @@ class FleetService:
                     frames, self._state, m.B0, m.b, tiles, self._t_score,
                     self._n_valid, lab, mask)
             telemetry.count("serve.scored_frames", S * C)
+            # the same function scores_from_tiles asks; int4 is the one
+            # precision the step feeds the kernel packed
+            if self.backend == "pallas" and kernel_ss.strided_alignment(
+                    W, packed=self.precision == "int4"):
+                telemetry.count("serve.strided_align_frames", S * C)
             self._state = new_state
             self._seq += 1
             rec = _InFlight(

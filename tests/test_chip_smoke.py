@@ -7,6 +7,7 @@ time is spent. The mesh phase needs four devices
 (``XLA_FLAGS=--xla_force_host_platform_device_count=4`` or more).
 """
 
+import dataclasses
 import importlib.util
 import pathlib
 import sys
@@ -16,6 +17,7 @@ import numpy as np
 import pytest
 
 from repro.configs import hubert_xlarge, hypersense as hs_config
+from repro.kernels import sliding_scores
 from repro.launch.compile_cache import enable_compile_cache
 
 jax.config.update("jax_platform_name", "cpu")
@@ -114,5 +116,23 @@ def test_mesh_phase_matches_one_device(gate, mesh_shape, dim, block_d):
         model = chip_smoke.random_gate(CFG, dim, seed=30)
     res = chip_smoke.mesh_phase(model, streams, mesh_shape, chunk=C,
                                 block_d=block_d)
+    assert res["bitwise"]
+    assert (res["sensor_shards"], res["hyperdim_shards"]) == mesh_shape
+
+
+@pytest.mark.skipif(jax.device_count() < 4,
+                    reason="needs 4 devices (XLA_FLAGS=--xla_force_host_"
+                           "platform_device_count=4)")
+@pytest.mark.parametrize("mesh_shape,dim", [((4, 1), 256), ((2, 2), 512)])
+def test_mesh_phase_on_the_strided_alignment(mesh_shape, dim):
+    """Frames a whole band chunk wide take the strided lane rotate; the
+    sharded fleet on that path still equals one device bitwise."""
+    cfg = dataclasses.replace(CFG, frame_h=64, frame_w=128, fragment=16,
+                              stride=8)
+    assert sliding_scores.strided_alignment(cfg.frame_w)
+    model = chip_smoke.random_gate(cfg, dim, seed=30)
+    streams = chip_smoke.make_streams(cfg, 4, 2 * C, seed=10)
+    res = chip_smoke.mesh_phase(model, streams, mesh_shape, chunk=C,
+                                block_d=128)
     assert res["bitwise"]
     assert (res["sensor_shards"], res["hyperdim_shards"]) == mesh_shape

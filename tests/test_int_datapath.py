@@ -252,6 +252,29 @@ def test_int4_packed_matches_unpacked_bitwise():
                                rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("W", [128, 256])
+def test_int_strided_kernel_matches_log_step_bitwise(W):
+    """Unpacked codes in whole 128-column chunks take the strided
+    alignment, packed ones the log-step: the int32 sums are exact, so
+    the two kernels give the same scores bit for bit."""
+    N, H, D, h, w, stride = 2, 10, 128, 4, 20, 12
+    _, codes, B0, b, C = make_inputs(120, N, H, W, D, h, bits=4)
+    assert k_ss.strided_alignment(W)
+    assert not k_ss.strided_alignment(W, packed=True)
+    tiles = k_int.precompute_tiles_int(B0, b, C, W=W, w=w, stride=stride,
+                                       block_d=128)
+    got = k_int.fragment_scores_batch_int(codes, tiles, h=h, w=w,
+                                          stride=stride, interpret=True)
+    log_step = k_int.fragment_scores_batch_int(
+        adc.pack_nibbles(codes), tiles, h=h, w=w, stride=stride,
+        interpret=True, packed=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(log_step))
+    want = k_int.fragment_scores_batch_int_ref(codes, tiles, h=h, w=w,
+                                               stride=stride)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+
+
 def test_binary_mode_kernel_matches_oracle():
     """mode="binary": slabs and class tiles really are ±1, the kernel
     still matches the quantized-operand oracle, and scores are finite."""

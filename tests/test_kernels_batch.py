@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core import encoding, hypersense
 from repro.kernels import ops
@@ -108,6 +109,62 @@ def test_batch_odd_shapes_match_jnp(H, W, h, w, stride):
     got = k_ss.fragment_scores_batch(frames, tiles, h=h, w=w, stride=stride,
                                      interpret=True)
     assert got.shape == (N, my, mx)
+    for i in range(N):
+        want = hypersense.fragment_score_map(frames[i], C, B0, b, h=h, w=w,
+                                             stride=stride, backend="jnp")
+        np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("W,td", [
+    (128, 512),    # the paper's band chunk: (128, 640) products
+    (256, 512),    # two chunks: the second reads slab columns [128, 768)
+])
+def test_strided_aligner_bitwise_equals_log_step(W, td):
+    """In a Pallas kernel (interpret mode): one strided lane rotate of
+    the row-reversed product gives, row for row, exactly the bits of the
+    log-step roll-and-select of the product itself."""
+    h, cw = 8, k_ss.W_CHUNK
+    L = -(-(td + cw) // 128) * 128
+    n = W // cw
+    band = jax.random.normal(key(30), (h, W))
+    slabs = jax.random.normal(key(31), (h, k_ss.slab_width(td, W)))
+
+    def kern(band_ref, slab_ref, want_ref, got_ref):
+        for k in range(n):
+            c0 = k * cw
+            p = k_ss._project(band_ref[:, c0:c0 + cw],
+                              slab_ref[:, c0:c0 + L], planes=0)
+            shift = jax.lax.broadcasted_iota(jnp.int32, p.shape, 0)
+            want_ref[k] = k_ss._rows_to_diagonals(p, shift,
+                                                  max_shift=cw - 1, td=td)
+            got_ref[k] = k_ss._rotate_to_diagonals(p[::-1], td=td)[::-1]
+
+    out = jax.ShapeDtypeStruct((n, cw, td), jnp.float32)
+    want, got = pl.pallas_call(kern, out_shape=[out, out],
+                               interpret=True)(band, slabs)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    p = np.asarray(band[:, :cw]).T.astype(np.float64) @ np.asarray(
+        slabs[:, :L], np.float64)
+    i, j = np.ogrid[:cw, :td]
+    np.testing.assert_allclose(np.asarray(want[0]), p[i, i + j],
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("W", [128, 256])
+def test_batch_strided_path_matches_jnp(W):
+    """Frames in whole 128-column chunks take the strided alignment
+    (columns reversed per chunk before the launch): the scores still
+    match the jnp oracle, across a chunk boundary too."""
+    N, H, D, h, w, stride = 2, 10, 256, 4, 24, 20
+    assert k_ss.strided_alignment(W)
+    frames = jax.random.uniform(key(32), (N, H, W))
+    B0, b = encoding.make_perm_base_rows(key(33), h, D)
+    C = jax.random.normal(key(34), (2, D))
+    tiles = k_ss.precompute_tiles(B0, b, C, W=W, w=w, stride=stride,
+                                  block_d=128)
+    got = k_ss.fragment_scores_batch(frames, tiles, h=h, w=w, stride=stride,
+                                     interpret=True)
     for i in range(N):
         want = hypersense.fragment_score_map(frames[i], C, B0, b, h=h, w=w,
                                              stride=stride, backend="jnp")

@@ -8,15 +8,21 @@
   tick collected by dispatch's back-pressure is not inside its span;
 * ``CascadeService`` records one launch and one wait span per batch and
   counts its blocks' bytes;
-* the gate step's named scopes reach the lowered program.
+* the gate step's named scopes reach the lowered program;
+* the scoring kernel takes the strided alignment by shape, and
+  ``serve.strided_align_frames`` counts the slot-frames it scores.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import hypersense as hs_config
 from repro.core import encoding, hypersense
 from repro.core.sensor_control import CaptureConfig, ControllerConfig
+from repro.kernels import sliding_scores as k_ss
+from repro.kernels import sliding_scores_int as k_int
 from repro.launch import telemetry
 from repro.launch.serve import FleetService
 
@@ -188,3 +194,83 @@ def test_gate_step_carries_the_named_scopes():
     text = svc.compiled_step_text()
     for scope in ("tile_fold", "cosine_epilogue", "control_scan"):
         assert f"/{scope}/" in text, scope
+
+
+def _primitives(jaxpr):
+    """Every primitive name in ``jaxpr``, nested jaxprs (kernels) too."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _primitives(sub)
+
+
+PAPER = hs_config.config()
+
+
+@pytest.mark.parametrize("precision,H,W,frag,stride,D,block_d,strided", [
+    ("float32", PAPER.frame_h, PAPER.frame_w, PAPER.fragment, PAPER.stride,
+     PAPER.dim, 512, True),                        # the paper's geometry
+    ("int8", PAPER.frame_h, PAPER.frame_w, PAPER.fragment, PAPER.stride,
+     PAPER.dim, 512, True),
+    ("float32", 17, 23, 4, 3, 64, 32, False),      # an odd shape
+    ("int4", PAPER.frame_h, PAPER.frame_w, PAPER.fragment, PAPER.stride,
+     PAPER.dim, 512, False),                       # packed nibbles
+])
+def test_kernel_alignment_path_is_chosen_by_shape(precision, H, W, frag,
+                                                  stride, D, block_d,
+                                                  strided):
+    packed = precision == "int4"
+    assert k_ss.strided_alignment(W, packed=packed) == strided
+
+    def build():
+        B0, b = encoding.make_perm_base_rows(jax.random.PRNGKey(0), frag, D)
+        kw = dict(W=W, w=frag, stride=stride, block_d=block_d)
+        chvs = jnp.ones((2, D), jnp.float32)
+        if precision == "float32":
+            return k_ss.precompute_tiles(B0, b, chvs, **kw)
+        return k_int.precompute_tiles_int(B0, b, chvs, **kw)
+
+    tiles = jax.eval_shape(build)
+    kw = dict(h=frag, w=frag, stride=stride)
+    if precision == "float32":
+        x = jax.ShapeDtypeStruct((2, H, W), jnp.float32)
+        fn = lambda f, t: k_ss.fragment_scores_batch(f, t, **kw)
+    else:
+        x = jax.ShapeDtypeStruct((2, H, W // 2 if packed else W), jnp.uint8)
+        fn = lambda f, t: k_int.fragment_scores_batch_int(f, t, packed=packed,
+                                                          **kw)
+    prims = set(_primitives(jax.make_jaxpr(fn)(x, tiles).jaxpr))
+    assert "pallas_call" in prims
+    assert ("roll" in prims) == strided
+
+
+def _square_model(frag, D):
+    B0, b = encoding.make_perm_base_rows(jax.random.PRNGKey(1), frag, D)
+    chvs = jax.random.normal(jax.random.PRNGKey(2), (2, D))
+    return hypersense.HyperSenseModel(chvs, B0, b, frag, frag, frag,
+                                      t_score=-0.05, t_detection=1)
+
+
+@pytest.mark.parametrize("hw,model,block_d,strided", [
+    ((128, 128), lambda: _square_model(64, 128), 128, True),
+    (HW, make_model, 32, False),
+], ids=["strided", "log-step"])
+def test_strided_align_frames_counts_the_strided_kernel(hw, model, block_d,
+                                                        strided):
+    telemetry.reset()
+    svc = FleetService(model(), CFG, n_slots=2, chunk_size=C,
+                       backend="pallas", adc_bits=4, block_d=block_d)
+    svc.attach(0)
+    svc.attach(1)
+    trace = np.random.default_rng(0).normal(
+        size=(2, C, *hw)).astype(np.float32)
+    svc.dispatch({0: trace[0], 1: trace[1]})
+    svc.dispatch({0: trace[1]})
+    svc.flush()
+    cnt = telemetry.snapshot()["counters"]
+    assert cnt["serve.scored_frames"] == 2 * svc.n_slots * C
+    assert cnt.get("serve.strided_align_frames", 0) == (
+        cnt["serve.scored_frames"] if strided else 0)

@@ -5,8 +5,9 @@ compiler refuses: unaligned tiles, unsupported operand types, more fast
 memory than a kernel may use. These tests compile — without a chip, for
 a described v5e topology — the scoring kernel of one gate chunk at the
 paper's operating point (128x128 frames, 96x96 fragments, stride 8,
-D=5000) in every precision the fleet serves, and the hubert-xlarge
-detector step at its published width. Nothing runs; a compile that
+D=5000) in every precision the fleet serves, the float32 kernel on
+frames two band chunks wide, and the hubert-xlarge detector step at its
+published width. Nothing runs; a compile that
 passes is not a chip run.
 
 The topology is described inside a module fixture, never at import: only
@@ -54,14 +55,13 @@ def _on(sharding, tree):
         tree)
 
 
-def _paper_tiles(precision: str):
+def _paper_tiles(precision: str, W: int = CFG.frame_w):
     """Abstract kernel tiles of a paper-width gate (shapes only)."""
     def build():
         B0, b = encoding.make_perm_base_rows(jax.random.PRNGKey(0),
                                              CFG.fragment, CFG.dim)
         chvs = jnp.ones((2, CFG.dim), jnp.float32)
-        kw = dict(W=CFG.frame_w, w=CFG.fragment, stride=CFG.stride,
-                  block_d=512)
+        kw = dict(W=W, w=CFG.fragment, stride=CFG.stride, block_d=512)
         if precision == "float32":
             return ss.precompute_tiles(B0, b, chvs, **kw)
         return ssi.precompute_tiles_int(
@@ -90,6 +90,24 @@ def test_gate_kernel_compiles_at_paper_width(one_chip, precision):
     assert "tpu_custom_call" in compiled.as_text()
     my = (H - CFG.fragment) // CFG.stride + 1
     assert compiled.out_info.shape == (CHUNK, my, my)
+
+
+def test_float_kernel_compiles_two_chunks_wide(one_chip):
+    """Frames 256 columns wide: two band chunks, each aligned by one
+    strided lane rotate on a lane-aligned product, which Mosaic accepts
+    only at a lane-multiple width; interpret mode cannot tell."""
+    H, W = CFG.frame_h, 2 * CFG.frame_w
+    assert ss.strided_alignment(W)
+    tiles = _on(one_chip, _paper_tiles("float32", W))
+    frames = jax.ShapeDtypeStruct((CHUNK, H, W), jnp.float32,
+                                  sharding=one_chip)
+    kw = dict(h=CFG.fragment, w=CFG.fragment, stride=CFG.stride)
+    compiled = jax.jit(lambda f, t: ss.fragment_scores_batch(f, t, **kw)
+                       ).lower(frames, tiles).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    my = (H - CFG.fragment) // CFG.stride + 1
+    mx = (W - CFG.fragment) // CFG.stride + 1
+    assert compiled.out_info.shape == (CHUNK, my, mx)
 
 
 def test_detector_step_compiles_at_full_width(one_chip):

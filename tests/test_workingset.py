@@ -68,8 +68,10 @@ def test_geometry_stores_no_expanded_operand():
                                          block_d=td)
     assert not hasattr(geom, "slab_mat")
     n_dt = D // td
-    assert geom.slabs_q.shape == (n_dt, h, td + W - 1)
-    # per D-tile slab bytes: h * (td + W - 1), nowhere near h * W * td
+    # td + W columns rounded up to a lane multiple (the strided alignment
+    # reads lane-aligned products)
+    assert geom.slabs_q.shape == (n_dt, h, 128)
+    # per D-tile slab bytes: h * slab_width, nowhere near h * W * td
     assert geom.slabs_q[0].size < h * W * td / 8
 
 
