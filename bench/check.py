@@ -21,7 +21,8 @@ configuration file:
   of captured frames drawn from the seed;
 - ``logit_gap`` and ``logit_rms``: over a sample of detected frames drawn
   from the seed, the largest and the root-mean-square |logit - reference
-  logit|, over the RMS of the reference logits;
+  logit|, over the RMS of the reference logits. The reference is the
+  detector module the configuration names (``bench/reference``);
 - ``logit_noise_ratio``: the RMS |logit - reference logit| over the RMS
   by which the reference's own logits move between float32 and bfloat16
   on the same frames. Random deep encoders differ from seed to seed in
@@ -41,9 +42,8 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
-from bench import generator
+from bench import generator, reference
 from bench.reference import gate as gate_ref
-from bench.reference import hubert as hubert_ref
 
 #: detected frames the reference re-runs through the detector
 LOGIT_SAMPLE = 64
@@ -126,8 +126,9 @@ class Reference:
     def logits(self, keys, mode: str = "bfloat16") -> np.ndarray:
         if not keys:
             return np.zeros((0, 0), np.float32)
-        return hubert_ref.logits(self.sess.det_params, self.hp(list(keys)),
-                                 self.sess.d, mode=mode)
+        det = reference.detector(self.sess.cell.config)
+        return det.logits(self.sess.det_params, self.hp(list(keys)),
+                          self.sess.d, mode=mode)
 
     def bf16_noise(self, keys) -> float:
         """RMS of the reference's own float32 - bfloat16 logits on ``keys``:

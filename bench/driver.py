@@ -19,9 +19,8 @@ import time
 import jax
 import numpy as np
 
-from bench import generator
+from bench import generator, reference
 from bench.reference import gate as gate_ref
-from bench.reference import hubert as hubert_ref
 
 #: ticks served before the window: they compile every program the window
 #: runs (the gate step, the ADC convert, the HP capture, the backbone)
@@ -184,8 +183,9 @@ class Session:
         from repro.launch.cascade import CascadeService
 
         d, g = self.d, self.g
+        det_ref = reference.detector(self.cell.config)
         with self._step("detector_init"):
-            self.det_params = hubert_ref.make_weights(
+            self.det_params = det_ref.make_weights(
                 generator.jax_key(self.seed, 20), g, d)
             jax.block_until_ready(self.det_params)
         with self._step("cascade_build"):

@@ -21,7 +21,8 @@ precision below the stated one.
 
 The weights are made here from the seed, on the device, in one call, in
 float32 (the type the cascade holds them in), in the tree layout the
-cascade takes.
+cascade takes. The module keeps the detector references' contract
+(``bench/reference/__init__.py``).
 """
 
 from __future__ import annotations
@@ -79,6 +80,27 @@ def make_weights(key, g: dict, d: dict) -> dict:
               d["d_model"] // d["n_heads"], d["d_ff"], d["vocab"],
               d["patch"] * d["patch"], tokens(g, d))
     return _init(key, shapes=shapes)
+
+
+def frame_flops(g: dict, d: dict) -> float:
+    """Forward FLOPs of the encoder layers for one real frame.
+
+    Per token and layer: Q, K, V and output projections (8 d^2), the
+    feed-forward up and down projections (4 d d_ff), and the attention
+    scores and weighted sum over ``s`` tokens (4 s d). With d_ff = 4 d this
+    is the familiar 24 d^2 + 4 s d; for hubert-xlarge at 128x128 frames in
+    8x8 patches, 256 tokens x 48 layers come to about 0.499 TFLOP.
+    """
+    s = tokens(g, d)
+    dm, f = d["d_model"], d["d_ff"]
+    per_token = 8 * dm * dm + 4 * dm * f + 4 * s * dm
+    return float(s * d["n_layers"] * per_token)
+
+
+def tiny(d: dict) -> dict:
+    """The keys of ``d`` a CPU test shrinks: two layers of width 64."""
+    return {"n_layers": 2, "d_model": 64, "n_heads": 4, "kv_heads": 4,
+            "d_ff": 128, "vocab": 64, "batch": 2}
 
 
 def _layer_norm(x, p, cd):

@@ -36,7 +36,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import numpy as np  # noqa: E402
 
-from bench import spec  # noqa: E402
+from bench import program, spec  # noqa: E402
 
 
 def _err(msg: str) -> None:
@@ -92,6 +92,12 @@ def window_lines(sess, win, seconds: float) -> list[str]:
             f"CPU user/sys {cpu[worst, 0]:.2f}/{cpu[worst, 1]:.2f} s "
             f"(p50 {np.median(cpu[:, 0]):.2f}/{np.median(cpu[:, 1]):.2f}); "
             f"{int((gaps > 3 * np.median(gaps)).sum())} over 3x the median")
+        inside = program.longest_spans(a, b)
+        if inside:
+            lines.append("window: in that gap the program's longest spans, "
+                         "ms: " + ", ".join(
+                             f"{k} {v * 1e3:.4f}"
+                             for k, v in sorted(inside.items())))
     if sess.casc is not None:
         lines.append(f"window: {sum(r.hp for r in ticks)} HP frames, "
                      f"{sess.cascade_batches} backbone batches in all")
@@ -133,8 +139,8 @@ def per_layer(sess, win, cell, peaks, trace_dir: str):
     S, C = sess.t.sensors, sess.t.chunk
     inside = lambda x: x is not None and lo <= x <= hi
     ctx = {
-        "trace": tr, "gate": sess.g, "detector": sess.d, "peaks": peaks,
-        "chips": cell.chips,
+        "trace": tr, "config": cell.config, "gate": sess.g,
+        "detector": sess.d, "peaks": peaks, "chips": cell.chips,
         "frames_per_kernel_call": sess.svc.n_slots * C / max(
             len(tr["devices"]), 1),
         "counts": {

@@ -57,3 +57,16 @@ def test_a_program_without_telemetry_gives_none(monkeypatch):
     monkeypatch.delattr(repro.launch, "telemetry", raising=False)
     for base in sorted(PROGRAM_METRICS):
         assert spec.metric_reader(base)({}) is None, base
+
+
+def test_longest_spans_reads_the_program_s_spans_inside_a_gap():
+    from repro.launch import telemetry
+
+    from bench import program
+
+    t0 = time.perf_counter()
+    with telemetry.span("bench_test.gap", 0):
+        time.sleep(0.01)
+    t1 = time.perf_counter()
+    assert 0.01 <= program.longest_spans(t0, t1)["bench_test.gap"] <= t1 - t0
+    assert "bench_test.gap" not in program.longest_spans(t1, t1 + 1.0)

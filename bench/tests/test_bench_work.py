@@ -3,7 +3,7 @@
 import json
 import pathlib
 
-from bench import work
+from bench import reference, work
 
 CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "configs"
 
@@ -24,9 +24,11 @@ def test_gate_bytes_are_the_frame_and_the_partial_sums():
 
 
 def test_hubert_forward_per_frame():
+    """The cascade's detector FLOPs are its reference module's count."""
     c = _cfg("hs-cascade-hubert-xlarge")
     d, s = 1280, 256
     want = s * 48 * (24 * d * d + 4 * s * d)
     assert work.detector_tokens(c["gate"], c["detector"]) == s
-    assert work.backbone_frame_flops(c["gate"], c["detector"]) == want
+    flops = reference.detector(c).frame_flops(c["gate"], c["detector"])
+    assert flops == want
     assert abs(want - 0.499e12) < 0.001e12

@@ -1,8 +1,8 @@
 """Cells of BENCHMARK.json cut to a size the CPU runs in seconds.
 
 Only the sizes change (32x32 frames, 8x8 fragments, D=256, three sensors,
-two encoder layers of width 64); the traffic's loop, capture control and
-the configuration's limits are the cell's own.
+and the detector's ``tiny`` keys from its reference module); the traffic's
+loop, capture control and the configuration's limits are the cell's own.
 """
 
 from __future__ import annotations
@@ -10,7 +10,7 @@ from __future__ import annotations
 import copy
 import dataclasses
 
-from bench import spec
+from bench import reference, spec
 from bench.peaks import Peaks
 
 #: stands in for a chip's peaks where a CPU test reads a roofline
@@ -36,15 +36,16 @@ def benchmark() -> dict:
     return bm
 
 
-def tiny_cell(name: str) -> spec.Cell:
-    c = spec.cell(name, benchmark())
+def tiny_cell(name: str, bm: dict | None = None) -> spec.Cell:
+    """The cell ``name`` of ``bm`` (default: :func:`benchmark`), cut to the
+    CPU's size."""
+    c = spec.cell(name, benchmark() if bm is None else bm)
     cfg = copy.deepcopy(c.config)
     cfg["gate"].update(frame_h=32, frame_w=32, fragment=8, stride=4, dim=256,
                        block_d=128)
     cfg["training"].update(frames=16)
     if "detector" in cfg:
-        cfg["detector"].update(n_layers=2, d_model=64, n_heads=4, kv_heads=4,
-                               d_ff=128, vocab=64, batch=2)
+        cfg["detector"].update(reference.detector(cfg).tiny(cfg["detector"]))
     t = dataclasses.replace(c.traffic, sensors=3, chunk=4, pool_streams=2,
                             pool_frames=16,
                             frame_hz=40.0 if c.traffic.loop == "open" else 0.0)

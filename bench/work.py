@@ -3,7 +3,9 @@
 These counts are the algorithm's, not the implementation's: padding the
 hypervector width up to whole tiles, the slab overlap of a tile, pad
 rows of a detector batch and recomputed work never count. So a later
-change to how a step is implemented leaves its work unchanged.
+change to how a step is implemented leaves its work unchanged. A
+detector's forward FLOPs are its reference module's ``frame_flops``
+(``bench/reference``).
 """
 
 from __future__ import annotations
@@ -38,17 +40,3 @@ def detector_tokens(g: dict, d: dict) -> int:
     """Patch tokens one frame unrolls to."""
     return (g["frame_h"] // d["patch"]) * (g["frame_w"] // d["patch"])
 
-
-def backbone_frame_flops(g: dict, d: dict) -> float:
-    """Forward FLOPs of the encoder layers for one real frame.
-
-    Per token and layer: Q, K, V and output projections (8 d^2), the
-    feed-forward up and down projections (4 d d_ff), and the attention
-    scores and weighted sum over ``s`` tokens (4 s d). With d_ff = 4 d this
-    is the familiar 24 d^2 + 4 s d; for hubert-xlarge at 128x128 frames in
-    8x8 patches, 256 tokens x 48 layers come to about 0.499 TFLOP.
-    """
-    s = detector_tokens(g, d)
-    dm, f = d["d_model"], d["d_ff"]
-    per_token = 8 * dm * dm + 4 * dm * f + 4 * s * dm
-    return float(s * d["n_layers"] * per_token)
