@@ -25,7 +25,7 @@ SHARD2_FILES = tests/test_arch_smoke.py tests/test_cells.py \
 	tests/test_data_pipeline.py tests/test_gate.py tests/test_hdc_core.py \
 	tests/test_hypersense.py tests/test_online.py tests/test_system.py \
 	tests/test_train_runtime.py tests/test_ci_shards.py \
-	tests/test_analysis.py
+	tests/test_analysis.py tests/test_moonlight.py
 
 # PYTEST_EXTRA lets CI attach coverage flags (see .github/workflows/ci.yml);
 # plain local runs need no pytest-cov install.

@@ -27,6 +27,7 @@ ARCH_IDS = [
     "deepseek-67b",
     "xlstm-350m",
     "internvl2-76b",
+    "moonlight-16b-a3b",
 ]
 
 #: the paper's own workload (HyperSense sensing config)

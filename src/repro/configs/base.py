@@ -23,16 +23,31 @@ class ModelConfig:
     vocab: int
     head_dim: int = 0                 # 0 -> d_model // n_heads
     norm: str = "rmsnorm"
+    norm_eps: float = 0.0             # 0 -> the norm's own default
     qk_norm: bool = False
     rope_theta: float = 10000.0
     causal: bool = True
     is_encoder: bool = False
     activation: str = "silu"
+    # --- multi-head latent attention (MLA; deepseek_v3) ---
+    kv_lora_rank: int = 0             # 0 -> grouped-query attention
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0                # held here (all: no router width)
     top_k: int = 0
     capacity_factor: float = 1.25
     moe_dispatch_int8: bool = False
+    # a router width > 0 selects the dropless layer over the held
+    # experts ``expert_offset ..+ n_experts`` of ``router_experts``;
+    # 0 keeps the capacity-bounded MoE over all ``n_experts``
+    router_experts: int = 0
+    expert_offset: int = 0
+    n_shared_experts: int = 0         # each ``d_ff`` wide
+    routed_scaling_factor: float = 1.0
+    first_dense_layers: int = 0       # leading dense layers, unscanned
+    dense_d_ff: int = 0               # their FFN width (d_ff: per expert)
     # --- SSM / hybrid ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -89,11 +104,12 @@ SMOKE_SHAPE = ShapeConfig("smoke", 64, 2, "train")
 def applicable_shapes(cfg: ModelConfig) -> dict[str, ShapeConfig | None]:
     """Which of the 4 assigned shapes run for this arch (None = skip).
 
-    Skip rules (DESIGN.md §4): encoder-only archs have no decode step;
+    Skip rules: encoder-only archs, and latent-attention archs (whose
+    attention here runs full sequences only), have no decode step;
     long_500k runs only for sub-quadratic (ssm/hybrid) archs.
     """
     out: dict[str, ShapeConfig | None] = dict(SHAPES)
-    if cfg.is_encoder:
+    if cfg.is_encoder or cfg.kv_lora_rank:
         out["decode_32k"] = None
         out["long_500k"] = None
     if cfg.family not in ("ssm", "hybrid"):

@@ -17,12 +17,15 @@ that closes the loop:
   (:meth:`~CascadeService.compile_count` witnesses, same contract as
   ``FleetService``).
 
-* **Bitwise batching.** The detector step
+* **Batching.** For a backbone without experts the detector step
   (:func:`repro.launch.steps.build_detector_cell`) maps the batch axis
   with ``jax.lax.map``, so a frame's logits are bit-identical whether
   it arrives alone, padded, or co-batched mid-burst — batched service
   output ≡ eager per-frame evaluation (:meth:`~CascadeService.eager`),
-  gated in ``benchmarks/fig16_speedup.py --system --check``.
+  gated in ``benchmarks/fig16_speedup.py --system --check``. A backbone
+  with experts runs the whole block as one batch, so its expert layers
+  read their weights once a batch; its dropless routing keeps a frame's
+  logits free of the other rows, up to the rounding of batched products.
 
 * **Async double-buffering** (PR-8 pattern). ``device_put`` starts the
   H2D copy immediately and the jitted step returns once *enqueued*, so
@@ -33,7 +36,13 @@ that closes the loop:
   ``cascade.launch`` (block assembly, upload, step enqueue) and
   ``cascade.wait`` (the host blocked on its logits) in
   :mod:`repro.launch.telemetry`, under the batch's ``seq``, and adds the
-  block's bytes to the counter ``cascade.h2d_bytes``.
+  block's bytes to the counter ``cascade.h2d_bytes``. A backbone with
+  held experts also counts its routing, from the pair counts downloaded
+  with the logits: ``cascade.routed_pairs`` (every row's tokens, pad rows
+  included, × top-k × expert layers), ``cascade.local_pairs`` (the pairs
+  the held experts computed) and ``cascade.local_pairs_peak`` (per expert
+  layer, the busiest held expert's pairs × the experts held: what a
+  grouped matmul padded to its largest group would compute).
 
 * **System accounting.** :meth:`~CascadeService.backbone_cost` reads
   the compiled step's XLA ``cost_analysis()`` (the roofline model's
@@ -84,6 +93,7 @@ class CascadeBatch:
 class _InFlightBatch:
     seq: int
     logits: Array                  # (batch_size, n_out) device future
+    loads: Array                   # (expert layers, held) pair counts
     rows: list                     # [(sid, abs_idx), ...] valid rows
 
 
@@ -206,9 +216,9 @@ class CascadeService:
             telemetry.count("cascade.h2d_bytes", block.nbytes)
             dev = (jax.device_put(block) if self._mesh is None
                    else jax.device_put(block, self._cell.in_shardings[1]))
-            logits = self._jit(self._params, dev)  # async: enqueued, not run
+            logits, loads = self._jit(self._params, dev)  # enqueued only
         self._pending.append(_InFlightBatch(
-            seq=self._seq, logits=logits,
+            seq=self._seq, logits=logits, loads=loads,
             rows=[(sid, idx) for sid, idx, _ in rows]))
         self._seq += 1
         self.batches += 1
@@ -219,7 +229,9 @@ class CascadeService:
     def _finish(self, rec: _InFlightBatch) -> CascadeBatch:
         with telemetry.span("cascade.wait", rec.seq):
             # repro-lint: disable=RA003 (designed sync point: blocks on the oldest in-flight batch only)
-            logits = np.asarray(rec.logits)        # blocks on THIS batch
+            logits, loads = jax.device_get((rec.logits, rec.loads))
+        if loads.size:
+            self._count_routing(loads)
         m = len(rec.rows)
         return CascadeBatch(
             seq=rec.seq,
@@ -227,6 +239,18 @@ class CascadeService:
             frame_idx=np.asarray([i for _, i in rec.rows], np.int64),
             logits=logits[:m],
             n_padded=self.batch_size - m)
+
+    def _count_routing(self, loads: np.ndarray) -> None:
+        """Routing counters of one batch from its ``(layers, held)``
+        pair counts."""
+        cfg = self.cfg
+        tokens = self.batch_size * steps.detector_seq_len(self.frame_hw,
+                                                          self.patch)
+        telemetry.count("cascade.routed_pairs",
+                        tokens * cfg.top_k * loads.shape[0])
+        telemetry.count("cascade.local_pairs", int(loads.sum()))
+        telemetry.count("cascade.local_pairs_peak",
+                        int(loads.max(axis=1).sum()) * loads.shape[1])
 
     def collect(self) -> CascadeBatch | None:
         """Oldest finished batch (FIFO), or None with nothing in flight."""
@@ -266,8 +290,10 @@ class CascadeService:
 
         Runs each ``(H, W)`` frame alone (row 0 of a zero-padded batch)
         through the SAME jitted step and returns ``(M, n_out)`` logits.
-        The cascade's batched outputs must be bitwise-equal to this —
-        the ``lax.map`` row independence makes it so by construction.
+        Without experts the cascade's batched outputs are bitwise-equal
+        to this — the ``lax.map`` row independence makes it so by
+        construction; with experts they agree up to the rounding of
+        batched products.
         """
         frames = np.asarray(frames, np.float32)
         out = np.empty((frames.shape[0], self.n_out), np.float32)
@@ -276,7 +302,7 @@ class CascadeService:
             block[0] = frames[j]
             dev = (jax.device_put(block) if self._mesh is None
                    else jax.device_put(block, self._cell.in_shardings[1]))
-            out[j] = np.asarray(self._jit(self._params, dev))[0]
+            out[j] = np.asarray(self._jit(self._params, dev)[0])[0]
         return out
 
     def _ensure_compiled(self):

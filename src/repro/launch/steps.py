@@ -230,23 +230,30 @@ def build_detector_cell(cfg: ModelConfig, *, batch: int,
     """Downstream-backbone detector step for the gated cascade.
 
     ``detector_step(params, frames)``: a fixed ``(batch, H, W)`` float32
-    block of HP frames → ``(batch, n_out)`` float32 class logits. Each
+    block of HP frames → ``((batch, n_out)`` float32 class logits,
+    ``(expert layers, n_experts)`` int32 pairs per held expert``)``. Each
     frame is patchified to ``seq = (H/patch)*(W/patch)`` tokens, linearly
     embedded (``params["embedder"]``: ``proj (patch², d_model)`` +
     ``pos (seq, d_model)``), and run through an **embeds-in** LM backbone
-    (``params["backbone"]``); the last position's first ``n_out`` vocab
-    logits are the detection head (at smoke scale the backbone is the
-    hubert-style encoder — the cascade's stand-in for the paper's YOLO
-    detector).
+    (``params["backbone"]``); only the last position is unembedded, and
+    its first ``n_out`` vocab logits are the detection head (at smoke
+    scale the backbone is the hubert-style encoder — the cascade's
+    stand-in for the paper's YOLO detector).
 
-    The batch axis is ``jax.lax.map``, NOT ``vmap``: every row executes
-    the identical unbatched program, so a frame's logits are bitwise
-    independent of its batch position and of whatever else shares the
-    batch — including zero-padded slack rows. That, by construction, is
-    the cascade's parity gate (batched service output ≡ eager per-frame
-    evaluation, ``benchmarks/fig16_speedup.py --system --check``); a
-    vmapped/batched dot would reassociate with the batch extent (see
-    ``fleet._per_stream_fold`` for the precedent).
+    The batch axis takes one of two paths, by whether the config has
+    experts:
+
+    * Without experts, ``jax.lax.map``, NOT ``vmap``: every row executes
+      the identical unbatched program, so a frame's logits are bitwise
+      independent of its batch position and of whatever else shares the
+      batch — including zero-padded slack rows (batched service output ≡
+      eager per-frame evaluation). The pair counts are ``(0, 0)``.
+    * With experts, the whole block is one batch through the trunk, so
+      each expert layer runs every frame's routed pairs at once and reads
+      its weights once a batch; attention stays within each frame. A
+      frame's logits no longer depend on the others' routing (the
+      dropless layer drops nothing), but the batched products may round
+      differently with the rows beside them.
 
     With a ``mesh`` the backbone params shard via ``param_shardings``
     (frames and the tiny embedder replicate); ``mesh=None`` builds an
@@ -264,18 +271,24 @@ def build_detector_cell(cfg: ModelConfig, *, batch: int,
     H, W = frame_hw
     seq = detector_seq_len(frame_hw, patch)
 
-    def one_frame(params, frame):
-        p = frame.reshape(H // patch, patch, W // patch, patch)
-        p = p.transpose(0, 2, 1, 3).reshape(seq, patch * patch)
+    def logits_of(params, frames):
+        """(b, H, W) -> ((b, n_out) logits, pair counts)."""
+        b = frames.shape[0]
+        p = frames.reshape(b, H // patch, patch, W // patch, patch)
+        p = p.transpose(0, 1, 3, 2, 4).reshape(b, seq, patch * patch)
         emb = (p.astype(jnp.float32) @ params["embedder"]["proj"]
                + params["embedder"]["pos"])
-        b1 = Batch(tokens=None, labels=jnp.zeros((1, seq), jnp.int32),
-                   embeds=emb[None].astype(model.compute_dtype))
-        logits, _ = model.forward(params["backbone"], b1)
-        return logits[0, -1, :n_out].astype(jnp.float32)
+        bt = Batch(tokens=None, labels=jnp.zeros((b, seq), jnp.int32),
+                   embeds=emb.astype(model.compute_dtype))
+        logits, loads = model.last_logits(params["backbone"], bt)
+        return logits[:, 0, :n_out].astype(jnp.float32), loads
 
     def detector_step(params, frames):
-        return jax.lax.map(lambda f: one_frame(params, f), frames)
+        if cfg.n_experts:
+            return logits_of(params, frames)
+        logits = jax.lax.map(lambda f: logits_of(params, f[None])[0][0],
+                             frames)
+        return logits, jnp.zeros((0, 0), jnp.int32)
 
     p_abs = {
         "backbone": model.abstract_params(),
@@ -295,7 +308,7 @@ def build_detector_cell(cfg: ModelConfig, *, batch: int,
     }
     return Cell(step_fn=detector_step,
                 in_shardings=(p_sh, _replicated(mesh)),
-                out_shardings=_replicated(mesh),
+                out_shardings=(_replicated(mesh), _replicated(mesh)),
                 abstract_args=(p_abs, f_abs),
                 donate_argnums=())
 

@@ -1,4 +1,5 @@
-"""Grouped-query attention: full (train/prefill) + cached decode step.
+"""Grouped-query attention: full (train/prefill) + cached decode step;
+multi-head latent attention (MLA, deepseek_v3): full sequence only.
 
 Covers every assigned transformer family: MHA (kv=heads), GQA (kv<heads),
 causal and bidirectional, optional QK-norm (Qwen3), RoPE.
@@ -91,7 +92,7 @@ def _sdpa_block(q: Array, k: Array, v: Array, cfg: AttnConfig,
     attn = jax.nn.softmax(scores, axis=-1)
     attn = attn.reshape(b, kv, group, sq, sk)
     out = jnp.einsum("bkgqs,bskh->bqkgh", attn, v.astype(jnp.float32))
-    return out.reshape(b, sq, h, hd).astype(q.dtype)
+    return out.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)
 
 
 def _sdpa(q: Array, k: Array, v: Array, cfg: AttnConfig,
@@ -129,6 +130,81 @@ def full(params: dict, x: Array, cfg: AttnConfig,
     q, k, v = _project_qkv(params, x, cfg, positions)
     out = _sdpa(q, k, v, cfg, positions, positions)
     out = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
+    return shard(out, "act_batch", "act_seq", "act_embed")
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (full sequence)
+# ---------------------------------------------------------------------------
+
+class MLAConfig(NamedTuple):
+    d_model: int
+    n_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    causal: bool = True
+    rope_theta: float = 10000.0
+    q_chunk: int = 1024
+
+
+def mla_spec(cfg: MLAConfig) -> dict:
+    """``wq`` gives each head its nope and rope query dims (no query
+    latent); ``wkv_a`` the latent and the one rotary key all heads share;
+    ``wkv_b`` expands the normed latent to each head's key-nope and value
+    dims."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    return {
+        "wq": P((d, h, qk), ("embed", "heads", "head_dim")),
+        "wkv_a": P((d, r + cfg.qk_rope_head_dim), ("embed", None)),
+        "kv_norm": common.norm_spec(r, "rmsnorm"),
+        "wkv_b": P((r, h, cfg.qk_nope_head_dim + cfg.v_head_dim),
+                   (None, "heads", "head_dim")),
+        "wo": P((h, cfg.v_head_dim, d), ("heads", "head_dim", "embed")),
+    }
+
+
+def _rope_pairs(x: Array, positions: Array, theta: float) -> Array:
+    """Rotary embedding on dims laid out as interleaved pairs (the
+    published deepseek_v3 layout): de-interleave to halves, then rotate.
+    The output keeps the halves layout, the same for queries and keys."""
+    halves = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return common.apply_rope(halves, positions, theta)
+
+
+def mla_full(params: dict, x: Array, cfg: MLAConfig,
+             positions: Array | None = None) -> Array:
+    """(b, s, d) -> (b, s, d): latent attention over the whole sequence.
+
+    The latent is RMS-normed (eps 1e-6, as published) and expanded to
+    per-head keys and values; scores are scaled by (nope + rope)^-1/2.
+    """
+    b, s, _ = x.shape
+    if positions is None:
+        positions = jnp.arange(s)
+    dt = x.dtype
+    nope, rope, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+        cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dt))
+    ckv = x @ params["wkv_a"].astype(dt)                        # (b,s,r+rope)
+    latent = common.rms_norm(ckv[..., :r], params["kv_norm"]["scale"])
+    kv = jnp.einsum("bsr,rhk->bshk", latent, params["wkv_b"].astype(dt))
+    q_pe = _rope_pairs(q[..., nope:], positions, cfg.rope_theta)
+    k_pe = _rope_pairs(ckv[:, :, None, r:], positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(k_pe, (b, s, cfg.n_heads, rope))], axis=-1)
+    q = shard(q, "act_batch", "act_seq", "act_heads", None)
+    k = shard(k, "act_batch", "act_seq", "act_heads", None)
+    v = shard(kv[..., nope:], "act_batch", "act_seq", "act_heads", None)
+    acfg = AttnConfig(d_model=cfg.d_model, n_heads=cfg.n_heads,
+                      kv_heads=cfg.n_heads, head_dim=nope + rope,
+                      causal=cfg.causal, q_chunk=cfg.q_chunk)
+    out = _sdpa(q, k, v, acfg, positions, positions)
+    out = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dt))
     return shard(out, "act_batch", "act_seq", "act_embed")
 
 

@@ -130,14 +130,17 @@ def layer_norm(x: Array, weight: Array | None = None,
     return out
 
 
-def apply_norm(x: Array, params: dict | None, kind: str) -> Array:
+def apply_norm(x: Array, params: dict | None, kind: str,
+               eps: float = 0.0) -> Array:
+    """``eps`` 0 takes the norm's own default (1e-6 RMS, 1e-5 layer)."""
     if kind == "rmsnorm":
-        return rms_norm(x, params["scale"] if params else None)
+        return rms_norm(x, params["scale"] if params else None, eps or 1e-6)
     if kind == "layernorm":
         return layer_norm(x, params["scale"] if params else None,
-                          params.get("bias") if params else None)
+                          params.get("bias") if params else None,
+                          eps or 1e-5)
     if kind == "nonparametric_ln":
-        return layer_norm(x, None, None)
+        return layer_norm(x, None, None, eps or 1e-5)
     raise ValueError(kind)
 
 

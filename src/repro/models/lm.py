@@ -10,7 +10,9 @@
 
 Layer stacking: homogeneous families (dense/moe/encoder/vlm) use
 ``jax.lax.scan`` over stacked layer params (compact HLO for 95-layer
-stacks) with per-layer remat. Heterogeneous families (zamba2 hybrid,
+stacks) with per-layer remat; a config's leading dense layers
+(``first_dense_layers``, before a scanned expert stack) run unscanned
+first. Heterogeneous families (zamba2 hybrid,
 xlstm) use python loops over per-layer param lists — their layer counts
 are small.
 """
@@ -41,10 +43,27 @@ def _attn_cfg(cfg: ModelConfig) -> attention.AttnConfig:
         rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm, norm=cfg.norm)
 
 
-def _mlp_cfg(cfg: ModelConfig) -> mlp.MLPConfig:
-    return mlp.MLPConfig(d_model=cfg.d_model, d_ff=cfg.d_ff,
+def _mla_cfg(cfg: ModelConfig) -> attention.MLAConfig:
+    return attention.MLAConfig(
+        d_model=cfg.d_model, n_heads=cfg.n_heads,
+        kv_lora_rank=cfg.kv_lora_rank,
+        qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+        causal=cfg.causal and not cfg.is_encoder, rope_theta=cfg.rope_theta)
+
+
+def _mlp_cfg(cfg: ModelConfig, d_ff: int | None = None) -> mlp.MLPConfig:
+    return mlp.MLPConfig(d_model=cfg.d_model, d_ff=d_ff or cfg.d_ff,
                          activation=cfg.activation,
                          gated=cfg.activation == "silu")
+
+
+def _held_cfg(cfg: ModelConfig) -> mlp.HeldExpertsConfig:
+    return mlp.HeldExpertsConfig(
+        d_model=cfg.d_model, d_ff=cfg.d_ff, n_held=cfg.n_experts,
+        n_routed=cfg.router_experts, top_k=cfg.top_k,
+        offset=cfg.expert_offset, n_shared=cfg.n_shared_experts,
+        scaling=cfg.routed_scaling_factor)
 
 
 def _moe_cfg(cfg: ModelConfig) -> mlp.MoEConfig:
@@ -80,13 +99,20 @@ def _remat(fn, cfg: ModelConfig):
 # Transformer layer (dense / moe / encoder / vlm — all share this block)
 # ---------------------------------------------------------------------------
 
-def _tf_layer_spec(cfg: ModelConfig) -> dict:
+def _tf_layer_spec(cfg: ModelConfig, dense: bool = False) -> dict:
+    """One block; ``dense`` makes a leading dense layer of an expert model
+    (FFN ``dense_d_ff`` wide)."""
     s = {
         "attn_norm": common.norm_spec(cfg.d_model, cfg.norm),
-        "attn": attention.spec(_attn_cfg(cfg)),
+        "attn": (attention.mla_spec(_mla_cfg(cfg)) if cfg.kv_lora_rank
+                 else attention.spec(_attn_cfg(cfg))),
         "mlp_norm": common.norm_spec(cfg.d_model, cfg.norm),
     }
-    if cfg.n_experts:
+    if dense:
+        s["mlp"] = mlp.spec(_mlp_cfg(cfg, cfg.dense_d_ff))
+    elif cfg.n_experts and cfg.router_experts:
+        s["experts"] = mlp.held_spec(_held_cfg(cfg))
+    elif cfg.n_experts:
         s["moe"] = mlp.moe_spec(_moe_cfg(cfg))
     else:
         s["mlp"] = mlp.spec(_mlp_cfg(cfg))
@@ -108,18 +134,29 @@ def _to_resid(y: Array) -> Array:
     return shard(y, "act_batch", "act_resid_seq", "act_embed")
 
 
-def _tf_layer(params: dict, x: Array, cfg: ModelConfig) -> tuple[Array, Array]:
+def _tf_layer(params: dict, x: Array, cfg: ModelConfig
+              ) -> tuple[Array, Array, Array]:
     """Pre-norm transformer block (sequence-parallel residual stream).
 
-    Returns (x, moe_aux)."""
-    a = common.apply_norm(_seq_gather(x), params.get("attn_norm"), cfg.norm)
-    x = x + _to_resid(attention.full(params["attn"], a, _attn_cfg(cfg)))
-    m = common.apply_norm(_seq_gather(x), params.get("mlp_norm"), cfg.norm)
-    if cfg.n_experts:
+    Returns (x, moe_aux, loads): ``loads`` holds the pairs each held
+    expert computed (empty where the block holds no dropless experts).
+    The feed-forward kind follows the block's params."""
+    a = common.apply_norm(_seq_gather(x), params.get("attn_norm"), cfg.norm,
+                          cfg.norm_eps)
+    attn = (attention.mla_full(params["attn"], a, _mla_cfg(cfg))
+            if cfg.kv_lora_rank
+            else attention.full(params["attn"], a, _attn_cfg(cfg)))
+    x = x + _to_resid(attn)
+    m = common.apply_norm(_seq_gather(x), params.get("mlp_norm"), cfg.norm,
+                          cfg.norm_eps)
+    aux, loads = 0.0, jnp.zeros((0,), jnp.int32)
+    if "experts" in params:
+        out, loads = mlp.held_apply(params["experts"], m, _held_cfg(cfg))
+    elif "moe" in params:
         out, aux = mlp.moe_apply(params["moe"], m, _moe_cfg(cfg))
     else:
-        out, aux = mlp.apply(params["mlp"], m, _mlp_cfg(cfg)), 0.0
-    return x + _to_resid(out), jnp.asarray(aux, jnp.float32)
+        out = mlp.apply(params["mlp"], m, _mlp_cfg(cfg))
+    return x + _to_resid(out), jnp.asarray(aux, jnp.float32), loads
 
 
 def _tf_layer_decode(params: dict, x: Array, cache: attention.KVCache,
@@ -210,10 +247,14 @@ class Model:
 
         if cfg.family in ("dense", "moe", "encoder", "vlm"):
             layer = _tf_layer_spec(cfg)
+            n = cfg.n_layers - cfg.first_dense_layers
+            if cfg.first_dense_layers:
+                s["dense_layers"] = [_tf_layer_spec(cfg, dense=True)
+                                     for _ in range(cfg.first_dense_layers)]
             if cfg.scan_layers:
-                s["layers"] = common.map_layers(layer, cfg.n_layers)
+                s["layers"] = common.map_layers(layer, n)
             else:
-                s["layers"] = [layer for _ in range(cfg.n_layers)]
+                s["layers"] = [layer for _ in range(n)]
         elif cfg.family == "hybrid":
             mamba = ssm.spec(_ssm_cfg(cfg))
             s["layers"] = common.map_layers(mamba, cfg.n_layers)
@@ -267,15 +308,25 @@ class Model:
 
     def forward(self, params: dict, batch: Batch) -> tuple[Array, Array]:
         """Returns (logits, moe_aux_loss)."""
-        h, aux = self._trunk(params, batch)
+        h, aux, _ = self._trunk(params, batch)
         logits = common.unembed(params["unembed"], h, self.compute_dtype)
         if self.cfg.family == "vlm" and batch.embeds is not None \
                 and not self.cfg.embeds_in:
             logits = logits[:, batch.embeds.shape[1]:, :]   # text positions
         return logits, aux
 
-    def _trunk(self, params: dict, batch: Batch) -> tuple[Array, Array]:
-        """Embed + layer stack + final norm -> (hidden, moe_aux)."""
+    def last_logits(self, params: dict, batch: Batch
+                    ) -> tuple[Array, Array]:
+        """Logits of the last position only, ``(b, 1, vocab)``, and the
+        held experts' pair counts per expert layer, ``(layers, n_held)``
+        (``(layers, 0)`` for a model without them)."""
+        h, _, loads = self._trunk(params, batch)
+        return (common.unembed(params["unembed"], h[:, -1:],
+                               self.compute_dtype), loads)
+
+    def _trunk(self, params: dict, batch: Batch
+               ) -> tuple[Array, Array, Array]:
+        """Embed + layer stack + final norm -> (hidden, moe_aux, loads)."""
         cfg = self.cfg
         h = self._inputs_to_h(params, batch)
 
@@ -285,22 +336,30 @@ class Model:
             return shard(x, "act_batch", "act_resid_seq", "act_embed")
 
         h = resid(h)
+        loads = jnp.zeros((0, 0), jnp.int32)
         if cfg.family in ("dense", "moe", "encoder", "vlm"):
             layer_fn = _remat(
                 lambda p, x: _tf_layer(p, x, cfg), cfg)
+            aux = jnp.float32(0.0)
+            for lp in params.get("dense_layers", []):
+                h, a, _ = layer_fn(lp, h)
+                h = resid(h)
+                aux = aux + a
             if cfg.scan_layers:
                 def body(carry, layer_params):
                     x, aux = carry
-                    x, a = layer_fn(layer_params, x)
-                    return (resid(x), aux + a), None
-                (h, aux), _ = jax.lax.scan(body, (h, jnp.float32(0.0)),
-                                           params["layers"])
+                    x, a, n = layer_fn(layer_params, x)
+                    return (resid(x), aux + a), n
+                (h, aux), loads = jax.lax.scan(body, (h, aux),
+                                               params["layers"])
             else:
-                aux = jnp.float32(0.0)
+                per_layer = []
                 for lp in params["layers"]:
-                    h, a = layer_fn(lp, h)
+                    h, a, n = layer_fn(lp, h)
                     h = resid(h)
                     aux = aux + a
+                    per_layer.append(n)
+                loads = jnp.stack(per_layer)
         elif cfg.family == "hybrid":
             aux = jnp.float32(0.0)
             h = self._hybrid_forward(params, h)
@@ -326,8 +385,9 @@ class Model:
         else:
             raise ValueError(cfg.family)
 
-        h = common.apply_norm(h, params.get("final_norm"), cfg.norm)
-        return h, aux
+        h = common.apply_norm(h, params.get("final_norm"), cfg.norm,
+                              cfg.norm_eps)
+        return h, aux, loads
 
     def _hybrid_forward(self, params: dict, h: Array) -> Array:
         """Mamba backbone scanned in groups between shared-block stops.
@@ -381,7 +441,7 @@ class Model:
         logits are (re)computed inside a checkpointed block (forward and
         backward), capping the live loss buffer at (b, chunk, vocab)."""
         cfg = self.cfg
-        h, aux = self._trunk(params, batch)
+        h, aux, _ = self._trunk(params, batch)
         if cfg.family == "vlm" and batch.embeds is not None \
                 and not cfg.embeds_in:
             h = h[:, batch.embeds.shape[1]:, :]
@@ -418,6 +478,10 @@ class Model:
         acfg = _attn_cfg(cfg)
         if cfg.is_encoder:
             raise ValueError("encoder-only arch has no decode step")
+        if cfg.kv_lora_rank or cfg.first_dense_layers:
+            raise ValueError(f"{cfg.arch_id}: latent attention and leading "
+                             "dense layers run full sequences only (no "
+                             "decode cache)")
         if cfg.family in ("dense", "moe", "vlm"):
             one = attention.cache_spec(acfg, batch, max_seq)
             return attention.KVCache(

@@ -178,3 +178,92 @@ def moe_apply(params: dict, x: Array, cfg: MoEConfig
         expert_out * flat_w[:, None])
     out = combined.reshape(b, s, d)
     return shard(out, "act_batch", "act_seq", "act_embed"), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless expert layer over held experts (expert parallelism's local part)
+# ---------------------------------------------------------------------------
+
+class HeldExpertsConfig(NamedTuple):
+    d_model: int
+    d_ff: int                 # per-expert hidden
+    n_held: int               # experts held here
+    n_routed: int             # the router's width
+    top_k: int
+    offset: int = 0           # router index of the first held expert
+    n_shared: int = 0         # shared experts, one SwiGLU n_shared*d_ff wide
+    scaling: float = 1.0
+
+
+def held_spec(cfg: HeldExpertsConfig) -> dict:
+    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_held
+    s = {
+        "router": P((d, cfg.n_routed), ("embed", None)),
+        "router_bias": P((cfg.n_routed,), (None,), "zeros"),
+        "w_gate": P((e, d, f), ("expert", "embed", "expert_mlp")),
+        "w_up": P((e, d, f), ("expert", "embed", "expert_mlp")),
+        "w_down": P((e, f, d), ("expert", "expert_mlp", "embed")),
+    }
+    if cfg.n_shared:
+        s["shared"] = spec(MLPConfig(d, cfg.n_shared * f))
+    return s
+
+
+def route(params: dict, xf: Array, cfg: HeldExpertsConfig
+          ) -> tuple[Array, Array]:
+    """(n, d) -> the top-k ``(router index, weight)`` of each token.
+
+    Logits over every routed expert in float32, scored with a sigmoid; the
+    choice is by score plus ``router_bias`` (the correction bias of aux-free
+    balancing), the weight the score alone, always renormalised over the k
+    chosen and scaled.
+    """
+    logits = jnp.dot(xf.astype(jnp.float32),
+                     params["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + params["router_bias"].astype(jnp.float32)
+    _, idx = jax.lax.top_k(choice, cfg.top_k)                 # (n, k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return idx, w * cfg.scaling
+
+
+def held_apply(params: dict, x: Array, cfg: HeldExpertsConfig
+               ) -> tuple[Array, Array]:
+    """(..., d) -> ((..., d), (n_held,) int32 pairs per held expert).
+
+    Routes over all ``n_routed`` experts and computes the part of the
+    result the held ones give, dropping no token: the (token, expert)
+    pairs whose expert is held are sorted by expert and run through a
+    grouped matmul (``jax.lax.ragged_dot``), so the work follows the
+    routed pairs. Pairs routed elsewhere contribute nothing here. The
+    shared experts run on every token.
+    """
+    shape, d = x.shape, x.shape[-1]
+    dt = x.dtype
+    xf = x.reshape(-1, d)
+    n, k, e = xf.shape[0], cfg.top_k, cfg.n_held
+    idx, w = route(params, xf, cfg)
+    local = idx - cfg.offset
+    held = (local >= 0) & (local < e)                          # (n, k)
+    key = jnp.where(held, local, e).reshape(-1)                # e: not here
+    order = jnp.argsort(key, stable=True)                      # held first
+    loads = jnp.sum(key[:, None] == jnp.arange(e), axis=0,
+                    dtype=jnp.int32)                           # (e,)
+    xs = jnp.take(xf, order // k, axis=0)                      # (n*k, d)
+    gate = jax.lax.ragged_dot(xs, params["w_gate"].astype(dt), loads)
+    up = jax.lax.ragged_dot(xs, params["w_up"].astype(dt), loads)
+    ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
+                            params["w_down"].astype(dt), loads)
+    # back to (token, choice) order; rows past the held pairs are not
+    # written by the grouped matmul, so mask rather than weight them
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(n * k, dtype=order.dtype))
+    y = jnp.take(ys, inv, axis=0).reshape(n, k, d).astype(jnp.float32)
+    y = jnp.where(held[..., None], y * w[..., None], 0.0).sum(axis=1)
+    out = y.astype(dt).reshape(shape)
+    if cfg.n_shared:
+        out = out + apply(params["shared"], x,
+                          MLPConfig(d, cfg.n_shared * cfg.d_ff))
+    return out, loads

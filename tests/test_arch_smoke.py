@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro import configs
+from repro.configs.base import applicable_shapes
 from repro.models import lm
 from repro.train import optim
 
@@ -82,8 +83,8 @@ def test_train_step(arch, built):
 @pytest.mark.parametrize("arch", ARCHS)
 def test_decode_step(arch, built):
     cfg, model, params = built(arch)
-    if cfg.is_encoder:
-        pytest.skip("encoder-only arch: no decode step")
+    if applicable_shapes(cfg)["decode_32k"] is None:
+        pytest.skip("encoder-only or latent-attention arch: no decode step")
     state = model.init_decode_state(batch=2, max_seq=8)
     tok = jnp.array([[3], [5]], jnp.int32)
     logits, state = model.decode_step(

@@ -283,13 +283,13 @@ def test_detector_cell_bitwise_batch_invariant(detector):
     cell = steps.build_detector_cell(cfg, batch=3, frame_hw=HW, patch=8)
     step = jax.jit(cell.step_fn)
     batch = frames_of(3, seed=5)
-    out = np.asarray(step(params, batch))
+    out = np.asarray(step(params, batch)[0])
     perm = [2, 0, 1]
-    out_perm = np.asarray(step(params, batch[perm]))
+    out_perm = np.asarray(step(params, batch[perm])[0])
     np.testing.assert_array_equal(out[perm], out_perm)
     # a row's logits don't depend on what it is co-batched with
     alone = np.asarray(step(params, np.stack(
-        [batch[1], np.zeros(HW, np.float32), np.zeros(HW, np.float32)])))
+        [batch[1], np.zeros(HW, np.float32), np.zeros(HW, np.float32)]))[0])
     np.testing.assert_array_equal(alone[0], out[1])
 
 

@@ -31,8 +31,9 @@ ALL_CELLS = [
 
 
 def test_cell_count_matches_assignment():
-    # 40 assigned cells, 9 skipped by the assignment's own rules
-    assert len(ALL_CELLS) == 31
+    # 40 assigned cells, 9 skipped by the assignment's own rules; plus
+    # moonlight-16b-a3b's train and prefill (latent attention: no decode)
+    assert len(ALL_CELLS) == 33
 
 
 @pytest.mark.parametrize("arch,shape_name", ALL_CELLS)
