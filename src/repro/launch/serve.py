@@ -17,9 +17,12 @@ already assembling and transferring tick ``t+1`` while the device still
 executes tick ``t``: the send/await split of a DMA frame manager, at the
 host↔device boundary (the in-kernel analog is the double-buffered DMA
 pattern in the Pallas guide). ``collect`` blocks only on the *oldest*
-in-flight chunk. The rotating buffers are **donated** where they can
-alias: the raw super-chunk into the ADC-convert jit (float in, float
-out — same buffer), and the carried
+in-flight chunk. The host super-chunks rotate too: at most
+``max_inflight + 1`` buffers, each back in the pool once its tick is
+collected, so a tick never maps and zeroes fresh pages. The rotating
+device buffers are **donated** where they can alias: the raw
+super-chunk into the ADC-convert jit (float in, float out — same
+buffer), and the carried
 :class:`~repro.sensing.stream.StreamState` into the fleet step
 (``super_chunk_step_donated``), so a service that steps forever rolls
 the same device allocations instead of growing per chunk.
@@ -64,7 +67,9 @@ backend, precision and frame width through the kernel's own choice,
 :func:`repro.kernels.sliding_scores.strided_alignment`, not read back
 from the compiled kernel),
 ``serve.sampled_frames`` (of them, converted by an active sensor's LP
-ADC) and ``serve.hp_frames`` captured. None of it waits for the device.
+ADC), ``serve.hp_frames`` captured and ``serve.assemble_reused`` (ticks
+assembled into a recycled host buffer: every tick after the first
+``max_inflight + 1``). None of it waits for the device.
 
 ``benchmarks/serve_throughput.py --check`` gates the service ≥ the
 synchronous ``FleetRunner`` on frames/sec with bitwise-equal outputs on
@@ -136,7 +141,7 @@ class _InFlight:
     sampled: Array
     sids: tuple                      # slot -> sid for arrival slots, else None
     starts: np.ndarray               # (S,) per-slot absolute frame base
-    raw: np.ndarray | None           # host raw frames (HP capture only)
+    host: np.ndarray                 # the pooled host raw super-chunk
 
 
 def _adc_convert_fn(frames: Array, keys: Array, starts: Array, *,
@@ -270,6 +275,10 @@ class FleetService:
             per_stream=self._per_stream())
         self._pending: collections.deque[_InFlight] = collections.deque()
         self._ready: collections.deque[ServedChunk] = collections.deque()
+        # free host super-chunk buffers of one dtype; with the in-flight
+        # ticks' own, at most max_inflight + 1 exist
+        self._host_free: list[np.ndarray] = []
+        self._host_dtype: np.dtype | None = None
 
     # ------------------------------------------------------------------
     # slot pool
@@ -438,6 +447,17 @@ class FleetService:
                         sds((S, C), jnp.int32), sds((S,), jnp.bool_),
                         **kw).compile().as_text()
 
+    def _host_buffer(self, shape: tuple, dtype) -> np.ndarray:
+        """A host super-chunk for the next tick: a collected tick's
+        buffer, else a new one. A dtype change drops the pool."""
+        dtype = np.dtype(dtype)
+        if dtype != self._host_dtype:
+            self._host_free, self._host_dtype = [], dtype
+        if self._host_free:
+            telemetry.count("serve.assemble_reused")
+            return self._host_free.pop()
+        return np.empty(shape, dtype)
+
     def _put(self, x, spec=None):
         telemetry.count("serve.h2d_bytes", x.nbytes)
         if self._mesh is None or spec is None:
@@ -503,15 +523,16 @@ class FleetService:
                                                np.integer)
                                  for f in arrivals.values()) and arrivals)
             with telemetry.span("serve.dispatch.assemble"):
-                assemble = np.zeros((S, C, H, W),
-                                    np.int32 if int_codes else np.float32)
+                assemble = self._host_buffer(
+                    (S, C, H, W), np.int32 if int_codes else np.float32)
                 mask_np = np.zeros((S,), bool)
                 starts = np.zeros((S,), np.int32)
                 uids = np.zeros((S,), np.int32)
                 lab_np = np.zeros((S, C), np.int32)
-                hp_k = stream_mod.resolve_hp_buffer(
-                    self.control, C,
-                    np.int32 if int_codes else np.float32)
+                # refuses integer codes where the HP capture needs raw
+                # frames, before the tick is enqueued
+                stream_mod.resolve_hp_buffer(
+                    self.control, C, assemble.dtype)
                 for sid, fr in arrivals.items():
                     # repro-lint: disable=RA003 (admission boundary: ragged arrivals are normalized into the host assemble buffer, then uploaded once, batched)
                     fr = np.asarray(fr)
@@ -534,6 +555,9 @@ class FleetService:
                                 f"{sid!r} needs labels[{sid!r}]")
                         # repro-lint: disable=RA003 (labels are caller-side host metadata, folded into the batched upload)
                         lab_np[slot] = np.asarray(labels[sid], np.int32)
+                # a recycled buffer holds an older tick's frames: masked
+                # slots see zeros, as in a fresh buffer
+                assemble[~mask_np] = 0
             telemetry.count("serve.arrival_frames", C * len(arrivals))
 
             axes = self._step_axes[0] if self._step_axes else \
@@ -583,8 +607,7 @@ class FleetService:
                 seq=self._seq - 1, scores=s, fired=f, gated=g, sampled=smp,
                 sids=tuple(sid if mask_np[i] else None
                            for i, sid in enumerate(self._slots)),
-                starts=starts,
-                raw=assemble if hp_k > 0 else None)
+                starts=starts, host=assemble)
             self._pending.append(rec)
         # back-pressure and the checkpoint sit outside ``serve.dispatch``:
         # a tick collected here records its own top-level ``serve.collect``
@@ -614,11 +637,11 @@ class FleetService:
                 logs = self._logs[sid]
                 logs[0].append(smp[slot])
                 logs[1].append(g[slot])
-            if rec.raw is not None:
+            hp_k = stream_mod.resolve_hp_buffer(self.control,
+                                                self.chunk_size,
+                                                rec.host.dtype)
+            if hp_k > 0:
                 with telemetry.span("serve.collect.hp_capture"):
-                    hp_k = stream_mod.resolve_hp_buffer(self.control,
-                                                        self.chunk_size,
-                                                        rec.raw.dtype)
                     # a detached-but-still-holding slot's gated output is
                     # masked noise — it must not be HP-captured or counted
                     # as dropped
@@ -626,7 +649,7 @@ class FleetService:
                     # the raw super-chunk goes up again: dispatch's copy
                     # was donated into the ADC convert
                     entries, dropped = stream_mod.collect_hp(
-                        self._put(rec.raw), self._put(g & act[:, None]),
+                        self._put(rec.host), self._put(g & act[:, None]),
                         self.chunk_size, hp_k, self.control.hp_bits,
                         rec.starts)
                     for slot, sid in enumerate(rec.sids):
@@ -635,6 +658,12 @@ class FleetService:
                             telemetry.count("serve.hp_frames",
                                             len(entries[slot]))
                 self.hp_dropped += dropped
+            # the step that consumed this tick's upload has finished (its
+            # outputs were read above), and the HP capture has read the
+            # raw frames: every backend's device_put of the buffer is
+            # complete, so the next tick may write it
+            if rec.host.dtype == self._host_dtype:
+                self._host_free.append(rec.host)
             return ServedChunk(seq=rec.seq, outputs=outputs, sampled=sampled)
 
     def collect(self) -> ServedChunk | None:

@@ -31,6 +31,7 @@ from repro.core.online import AdaptConfig
 from repro.core.sensor_control import CaptureConfig, ControllerConfig
 from repro.launch.mesh import make_mesh
 from repro.launch.serve import FleetService
+from repro.sensing import adc as adc_sim
 from repro.sensing.fleet import FleetRunner
 from repro.sensing.stream import StreamRunner
 
@@ -362,6 +363,166 @@ def test_max_inflight_is_invisible_to_results(inflight):
         for a, b in zip(cat(outs[0][i]), cat(outs[1][i])):
             np.testing.assert_array_equal(a, b)
     assert svc.collect() is None          # drained
+
+
+# sensor 2 arrives on ticks 0-2 and 6-7 only, sensor 1 skips ticks 2 and 5:
+# a slot filled on tick t is empty on tick t + 2 or t + 3, which lands on
+# the same host buffer when max_inflight is 1 or 2
+RAGGED = [(0, 1, 2), (0, 1, 2), (0, 2), (0, 1), (0, 1), (0,), (0, 1, 2),
+          (0, 1, 2)]
+
+
+def _ragged_service(model, inflight, control):
+    svc = FleetService(model, CFG, n_slots=3, chunk_size=C, backend="jnp",
+                       adc_bits=4, adc_sigma=0.02, control=control,
+                       max_inflight=inflight)
+    for sid in range(3):
+        svc.attach(sid)
+    return svc
+
+
+def _held_buffers(svc) -> list:
+    bufs = list(svc._host_free) + [r.host for r in svc._pending]
+    return [b for i, b in enumerate(bufs)
+            if not any(b is o for o in bufs[:i])]
+
+
+@pytest.mark.parametrize("capture", [False, True])
+@pytest.mark.parametrize("inflight", [1, 2])
+def test_reused_host_buffers_are_invisible_to_results(inflight, capture):
+    """Ticks rotate through max_inflight + 1 host buffers; a slot left
+    empty on a recycled buffer must read as the fresh buffer's zeros."""
+    model = make_model()
+    T = len(RAGGED)
+    trace = make_trace(3, T * C)
+    control = CaptureConfig(hp_bits=12) if capture else None
+    runs = []
+    for depth in (inflight, T):           # T: the pool never wraps
+        svc = _ragged_service(model, depth, control)
+        hosts = []
+        for t, present in enumerate(RAGGED):
+            svc.dispatch({i: trace[i, t * C:(t + 1) * C] for i in present})
+            host = svc._pending[-1].host
+            hosts.append((host, host.copy()))
+            assert len(_held_buffers(svc)) <= depth + 1
+        chunks = svc.flush()
+        runs.append((hosts, chunks, [svc.drain_hp(i) for i in range(3)]))
+    (hosts, chunks, hp), (ref_hosts, ref_chunks, ref_hp) = runs
+    period = inflight + 1
+    assert all(hosts[t][0] is hosts[t + period][0]
+               for t in range(T - period)), "the pool must wrap"
+    assert all(ref_hosts[t][0] is not ref_hosts[u][0]
+               for t in range(T) for u in range(t))
+    for (_, got), (_, want) in zip(hosts, ref_hosts):
+        np.testing.assert_array_equal(got, want)
+    assert [c.seq for c in chunks] == [c.seq for c in ref_chunks]
+    for ch, ref in zip(chunks, ref_chunks):
+        assert ch.outputs.keys() == ref.outputs.keys()
+        for sid in ref.outputs:
+            for a, b in zip(ch.outputs[sid], ref.outputs[sid]):
+                np.testing.assert_array_equal(a, b)
+            np.testing.assert_array_equal(ch.sampled[sid], ref.sampled[sid])
+    for (idx, fr), (ref_idx, ref_fr) in zip(hp, ref_hp):
+        np.testing.assert_array_equal(idx, ref_idx)
+        np.testing.assert_array_equal(fr, ref_fr)
+    if capture:
+        assert sum(i.size for i, _ in hp) > 0, "trace must capture frames"
+
+
+@pytest.mark.parametrize("inflight", [1, 2])
+def test_host_buffer_pool_is_bounded_and_counted(inflight):
+    """At most max_inflight + 1 host buffers under back-to-back ticks,
+    flush, back-pressure and churn; ``serve.assemble_reused`` counts every
+    tick after the pool's first fill."""
+    from repro.launch import telemetry
+
+    model = make_model()
+    T = 6
+    trace = make_trace(3, 3 * T * C)
+    svc = _ragged_service(model, inflight, None)
+    seen = []
+
+    def tick(t, present=(0, 1, 2)):
+        svc.dispatch({i: trace[i, t * C:(t + 1) * C] for i in present})
+        held = _held_buffers(svc)
+        assert len(held) <= inflight + 1
+        seen.extend(b for b in held if not any(b is o for o in seen))
+
+    telemetry.reset()
+    for t in range(T):                    # back to back
+        tick(t)
+    svc.flush()
+    assert telemetry.snapshot()["counters"]["serve.assemble_reused"] == \
+        T - (inflight + 1)
+    assert len(_held_buffers(svc)) == inflight + 1
+    for t in range(T, 2 * T):             # back-pressure, through churn
+        if t == T + 1:
+            svc.detach(1)
+        if t == T + 3:
+            svc.attach(1)
+        tick(t, (0, 2) if T + 1 <= t < T + 3 else (0, 1, 2))
+        if t % 3 == 0:
+            assert svc.collect() is not None
+    svc.flush()
+    assert len(seen) == inflight + 1
+    assert telemetry.snapshot()["counters"]["serve.assemble_reused"] == \
+        2 * T - (inflight + 1)
+
+
+def test_host_buffer_pool_follows_arrival_dtype():
+    """Integer-code arrivals assemble into int32 buffers: the float pool
+    is dropped, and the new pool stays within max_inflight + 1."""
+    model = make_model()
+    trace = make_trace(1, 6 * C)
+    codes = np.clip(np.abs(trace) * 8, 0, 31).astype(np.int32)
+    svc = FleetService(model, CFG, n_slots=1, chunk_size=C, backend="jnp",
+                       precision="int8", adc_bits=5, max_inflight=1)
+    svc.attach(0)
+    for t in range(3):
+        svc.dispatch({0: trace[0, t * C:(t + 1) * C]})
+    for t in range(3, 6):
+        svc.dispatch({0: codes[0, t * C:(t + 1) * C]})
+        assert len(_held_buffers(svc)) <= 2
+    svc.flush()
+    held = _held_buffers(svc)
+    assert len(held) == 2 and all(b.dtype == np.int32 for b in held)
+    # codes carry no raw frames for an HP capture: refused at dispatch
+    cap = FleetService(model, CFG, n_slots=1, chunk_size=C, backend="jnp",
+                       precision="int8", adc_bits=5,
+                       control=CaptureConfig(hp_bits=12))
+    cap.attach(0)
+    with pytest.raises(ValueError, match="raw frames"):
+        cap.dispatch({0: codes[0, :C]})
+
+
+def test_hp_capture_reads_each_ticks_own_frames():
+    """With max_inflight=1 the pool rotates every other tick; each tick's
+    HP capture must still read its own raw frames, not a later tick's."""
+    model = make_model(t_score=-0.2)      # every frame passes the gate
+    T = 6
+    trace = make_trace(2, T * C)
+    svc = FleetService(model, CFG, n_slots=2, chunk_size=C, backend="jnp",
+                       adc_bits=4, control=CaptureConfig(hp_bits=12),
+                       max_inflight=1)
+    svc.attach(0)
+    svc.attach(1)
+    gated = {0: [], 1: []}
+    for t in range(T):
+        svc.dispatch({i: trace[i, t * C:(t + 1) * C] for i in range(2)})
+    for ch in svc.flush():
+        for sid, (_, _, g) in ch.outputs.items():
+            gated[sid].append(g)
+    hp12 = np.asarray(adc_sim.quantize_per_frame(
+        jnp.asarray(trace.reshape(-1, *trace.shape[2:])),
+        jnp.full((2 * T * C,), 12))).reshape(trace.shape)
+    ticks = set()
+    for sid in range(2):
+        idx, frames = svc.drain_hp(sid)
+        np.testing.assert_array_equal(
+            idx, np.flatnonzero(np.concatenate(gated[sid])))
+        np.testing.assert_array_equal(frames, hp12[sid, idx])
+        ticks.update((idx // C).tolist())
+    assert ticks == set(range(T)) and svc.hp_dropped == 0
 
 
 def test_slot_pool_errors():
