@@ -1,7 +1,7 @@
 .PHONY: test test-shard1 test-shard2 test-cov test-multidevice deps \
 	lint test-sanitize \
-	bench-stream bench-fleet bench-adapt bench-int bench-int4 \
-	bench-control bench bench-mesh bench-serve bench-cascade
+	bench-stream bench-adapt bench-int bench-int4 \
+	bench-control bench bench-cascade
 
 deps:
 	pip install -r requirements-dev.txt
@@ -71,9 +71,6 @@ test-multidevice:
 bench-stream:
 	PYTHONPATH=src python benchmarks/stream_throughput.py
 
-bench-fleet:
-	PYTHONPATH=src python benchmarks/fleet_throughput.py
-
 bench-adapt:
 	PYTHONPATH=src python benchmarks/adaptation.py
 
@@ -88,18 +85,6 @@ bench-int4:
 
 bench-control:
 	PYTHONPATH=src python benchmarks/control_loop.py
-
-# the 2-D mesh scale-out gate: S=1024 on the sensor axis, D=16384 on the
-# hyperdim axis (forced-8-device host mesh), bitwise parity + VMEM
-# certification enforced
-bench-mesh:
-	PYTHONPATH=src python benchmarks/fleet_throughput.py --mesh --check
-
-# the serving-layer gate: async double-buffered FleetService >= synchronous
-# FleetRunner fps, bitwise parity churn-off, zero recompiles under churn,
-# bitwise checkpoint kill-and-resume
-bench-serve:
-	PYTHONPATH=src python benchmarks/serve_throughput.py --check
 
 # the full-loop gate → detector cascade gate: batched async backbone
 # serving bitwise-equal to eager per-frame evaluation, exactly one

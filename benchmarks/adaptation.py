@@ -40,8 +40,8 @@ from repro.core.encoding import make_perm_base_rows
 from repro.core.online import AdaptConfig
 from repro.core.sensor_control import ControllerConfig
 from repro.kernels import ops
+from repro.launch.replay import StreamRunner
 from repro.sensing import fragments, synthetic
-from repro.sensing.stream import StreamRunner
 
 # CPU-tractable scale; the drift scenario is chosen so the frozen model
 # genuinely degrades (late AUC ~0.73 here) and label feedback measurably
@@ -118,25 +118,22 @@ def drift_recovery(backend: str = "jnp") -> dict:
     half = N_STREAM // 2
     control = ControllerConfig(hold_frames=2)
 
-    def timed(runner, feed):
-        runner.process(stream[:CHUNK],
-                       labels=None if feed is None else feed[:CHUNK])
-        runner.reset()                       # warmup: jit + tile precompute
+    def timed(feed, **kw):
+        # warmup on a throwaway runner: the step's jit cache is shared
+        StreamRunner(hs, control, chunk_size=CHUNK, backend=backend,
+                     **kw).process(
+            stream[:CHUNK], labels=None if feed is None else feed[:CHUNK])
+        runner = StreamRunner(hs, control, chunk_size=CHUNK,
+                              backend=backend, **kw)
         t0 = time.perf_counter()
         out = runner.process(stream, labels=feed)
         return out, time.perf_counter() - t0
 
-    frozen = StreamRunner(hs, control, chunk_size=CHUNK, backend=backend)
-    (s_frozen, _, _), t_frozen = timed(frozen, None)
-
-    ada = StreamRunner(hs, control, chunk_size=CHUNK, backend=backend,
-                       adapt=AdaptConfig(mode="label", lr=LR))
-    (s_label, _, _), t_label = timed(ada, labels)
-
-    pseudo = StreamRunner(hs, control, chunk_size=CHUNK, backend=backend,
-                          adapt=AdaptConfig(mode="pseudo", lr=0.5,
-                                            confidence=0.02))
-    (s_pseudo, _, _), _ = timed(pseudo, None)
+    (s_frozen, _, _), t_frozen = timed(None)
+    (s_label, _, _), t_label = timed(
+        labels, adapt=AdaptConfig(mode="label", lr=LR))
+    (s_pseudo, _, _), _ = timed(
+        None, adapt=AdaptConfig(mode="pseudo", lr=0.5, confidence=0.02))
 
     return {
         "frozen_auc_late": _auc(s_frozen[half:], labels[half:]),

@@ -39,8 +39,9 @@ from repro.core import energy, fragment_model as fm, hypersense, metrics
 from repro.core.sensor_control import (CaptureConfig, CaptureLog,
                                        ControllerConfig, decimation,
                                        stats_from)
+from repro.launch.replay import StreamRunner
 from repro.sensing import fragments, synthetic
-from repro.sensing.stream import StreamRunner, gate_scan
+from repro.sensing.stream import gate_scan
 
 # CPU-tractable scale: sparse events (the paper's "activity of interest
 # is infrequent" regime) so idle decimation has something to save.

@@ -17,8 +17,7 @@ from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = ["table1_auc", "fig12_thresholds", "fig13_stride",
           "fig15_fragsize_dim", "fig16_speedup", "stream_throughput",
-          "fleet_throughput", "serve_throughput", "adaptation",
-          "int_datapath", "control_loop", "table3_energy",
+          "adaptation", "int_datapath", "control_loop", "table3_energy",
           "hypersense_roofline", "roofline"]
 
 

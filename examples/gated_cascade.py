@@ -1,6 +1,6 @@
 """The paper's full loop: HDC gate → HP capture → backbone detector.
 
-A closed-loop StreamRunner gates a sparse-event radar stream, its
+A closed-loop FleetService gates a sparse-event radar stream, its
 high-precision burst drains feed a CascadeService backbone, and the
 capture log bills the whole system against an always-on detector.
 
@@ -17,8 +17,8 @@ from repro.core.sensor_control import CaptureConfig, ControllerConfig
 from repro.launch import steps
 from repro.launch.cascade import CascadeService
 from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import FleetService
 from repro.sensing import synthetic
-from repro.sensing.stream import StreamRunner
 
 FRAME, CHUNK, BATCH = 32, 16, 8
 
@@ -40,12 +40,13 @@ def main() -> None:
     scores = hypersense.frame_scores_batch(gate, stream, 0,
                                            sequential=True)
     gate = gate._replace(t_score=float(np.quantile(scores, 0.75)))
-    runner = StreamRunner(gate,
-                          ControllerConfig(base_rate_hz=10.0,
-                                           active_rate_hz=30.0,
-                                           hold_frames=4),
-                          chunk_size=CHUNK,
-                          control=CaptureConfig(hp_bits=12))
+    svc = FleetService(gate,
+                       ControllerConfig(base_rate_hz=10.0,
+                                        active_rate_hz=30.0,
+                                        hold_frames=4),
+                       n_slots=1, chunk_size=CHUNK,
+                       control=CaptureConfig(hp_bits=12))
+    svc.attach("radar")
 
     # the downstream detector: smoke embeds-in backbone + patch embedder
     cfg = configs.get_smoke("hubert-xlarge")
@@ -55,15 +56,16 @@ def main() -> None:
                           frame_hw=(FRAME, FRAME))
 
     for t in range(0, len(stream), CHUNK):
-        runner.process(stream[t:t + CHUNK])
-        casc.pump(runner)                 # ragged drain -> fixed batches
+        svc.dispatch({"radar": stream[t:t + CHUNK]})
+        svc.flush()                       # the tick's HP capture lands
+        casc.pump(svc)                    # ragged drain -> fixed batches
     for batch in casc.flush():
         for i, logit in zip(batch.frame_idx, batch.logits):
             label = int(jnp.argmax(jnp.asarray(logit)))
             print(f"frame {int(i):4d}  detector class {label}  "
                   f"logits {np.round(logit, 3)}")
 
-    log = runner.capture_log
+    log = svc.capture_log("radar")
     e = casc.system_energy(log)
     duty = float(np.asarray(log.gated, bool).mean())
     print(f"\ngate duty cycle      {duty:.3f}")

@@ -26,9 +26,9 @@ from repro.core.online import AdaptConfig
 from repro.core.sensor_control import (CaptureConfig, ControllerConfig,
                                        decimation, stats_from)
 from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.replay import (StreamRunner, simulate_fleet,
+                                 simulate_stream_batched)
 from repro.sensing import adc, fragments, synthetic
-from repro.sensing.fleet import simulate_fleet
-from repro.sensing.stream import StreamRunner, simulate_stream_batched
 
 
 def train_gate(key, cfg, frag, dim, stride):

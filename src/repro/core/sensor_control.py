@@ -53,7 +53,7 @@ def decimation(config: ControllerConfig) -> int:
 
 @dataclass(frozen=True)
 class CaptureConfig:
-    """Closed-loop ADC capture policy — the runners' ``control=`` argument.
+    """Closed-loop ADC capture policy — the gate's ``control=`` argument.
 
     With a ``CaptureConfig`` the gate decision at frame ``t`` modulates
     *capture* at frame ``t+1``: idle frames are temporally subsampled to
@@ -68,7 +68,7 @@ class CaptureConfig:
     ``base_rate_hz == active_rate_hz``). ``hp_bits`` is the burst bit
     depth (the energy model's ``adc_hp_bits``). ``hp_buffer`` bounds how
     many HP frames one chunk step can materialize (``None`` → the
-    runner's ``chunk_size``; ``0`` → log-only, no frames kept).
+    service's ``chunk_size``; ``0`` → log-only, no frames kept).
     """
     subsample: bool = True
     hp_bits: int = 12
@@ -107,26 +107,20 @@ class CaptureLog:
 def assemble_capture_log(sampled_blocks, gated_blocks, *,
                          lp_bits: int | None,
                          control: CaptureConfig | None,
-                         frame_pixels: int, axis: int = 0) -> CaptureLog:
-    """Build a :class:`CaptureLog` from a runner's per-chunk blocks.
+                         frame_pixels: int) -> CaptureLog:
+    """Build one stream's :class:`CaptureLog` from its per-tick ``(n,)``
+    blocks (with no blocks yet the arrays are ``(0,)``).
 
-    The ONE place every stream front-end (``StreamRunner``,
-    ``FleetRunner``, ``FleetService``) assembles its billing log, so the
-    ``hp_bits`` convention cannot drift between them: ``control=None``
-    (open loop) records ``hp_bits=None`` — billing-time code decides what
-    that means (see :func:`repro.core.energy.from_capture_log`); a
-    closed-loop runner records ``control.hp_bits``, the depth its HP
-    bursts were actually converted at.
-
-    ``axis`` is the frame axis blocks concatenate along: 0 for ``(n,)``
-    single-stream blocks, 1 for ``(S, n)`` fleet blocks. With no blocks
-    yet the arrays are empty with the right rank (``(0,)`` / ``(0, 0)``).
+    ``control=None`` (open loop) records ``hp_bits=None`` — billing-time
+    code decides what that means (see
+    :func:`repro.core.energy.from_capture_log`); a closed loop records
+    ``control.hp_bits``, the depth its HP bursts were actually converted
+    at.
     """
     def cat(blocks):
         if blocks:
-            return np.concatenate([np.asarray(b, bool) for b in blocks],
-                                  axis=axis)
-        return np.zeros((0,) * (axis + 1), bool)
+            return np.concatenate([np.asarray(b, bool) for b in blocks])
+        return np.zeros((0,), bool)
 
     return CaptureLog(sampled=cat(sampled_blocks), gated=cat(gated_blocks),
                       lp_bits=lp_bits,

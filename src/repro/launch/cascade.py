@@ -4,10 +4,10 @@ HyperSense's system claim is gate-then-detect: the always-on HDC gate
 runs on low-precision ADC data, and only the frames it passes are
 high-precision captured and fed to the heavy downstream detector —
 5.6x end-to-end vs an always-on YOLOv4 and up to 92.1% energy saving
-(paper §V-E). The sensing runtime already produces exactly that feed:
-every runner's ``drain_hp()`` delivers ``(absolute frame indices,
-(M, H, W) HP frames)`` bursts. :class:`CascadeService` is the consumer
-that closes the loop:
+(paper §V-E). The gate runtime already produces exactly that feed:
+:meth:`repro.launch.serve.FleetService.drain_hp` delivers ``(absolute
+frame indices, (M, H, W) HP frames)`` bursts per sensor.
+:class:`CascadeService` is the consumer that closes the loop:
 
 * **Fixed-shape batching.** Drains are ragged (a quiet tick drains 0
   frames, a bursty one dozens). Frames queue host-side and launch in
@@ -103,14 +103,12 @@ class CascadeService:
     ``params`` are :func:`repro.launch.steps.init_detector_params`-shaped
     (``{"backbone": ..., "embedder": ...}``) for an **embeds-in** ``cfg``
     (e.g. ``configs.get_smoke("hubert-xlarge")``). ``frame_hw`` must
-    match the gate runners' frames; ``batch_size`` fixes the backbone
-    step shape. With a ``mesh`` the backbone params shard across it.
+    match the gate's frames; ``batch_size`` fixes the backbone step
+    shape. With a ``mesh`` the backbone params shard across it.
 
     Feed it either directly (:meth:`submit` takes any ``drain_hp()``
-    output) or via :meth:`pump`, which drains a
-    :class:`~repro.launch.serve.FleetService`,
-    :class:`~repro.sensing.fleet.FleetRunner`, or
-    :class:`~repro.sensing.stream.StreamRunner` in place. Results come
+    output) or via :meth:`pump`, which drains every attached sensor of a
+    :class:`~repro.launch.serve.FleetService` in place. Results come
     back through :meth:`collect`/:meth:`flush` as
     :class:`CascadeBatch` rows mapped back to (sensor, absolute frame).
     """
@@ -184,24 +182,11 @@ class CascadeService:
         return int(idx.shape[0])
 
     def pump(self, gate) -> int:
-        """Drain a gate front-end into the queue; returns frames taken.
-
-        Accepts a ``FleetService`` (per-sensor drains, keyed by sid), a
-        ``FleetRunner`` (per-stream drains, keyed by row index), or a
-        ``StreamRunner`` (single stream, sid 0).
-        """
-        taken = 0
-        if hasattr(gate, "attached"):              # FleetService
-            for sid in gate.attached:
-                taken += self.submit(sid, *gate.drain_hp(sid))
-        else:
-            out = gate.drain_hp()
-            if isinstance(out, list):              # FleetRunner
-                for si, (idx, frames) in enumerate(out):
-                    taken += self.submit(si, idx, frames)
-            else:                                  # StreamRunner
-                taken += self.submit(0, *out)
-        return taken
+        """Drain every attached sensor of ``gate`` (a
+        :class:`~repro.launch.serve.FleetService`) into the queue, keyed
+        by sensor id; returns frames taken."""
+        return sum(self.submit(sid, *gate.drain_hp(sid))
+                   for sid in gate.attached)
 
     # ------------------------------------------------------------------
     # dispatch / collect (PR-8 double-buffering shape)

@@ -2,11 +2,13 @@
 
 The paper's "Intelligent Sensor Control" system is *continuously
 running*: ADC streams feed the HDC gate in real time, and the FPGA
-design wins end-to-end because data movement overlaps compute. The
-batch-mode :class:`~repro.sensing.fleet.FleetRunner` pays host→device
-transfer serially before every kernel launch and freezes stream
-membership at construction; :class:`FleetService` is the serving layer
-on top of the same jitted fleet step that removes both limits.
+design wins end-to-end because data movement overlaps compute.
+:class:`FleetService` is the one runtime of the gate step
+(:func:`repro.sensing.stream.super_chunk_fn`): it builds the step, its
+tiles and ADC front-end, pads its slot axis to the mesh, and owns the HP
+capture and the per-sensor capture logs. Whole-recording replay
+(:mod:`repro.launch.replay`) and the gated cascade
+(:mod:`repro.launch.cascade`) sit on top of it.
 
 **Double buffering** (:meth:`FleetService.dispatch` /
 :meth:`~FleetService.collect`). ``dispatch`` assembles the next
@@ -24,18 +26,23 @@ device buffers are **donated** where they can alias: the raw
 super-chunk into the ADC-convert jit (float in, float out — same
 buffer), and the carried
 :class:`~repro.sensing.stream.StreamState` into the fleet step
-(``super_chunk_step_donated``), so a service that steps forever rolls
-the same device allocations instead of growing per chunk.
+(``super_chunk_step``), so a service that steps forever rolls the same
+device allocations instead of growing per chunk.
+
+**Short ticks.** A tick's arrivals share one length ``L <= chunk_size``.
+``L`` goes into the step's traced ``n_valid`` operand, so a short tick
+(the tail of a recording, say) runs the same compiled step; outputs,
+logs and HP captures keep the first ``L`` frames of each arrival.
 
 **Slot-pooled churn** (:meth:`~FleetService.attach` /
 :meth:`~FleetService.detach`). The fleet step always runs at a fixed
 ``(n_slots, chunk_size, H, W)`` shape; sensors map onto slots and
 membership/ragged arrival only flips bits in the step's ``slot_mask``
-operand — PR 7's padded-slot machinery, reused as a pool. Churn
+operand — the padded-slot machinery, reused as a pool. Churn
 therefore NEVER changes an array shape and never triggers a recompile
 (:meth:`~FleetService.compile_count` exposes the step's XLA compile
-counter so callers can assert exactly that). ``park_masked`` step
-semantics freeze a masked slot's hold/phase/classifier state in place,
+counter so callers can assert exactly that). The step parks a masked
+slot's hold/phase/classifier state in place,
 and detach parks the slot's state host-side, so detach→reattach —
 even through an intervening tenant in the same slot — restores a
 sensor's adapted classifier, gate hold, ADC phase, and capture log
@@ -70,11 +77,6 @@ from the compiled kernel),
 ADC), ``serve.hp_frames`` captured and ``serve.assemble_reused`` (ticks
 assembled into a recycled host buffer: every tick after the first
 ``max_inflight + 1``). None of it waits for the device.
-
-``benchmarks/serve_throughput.py --check`` gates the service ≥ the
-synchronous ``FleetRunner`` on frames/sec with bitwise-equal outputs on
-the same churn-free trace, zero recompiles across a churn trace, and
-bitwise checkpoint-restore.
 """
 
 from __future__ import annotations
@@ -110,8 +112,9 @@ Array = jax.Array
 class ServedChunk:
     """One collected tick: per-sensor outputs.
 
-    ``outputs[sid]`` is ``(scores (C,), fired (C,), gated (C,))`` numpy
-    arrays for every sensor that delivered frames in the tick;
+    ``outputs[sid]`` is ``(scores (L,), fired (L,), gated (L,))`` numpy
+    arrays for every sensor that delivered frames in the tick (``L`` the
+    tick's arrival length);
     ``sampled[sid]`` marks the frames its LP ADC actually converted
     (closed-loop mode). Where the host's time went for the tick is in
     :mod:`repro.launch.telemetry`, under the tick's ``seq``.
@@ -142,6 +145,7 @@ class _InFlight:
     sids: tuple                      # slot -> sid for arrival slots, else None
     starts: np.ndarray               # (S,) per-slot absolute frame base
     host: np.ndarray                 # the pooled host raw super-chunk
+    n_valid: int                     # frames per arrival in the tick
 
 
 def _adc_convert_fn(frames: Array, keys: Array, starts: Array, *,
@@ -151,8 +155,7 @@ def _adc_convert_fn(frames: Array, keys: Array, starts: Array, *,
     Each slot converts with its OWN noise key (folded per persistent
     sensor uid, not slot index) and its own absolute frame base, so a
     sensor's capture is bit-identical no matter which slot it lands in
-    or how its stream interleaves with churn — the per-sensor twin of
-    the runners' slicing invariance.
+    or how its stream interleaves with churn.
     """
     view = stream_mod.adc_view_codes if codes else stream_mod.adc_view
     return jax.vmap(lambda f, k, s0: view(f, bits, sigma=sigma, key=k,
@@ -177,23 +180,21 @@ _fold_uid_keys = jax.jit(jax.vmap(jax.random.fold_in, in_axes=(None, 0)))
 class FleetService:
     """Slot-pooled, double-buffered, checkpointed fleet serving.
 
-    The always-on front door to the fleet runtime: sensors
-    :meth:`attach` / :meth:`detach` dynamically (capacity is a fixed
-    ``n_slots`` pool, rounded up to the mesh's "sensors" extent so the
-    padded slot axis always shards), each service *tick* is one
-    :meth:`dispatch` of ``chunk_size`` frames from whichever sensors
-    have them ready (ragged arrival = absent from the dict), and
-    :meth:`collect` returns finished ticks in FIFO order. Up to
-    ``max_inflight`` ticks pipeline between host and device; state
-    (classifier adaptation, gate hysteresis, closed-loop ADC phase)
-    carries exactly as in :class:`~repro.sensing.fleet.FleetRunner`,
-    whose jitted step this shares — with an all-true slot mask the two
-    are bitwise identical.
+    The one runtime of the gate step: sensors :meth:`attach` /
+    :meth:`detach` dynamically (capacity is a fixed ``n_slots`` pool,
+    rounded up to the mesh's "sensors" extent so the padded slot axis
+    always shards), each service *tick* is one :meth:`dispatch` of up
+    to ``chunk_size`` frames from whichever sensors have them ready
+    (ragged arrival = absent from the dict), and :meth:`collect` returns
+    finished ticks in FIFO order. Up to ``max_inflight`` ticks pipeline
+    between host and device; state (classifier adaptation, gate
+    hysteresis, closed-loop ADC phase) carries from tick to tick.
 
-    Config mirrors ``FleetRunner`` (``backend``, ``precision``,
-    ``adc_bits``/``adc_sigma``, ``adapt``, ``control``, ``mesh``), plus:
+    Config: ``backend``, ``precision``, ``adc_bits``/``adc_sigma``/
+    ``adc_key``, ``adapt``, ``control``, ``mesh`` (default: the current
+    :func:`repro.distributed.sharding.use_mesh` mesh), plus:
 
-    * ``n_slots`` — pool capacity (this replaces the runner's frozen S);
+    * ``n_slots`` — pool capacity;
     * ``max_inflight`` — dispatched-but-uncollected ticks before
       ``dispatch`` itself drains the oldest (back-pressure);
     * ``ckpt_dir`` / ``ckpt_every`` / ``ckpt_keep`` — automatic async
@@ -265,7 +266,7 @@ class FleetService:
         self._geom = None
         self._tiles = None
         self._step = None
-        self._step_axes = None     # ("sensors" axes, k) resolved at build
+        self._step_axes = None     # ("sensors", "hyperdim") axes at build
         self._n_valid = jnp.int32(chunk_size)
         self._t_score = jnp.float32(model.t_score)
         # donated state rotates through the step forever — seed it with a
@@ -364,7 +365,7 @@ class FleetService:
         self._slots[slot] = None
 
     # ------------------------------------------------------------------
-    # step plumbing (shared with FleetRunner)
+    # step plumbing
     # ------------------------------------------------------------------
 
     def _ensure_geom(self, W: int):
@@ -380,7 +381,7 @@ class FleetService:
         return self._tiles
 
     def _ensure_step(self, W: int):
-        """Build (once) the donated, park-masked fleet step + tile args."""
+        """Build (once) the fleet step + tile args."""
         if self.backend == "pallas" \
                 or self.precision in adc_sim.INT_PRECISIONS:
             tiles = (self._ensure_geom(W) if self.adapt is not None
@@ -389,18 +390,18 @@ class FleetService:
             tiles = None
         if self._step is None:
             m = self.model
-            axes, k = fleet_mod._sensor_axes(self._mesh)
+            axes = fleet_mod._sensor_axes(self._mesh)[0]
             hd_axes = fleet_mod._hyperdim_axes(self._mesh, tiles,
                                                self.backend, self.precision)
             self._step = fleet_mod._build_step(
                 self._mesh, axes, hd_axes,
-                fleet_mod._tiles_specs(tiles, hd_axes), donate=True,
+                fleet_mod._tiles_specs(tiles, hd_axes),
                 h=m.h, w=m.w, stride=m.stride,
                 nonlinearity=m.nonlinearity, t_detection=self.t_detection,
                 hold_frames=self.config.hold_frames, backend=self.backend,
                 adapt=self.adapt, precision=self.precision,
-                adc_lsb=self._adc_lsb, decim=self._decim, park_masked=True)
-            self._step_axes = (axes, k)
+                adc_lsb=self._adc_lsb, decim=self._decim)
+            self._step_axes = (axes, hd_axes)
         return self._step, tiles
 
     @property
@@ -412,10 +413,10 @@ class FleetService:
         """Cumulative XLA compilations of this service's step function.
 
         The churn contract's witness: after the warm-up tick, attach/
-        detach/ragged arrival must leave this number frozen (asserted by
-        ``tests/test_serve.py`` and ``benchmarks/serve_throughput.py
-        --check``). Unsharded services share the module-level donated
-        step's cache, so compare DELTAS around a trace, not absolutes.
+        detach/ragged arrival/short ticks must leave this number frozen
+        (asserted by ``tests/test_serve.py``). Unsharded services share
+        the module-level step's cache, so compare DELTAS around a trace,
+        not absolutes.
         """
         step = self._step
         if step is None:
@@ -471,12 +472,14 @@ class FleetService:
     def dispatch(self, arrivals: dict, labels: dict | None = None) -> int:
         """Enqueue one service tick; returns its sequence number.
 
-        ``arrivals`` maps attached sensor ids to ``(chunk_size, H, W)``
-        frame blocks (raw float frames, or integer ADC codes under an
-        integer precision); an attached sensor absent from the dict is
-        masked for the tick — its carried state is parked in place, as
-        if no time passed for it. ``labels`` (same keying, ``(C,)``
-        ints) feeds ``adapt.mode == "label"`` updates.
+        ``arrivals`` maps attached sensor ids to ``(L, H, W)`` frame
+        blocks (raw float frames, or integer ADC codes under an integer
+        precision), one ``L <= chunk_size`` for every arrival of the
+        tick — a tick that mixes lengths is refused. An attached sensor
+        absent from the dict is masked for the tick — its carried state
+        is parked in place, as if no time passed for it. ``labels``
+        (same keying, ``(L,)`` ints) feeds ``adapt.mode == "label"``
+        updates.
 
         Returns as soon as the H2D transfer and the fleet step are
         *enqueued*; compute for up to ``max_inflight`` ticks overlaps
@@ -490,20 +493,29 @@ class FleetService:
             if labels is not None and not label_mode:
                 raise ValueError(
                     "labels passed without adapt.mode == 'label'")
-            first = None
+            # shape peeks only — np.shape reads .shape without pulling a
+            # device arrival to host (the upload happens once, batched)
+            shape = None
             for sid, fr in arrivals.items():
                 if sid not in self._by_sid:
                     raise ValueError(f"sensor {sid!r} is not attached")
-                first = fr if first is None else first
-            if first is not None and self._frame_hw is None:
-                # shape peek only — np.shape reads .shape without pulling
-                # a device arrival to host (the upload happens once,
-                # batched)
-                shp = np.shape(first)
-                if len(shp) != 3:
-                    raise ValueError(f"expected (chunk_size, H, W) "
-                                     f"arrival, got shape {shp}")
-                self._frame_hw = (int(shp[1]), int(shp[2]))
+                shp = tuple(int(d) for d in np.shape(fr))
+                if shape is not None and shp != shape:
+                    raise ValueError(
+                        f"arrival for {sid!r} has shape {shp}, expected "
+                        f"{shape}: the arrivals of a tick share one length")
+                shape = shp
+            if shape is not None and (
+                    len(shape) != 3 or not 1 <= shape[0] <= C
+                    or shape[1:] != (self._frame_hw or shape[1:])):
+                raise ValueError(
+                    f"arrival shape {shape}, expected (L, H, W) with "
+                    f"1 <= L <= chunk_size = {C}"
+                    + (f" and (H, W) = {self._frame_hw}"
+                       if self._frame_hw else ""))
+            L = shape[0] if shape else C     # a silent tick runs a chunk
+            if shape is not None and self._frame_hw is None:
+                self._frame_hw = shape[1:]
                 self._frame_pixels = (self._frame_hw[0]
                                       * self._frame_hw[1])
                 if self.precision in adc_sim.INT_PRECISIONS:
@@ -513,10 +525,10 @@ class FleetService:
                         self.model.w, stride=self.model.stride,
                         block_d=self.block_d,
                         packed=self.precision == "int4")
-            H, W = self._frame_hw if self._frame_hw else (0, 0)
             if self._frame_hw is None:
                 raise ValueError("first dispatch needs at least one "
                                  "arrival to fix the frame shape")
+            H, W = self._frame_hw
 
             int_codes = (self.precision in adc_sim.INT_PRECISIONS
                          and all(np.issubdtype(np.result_type(f),
@@ -534,31 +546,27 @@ class FleetService:
                 stream_mod.resolve_hp_buffer(
                     self.control, C, assemble.dtype)
                 for sid, fr in arrivals.items():
-                    # repro-lint: disable=RA003 (admission boundary: ragged arrivals are normalized into the host assemble buffer, then uploaded once, batched)
-                    fr = np.asarray(fr)
-                    if fr.shape != (C, H, W):
-                        raise ValueError(
-                            f"arrival for {sid!r} has shape {fr.shape}, "
-                            f"expected (chunk_size, H, W) = {(C, H, W)} "
-                            f"— a service tick is exactly one chunk; "
-                            f"buffer partial chunks at the edge")
                     slot = self._by_sid[sid]
-                    assemble[slot] = fr
+                    # repro-lint: disable=RA003 (admission boundary: ragged arrivals are normalized into the host assemble buffer, then uploaded once, batched)
+                    assemble[slot, :L] = np.asarray(fr)
                     mask_np[slot] = True
                     starts[slot] = self._n_seen[sid]
                     uids[slot] = self._uids[sid]
-                    self._n_seen[sid] += C
+                    self._n_seen[sid] += L
                     if label_mode:
                         if labels is None or sid not in labels:
                             raise ValueError(
                                 f'adapt.mode == "label": arrival for '
                                 f"{sid!r} needs labels[{sid!r}]")
                         # repro-lint: disable=RA003 (labels are caller-side host metadata, folded into the batched upload)
-                        lab_np[slot] = np.asarray(labels[sid], np.int32)
+                        lab_np[slot, :L] = np.asarray(labels[sid], np.int32)
                 # a recycled buffer holds an older tick's frames: masked
-                # slots see zeros, as in a fresh buffer
+                # slots and a short tick's tail see zeros, as in a fresh
+                # buffer
                 assemble[~mask_np] = 0
-            telemetry.count("serve.arrival_frames", C * len(arrivals))
+                if L < C:
+                    assemble[:, L:] = 0
+            telemetry.count("serve.arrival_frames", L * len(arrivals))
 
             axes = self._step_axes[0] if self._step_axes else \
                 fleet_mod._sensor_axes(self._mesh)[0]
@@ -578,8 +586,7 @@ class FleetService:
                 if int_codes:
                     # already-converted codes: concrete range check +
                     # pack (the noise, if configured, applies before
-                    # conversion — integer input with sigma > 0 raises, as
-                    # on the runners)
+                    # conversion — integer input with sigma > 0 raises)
                     frames = stream_mod.adc_view_codes(
                         frames, self.adc_bits, sigma=self.adc_sigma)
                 elif convert:
@@ -592,9 +599,10 @@ class FleetService:
 
                 step, tiles = self._ensure_step(W)
                 m = self.model
+                n_valid = self._n_valid if L == C else jnp.int32(L)
                 s, f, g, smp, new_state = step(
                     frames, self._state, m.B0, m.b, tiles, self._t_score,
-                    self._n_valid, lab, mask)
+                    n_valid, lab, mask)
             telemetry.count("serve.scored_frames", S * C)
             # the same function scores_from_tiles asks; int4 is the one
             # precision the step feeds the kernel packed
@@ -607,7 +615,7 @@ class FleetService:
                 seq=self._seq - 1, scores=s, fired=f, gated=g, sampled=smp,
                 sids=tuple(sid if mask_np[i] else None
                            for i, sid in enumerate(self._slots)),
-                starts=starts, host=assemble)
+                starts=starts, host=assemble, n_valid=L)
             self._pending.append(rec)
         # back-pressure and the checkpoint sit outside ``serve.dispatch``:
         # a tick collected here records its own top-level ``serve.collect``
@@ -627,16 +635,17 @@ class FleetService:
                 f = np.asarray(rec.fired)  # repro-lint: disable=RA003 (same designed sync point)
                 g = np.asarray(rec.gated)  # repro-lint: disable=RA003 (same designed sync point)
                 smp = np.asarray(rec.sampled)  # repro-lint: disable=RA003 (same designed sync point)
-            telemetry.count("serve.sampled_frames", smp.sum())
+            L = rec.n_valid
+            telemetry.count("serve.sampled_frames", smp[:, :L].sum())
             outputs, sampled = {}, {}
             for slot, sid in enumerate(rec.sids):
                 if sid is None:
                     continue
-                outputs[sid] = (s[slot], f[slot], g[slot])
-                sampled[sid] = smp[slot]
+                outputs[sid] = (s[slot, :L], f[slot, :L], g[slot, :L])
+                sampled[sid] = smp[slot, :L]
                 logs = self._logs[sid]
-                logs[0].append(smp[slot])
-                logs[1].append(g[slot])
+                logs[0].append(smp[slot, :L])
+                logs[1].append(g[slot, :L])
             hp_k = stream_mod.resolve_hp_buffer(self.control,
                                                 self.chunk_size,
                                                 rec.host.dtype)
@@ -650,8 +659,7 @@ class FleetService:
                     # was donated into the ADC convert
                     entries, dropped = stream_mod.collect_hp(
                         self._put(rec.host), self._put(g & act[:, None]),
-                        self.chunk_size, hp_k, self.control.hp_bits,
-                        rec.starts)
+                        L, hp_k, self.control.hp_bits, rec.starts)
                     for slot, sid in enumerate(rec.sids):
                         if sid is not None:
                             self._hp[sid].extend(entries[slot])

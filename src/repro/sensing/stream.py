@@ -1,18 +1,20 @@
-"""Batched streaming runtime: chunked scoring + gating + online learning.
+"""The gate step: chunked scoring + gating + online learning.
 
 The paper's sensing loop (§III-B/C) scores *every* incoming frame with the
 HDC HyperSense model and gates the expensive high-precision path in real
 time. ``repro.core.sensor_control.simulate_stream`` does that one frame per
-call — one kernel launch (or one jnp dispatch) per frame. This module is
-the throughput path: frames are consumed in fixed-size chunks and each
-chunk runs
+call — one kernel launch (or one jnp dispatch) per frame. This module holds
+the throughput step and its helpers: frames are consumed in fixed-size
+chunks and each chunk runs
 
   batched fragment scoring  ->  frame_detection_score  ->  threshold
   ->  SensorController hysteresis (as a ``lax.scan``)
   ->  (optionally) an online classifier update
 
-inside a single jitted step. On the ``pallas`` backend the whole chunk is
-ONE kernel launch (grid ``(N, n_dt)``).
+inside a single jitted step (:func:`super_chunk_fn`). On the ``pallas``
+backend the whole chunk is ONE kernel launch (grid ``(N, n_dt)``). The one
+runtime that drives the step is :class:`repro.launch.serve.FleetService`;
+:mod:`repro.launch.replay` replays whole recordings through it.
 
 **Mutable model state.** The model is no longer frozen at construction:
 every chunk threads a :class:`StreamState` pytree — class hypervectors,
@@ -42,8 +44,8 @@ online learning.
 :func:`gate_scan` is the exact jnp twin of
 :class:`~repro.core.sensor_control.SensorController`; the carried ``hold``
 state crosses chunk boundaries, so chunking is invisible:
-:func:`simulate_stream_batched` returns :class:`StreamStats` identical to
-the frame-at-a-time ``simulate_stream``.
+``repro.launch.replay.simulate_stream_batched`` returns ``StreamStats``
+identical to the frame-at-a-time ``simulate_stream``.
 
 **Closed capture loop.** With ``control=``
 (:class:`~repro.core.sensor_control.CaptureConfig`) the gate drives the
@@ -53,7 +55,7 @@ ADC itself: :func:`control_scan` (the jnp twin of
 frame ``t+1`` is converted at all — idle trickle at ``base_rate_hz`` /
 ``adc_bits``, gated bursts at ``active_rate_hz`` with high-precision
 frames gathered into a bounded buffer (:func:`hp_capture`,
-``runner.drain_hp()``). Every runner keeps a
+``FleetService.drain_hp``). The service keeps a per-sensor
 :class:`~repro.core.sensor_control.CaptureLog`;
 :func:`repro.core.energy.from_capture_log` bills from it directly.
 """
@@ -71,10 +73,7 @@ from repro.core import hypersense, online
 from repro.core.encoding import encode_fragments, flat_perm_base
 from repro.core.hypersense import HyperSenseModel, frame_detection_score
 from repro.core.online import AdaptConfig
-from repro.core.sensor_control import (CaptureConfig, CaptureLog,
-                                       ControllerConfig, StreamStats,
-                                       assemble_capture_log, decimation,
-                                       stats_from)
+from repro.core.sensor_control import CaptureConfig
 from repro.sensing import adc as adc_sim
 
 Array = jax.Array
@@ -112,13 +111,10 @@ def init_stream_state(class_hvs: Array, n_streams: int,
 
 def validate_runner_args(chunk_size: int, adc_bits: int | None,
                          adc_sigma: float, precision: str) -> None:
-    """Shared constructor validation for every streaming front-end.
-
-    ``StreamRunner``, :class:`~repro.sensing.fleet.FleetRunner` and
-    :class:`~repro.launch.serve.FleetService` all accept the same
-    (chunk, ADC, precision) surface; this is the ONE place its
-    consistency rules live, so the three cannot drift apart.
-    """
+    """Consistency rules of the (chunk, ADC, precision) configuration
+    :class:`~repro.launch.serve.FleetService` takes (the replay drivers,
+    which build their service on the first recording, check it up
+    front)."""
     if chunk_size < 1:
         raise ValueError(f"chunk_size must be >= 1, got {chunk_size}")
     if adc_sigma > 0.0 and adc_bits is None:
@@ -141,9 +137,8 @@ def adc_view(frames: Array, bits: int, *, sigma: float = 0.0,
 
     Thermal noise (``sigma > 0``) is keyed by *absolute frame index*
     (``start_index + i``), not by call count — re-slicing a stream into
-    different ``process()`` calls yields bit-identical captures, which is
-    what keeps the runners' slicing-invariance property intact with the
-    ADC in the loop.
+    different ticks yields bit-identical captures, which is what keeps
+    slicing invariance intact with the ADC in the loop.
     """
     return adc_sim.quantize(
         _noisy_capture(frames, sigma, key, start_index), bits)
@@ -252,7 +247,7 @@ def hp_capture(raw: Array, gated: Array, n_valid: Array, k: int, bits: int
     is the in-chunk frame index materialized in slot ``j`` (``-1`` =
     empty slot) and ``count`` is the total gated frames — ``count > k``
     means the buffer overflowed and ``count - k`` burst frames were
-    dropped (the runners surface this as ``hp_dropped``). Fixed shapes
+    dropped (the service counts this in ``hp_dropped``). Fixed shapes
     keep the step a single jit trace for every gate outcome.
     """
     C = raw.shape[0]
@@ -268,11 +263,11 @@ def hp_capture(raw: Array, gated: Array, n_valid: Array, k: int, bits: int
 
 def resolve_hp_buffer(control: CaptureConfig | None, chunk_size: int,
                       frames_dtype) -> int:
-    """Per-chunk HP buffer size for a runner (0 = no materialization).
+    """Per-chunk HP buffer size (0 = no materialization).
 
-    The ONE place both runners resolve ``CaptureConfig.hp_buffer``
-    (``None`` → ``chunk_size``) and reject integer-code input, which has
-    no raw frames to HP-capture from.
+    Resolves ``CaptureConfig.hp_buffer`` (``None`` → ``chunk_size``) and
+    rejects integer-code input, which has no raw frames to HP-capture
+    from.
     """
     if control is None:
         return 0
@@ -292,19 +287,17 @@ def collect_hp(raw_chunk: Array, gated: Array, n_valid: int, k: int,
 
     ``raw_chunk`` is ``(S, C, H, W)`` (padded to the chunk size), ``gated``
     the step's ``(S, C)`` gate output. ``base`` offsets the in-chunk frame
-    positions to absolute stream indices — a scalar when every stream sits
-    at the same absolute frame (the runners), or an ``(S,)`` vector when
-    streams run out of phase (the serving layer's ragged slots). Returns
-    (one ``[(absolute_frame_idx, hp_frame), ...]`` list per stream — in
-    frame order — and the number of burst frames dropped to full
-    buffers); shared by every front-end so the drop accounting can never
-    diverge.
+    positions to absolute stream indices — an ``(S,)`` vector, one base
+    per slot (slots run out of phase). Only the first
+    ``n_valid`` frames of each row are candidates. Returns (one
+    ``[(absolute_frame_idx, hp_frame), ...]`` list per stream — in frame
+    order — and the number of burst frames dropped to full buffers).
     """
     buf, idx, cnt = jax.vmap(
         lambda r, gt: hp_capture(r, gt, jnp.int32(n_valid), k, bits))(
             raw_chunk, gated)
     idx, buf = np.asarray(idx), np.asarray(buf)
-    base = np.broadcast_to(np.asarray(base, np.int64), (idx.shape[0],))
+    base = np.asarray(base, np.int64)
     out, dropped = [], 0
     for si in range(idx.shape[0]):
         kept = idx[si] >= 0
@@ -318,8 +311,8 @@ def hp_drain_arrays(entries, frame_hw: tuple[int, int] | None
                     ) -> tuple[np.ndarray, np.ndarray]:
     """One stream's ``[(abs_idx, frame), ...]`` buffer → drain arrays.
 
-    The drain-shape contract every front-end shares: ``(indices (M,)
-    int64, frames (M, H, W) float32)`` — an EMPTY drain still carries
+    The drain-shape contract: ``(indices (M,) int64, frames (M, H, W)
+    float32)`` — an EMPTY drain still carries
     the real frame shape ``(0, H, W)`` (float32 is the ``hp_bits``
     dtype: :func:`hp_capture` materializes bursts as float32
     reconstructions at ``control.hp_bits``), so a consumer can
@@ -360,28 +353,24 @@ def _top_fragment_hvs(frames: Array, maps: Array, B0: Array, b: Array, *,
 
 
 def super_chunk_fn(frames, state: StreamState, B0, b, tiles, t_score,
-                   n_valid, labels, slot_mask=None, *, h, w, stride,
+                   n_valid, labels, slot_mask, *, h, w, stride,
                    nonlinearity, t_detection, hold_frames, backend,
                    adapt: AdaptConfig | None = None,
                    precision: str = "float32", adc_lsb: float = 1.0,
                    decim: int | None = None,
-                   park_masked: bool = False,
                    sensor_axes: tuple[str, ...] | None = None,
                    hyperdim_axes: tuple[str, ...] | None = None):
     """One streaming step over an ``(S, C, H, W)`` super-chunk.
 
-    The shared core of both runners: ``StreamRunner`` calls it with
-    ``S = 1``, :class:`~repro.sensing.fleet.FleetRunner` with S concurrent
-    streams. The ``S*C`` axis is flattened into the batched scorer (one
-    kernel launch on the ``pallas`` backend) and each stream's gate is a
-    ``vmap``'d :func:`gate_scan`.
+    S slots of C frames each: the ``S*C`` axis is flattened into the
+    batched scorer (one kernel launch on the ``pallas`` backend) and each
+    slot's gate is a ``vmap``'d :func:`gate_scan`.
 
     ``state`` carries the mutable model: scoring uses
     ``state.class_hvs``; with ``adapt`` set, the returned state holds the
     chunk-updated classifier. On the ``pallas`` backend ``tiles`` is the
     full host-precomputed :class:`~repro.kernels.sliding_scores.ScoreTiles`
-    when frozen (``adapt=None`` — that path's kernel inputs, and hence
-    outputs, are bitwise identical to the pre-refactor runtime), or just
+    when frozen (``adapt=None``), or just
     the :class:`~repro.kernels.sliding_scores.ScoreGeometry` when
     adapting — the current classifier is re-tiled *inside* the step by
     the jitted ``retile_classes`` gather.
@@ -416,22 +405,17 @@ def super_chunk_fn(frames, state: StreamState, B0, b, tiles, t_score,
     of the decision, the gate, and the online update), and ``decim == 1``
     reproduces the open-loop outputs bitwise.
 
-    ``slot_mask`` (``(S,)`` bool, default all-true) marks *real* sensor
-    slots: the fleet pads S up to the mesh extent with masked slots so a
-    non-divisible fleet still shards (never a recompile or an unsharded
-    fallback). Masked slots never fire, never sample, and never
-    contribute to a shared-scope update — their presence is an exact
-    no-op on every real slot's outputs and on the shared classifier.
-
-    ``park_masked`` additionally freezes the masked slots' *carried
-    state* in place: their hold/phase counters (which would otherwise
-    decay through the chunk) and, in per-stream scope, their classifier
-    rows pass through unchanged. This is the serving layer's slot-pool
-    semantics (:class:`repro.launch.serve.FleetService`): a sensor that
-    sent no frames this tick experienced no time, so a later reattach
-    resumes exactly where it detached. With an all-true ``slot_mask``
-    the selects are identities — the parked step is bitwise the plain
-    one, which is what lets the service share this trace.
+    ``slot_mask`` (``(S,)`` bool) marks the slots that delivered frames
+    this tick: empty pool slots, slots padding S up to the mesh extent,
+    and silent sensors are masked. Masked slots never fire, never
+    sample, and never contribute to a shared-scope update — their
+    presence is an exact no-op on every real slot's outputs and on the
+    shared classifier. Their *carried state* is parked in place: the
+    hold/phase counters (which would otherwise decay through the chunk)
+    and, in per-stream scope, the classifier rows pass through
+    unchanged — a sensor that sent no frames this tick experienced no
+    time, so a later reattach resumes exactly where it detached. With an
+    all-true ``slot_mask`` the selects are identities.
 
     ``sensor_axes`` / ``hyperdim_axes`` name the mesh axes this step is
     ``shard_map``'d over (None outside a mesh). ``hyperdim_axes`` flows
@@ -514,13 +498,10 @@ def super_chunk_fn(frames, state: StreamState, B0, b, tiles, t_score,
         fired = jnp.zeros((S, C), bool)
     else:
         fired = (scores > t_score) & valid[None, :]
-    if slot_mask is not None:
-        fired = fired & slot_mask[:, None]
+    fired = fired & slot_mask[:, None]
 
     if decim is None:
-        sampled = jnp.broadcast_to(valid[None, :], (S, C))
-        if slot_mask is not None:
-            sampled = sampled & slot_mask[:, None]
+        sampled = valid[None, :] & slot_mask[:, None]
         gated, holds_seq = jax.vmap(
             lambda f, h0: gate_scan(f, hold_frames, h0))(fired, state.holds)
         phase_out = state.phases
@@ -531,8 +512,7 @@ def super_chunk_fn(frames, state: StreamState, B0, b, tiles, t_score,
                                                p0))(
                     fired, state.holds, state.phases)
         fired = fired & sampled
-        if slot_mask is not None:
-            sampled = sampled & slot_mask[:, None]
+        sampled = sampled & slot_mask[:, None]
         phase_out = jnp.where(n_valid > 0,
                               phases_seq[:, jnp.maximum(n_valid - 1, 0)],
                               state.phases)
@@ -586,10 +566,8 @@ def super_chunk_fn(frames, state: StreamState, B0, b, tiles, t_score,
                 (chvs, hv, labels, mask2d))
 
         if decim is None:
-            # masked pad slots contribute nothing (exact no-op selects)
-            mask2d = jnp.broadcast_to(valid[None, :], (S, C))
-            if slot_mask is not None:
-                mask2d = mask2d & slot_mask[:, None]
+            # masked slots contribute nothing (exact no-op selects)
+            mask2d = valid[None, :] & slot_mask[:, None]
             if per_stream:
                 class_hvs = _per_stream_fold(class_hvs, hv, labels, mask2d)
             else:
@@ -604,15 +582,14 @@ def super_chunk_fn(frames, state: StreamState, B0, b, tiles, t_score,
             else:
                 class_hvs = _shared_fold(class_hvs, hv, labels, seen)
 
-    if park_masked and slot_mask is not None:
-        # slot-pool semantics: a masked slot's carried state is parked in
-        # place — no hold/phase decay, no classifier churn — so detached
-        # or silent sensors resume bitwise where they stopped
-        hold_out = jnp.where(slot_mask, hold_out, state.holds)
-        phase_out = jnp.where(slot_mask, phase_out, state.phases)
-        if class_hvs.ndim == 3:
-            class_hvs = jnp.where(slot_mask[:, None, None], class_hvs,
-                                  state.class_hvs)
+    # a masked slot's carried state is parked in place — no hold/phase
+    # decay, no classifier churn — so detached or silent sensors resume
+    # bitwise where they stopped
+    hold_out = jnp.where(slot_mask, hold_out, state.holds)
+    phase_out = jnp.where(slot_mask, phase_out, state.phases)
+    if class_hvs.ndim == 3:
+        class_hvs = jnp.where(slot_mask[:, None, None], class_hvs,
+                              state.class_hvs)
 
     new_state = StreamState(class_hvs=class_hvs, holds=hold_out,
                             phases=phase_out,
@@ -623,24 +600,20 @@ def super_chunk_fn(frames, state: StreamState, B0, b, tiles, t_score,
 
 _STEP_STATIC = ("h", "w", "stride", "nonlinearity", "t_detection",
                 "hold_frames", "backend", "adapt", "precision", "adc_lsb",
-                "decim", "park_masked", "sensor_axes", "hyperdim_axes")
+                "decim", "sensor_axes", "hyperdim_axes")
 
-#: module-level jit: every runner instance shares one trace cache.
-super_chunk_step = jax.jit(super_chunk_fn, static_argnames=_STEP_STATIC)
-
-#: the serving twin: identical trace, but the carried
-#: :class:`StreamState` (arg 1) is DONATED — XLA aliases it into the
-#: step's output state, so a long-running
+#: module-level jit: every unsharded service shares one trace cache. The
+#: carried :class:`StreamState` (arg 1) is DONATED — XLA aliases it into
+#: the step's output state, so a long-running
 #: :class:`repro.launch.serve.FleetService` rolls one state allocation
 #: forever instead of allocating per chunk. (The super-chunk buffer
 #: itself is donated one stage earlier, at the service's ADC-convert
 #: jit, where input and output shapes actually alias; no step output
 #: matches the ``(S, C, H, W)`` frames, so donating arg 0 here could
-#: never be used.) Donated inputs are dead after the call; only the
-#: service (which never re-reads its carried state) may use this.
-super_chunk_step_donated = jax.jit(super_chunk_fn,
-                                   static_argnames=_STEP_STATIC,
-                                   donate_argnums=(1,))
+#: never be used.) Donated inputs are dead after the call: the caller
+#: never re-reads its carried state.
+super_chunk_step = jax.jit(super_chunk_fn, static_argnames=_STEP_STATIC,
+                           donate_argnums=(1,))
 
 
 def model_geometry(model: HyperSenseModel, W: int, block_d: int,
@@ -666,293 +639,3 @@ def model_tiles(model: HyperSenseModel, W: int, block_d: int,
     fn = (kops.retile_classes_int if precision in adc_sim.INT_PRECISIONS
           else kops.retile_classes)
     return fn(geom, model.class_hvs)
-
-
-class StreamRunner:
-    """Stateful chunked scorer+gate(+learner). ``process(frames)`` freely.
-
-    The :class:`StreamState` — controller ``hold``, absolute frame index,
-    and (with ``adapt``) the live class hypervectors — carries across
-    ``process`` calls, so a long stream can be fed incrementally in
-    arbitrary slices; every internal step is one fixed-shape jit call
-    (tail chunks are padded and masked, so no recompiles).
-
-    ``adapt=None`` (default) is the frozen runtime — bitwise identical to
-    the pre-online-learning runner on the ``pallas`` backend. With an
-    :class:`~repro.core.online.AdaptConfig` the classifier updates every
-    chunk; in ``"label"`` mode pass per-frame labels to ``process``. The
-    live classifier is ``runner.class_hvs``; :meth:`set_class_hvs`
-    installs an external update mid-stream (a jitted ``retile_classes``
-    gather on the ``pallas`` backend — never a host-side re-precompute;
-    the tile cache is keyed on class-hv *identity*, so stale tiles are
-    impossible).
-
-    ``control=`` (a :class:`~repro.core.sensor_control.CaptureConfig`)
-    closes the capture loop: the ``ControllerConfig`` rates stop being
-    decorative — idle frames are LP-converted at ``base_rate_hz`` only
-    (temporal decimation inside the chunk scan; skipped frames can never
-    fire), gate bursts capture every frame, and the gated frames are
-    additionally converted at ``control.hp_bits`` into a bounded buffer,
-    drained via :meth:`drain_hp` — the runtime's deliverable to the
-    downstream backend. Every runner (open- or closed-loop) keeps a
-    :attr:`capture_log` of what the ADC actually converted, which
-    :func:`repro.core.energy.from_capture_log` bills directly. With
-    ``base == active`` rates or ``subsample=False`` the closed-loop
-    outputs are bitwise-identical to ``control=None``.
-    """
-
-    def __init__(self, model: HyperSenseModel,
-                 config: ControllerConfig | None = None, *,
-                 chunk_size: int = 32, backend: str = "jnp",
-                 t_detection: int | None = None, block_d: int = 512,
-                 adc_bits: int | None = None, adc_sigma: float = 0.0,
-                 adc_key: Array | int = 0,
-                 adapt: AdaptConfig | None = None,
-                 precision: str = "float32",
-                 control: CaptureConfig | None = None):
-        validate_runner_args(chunk_size, adc_bits, adc_sigma, precision)
-        if adapt is not None and adapt.scope == "per-stream":
-            raise ValueError('scope="per-stream" is a FleetRunner mode; '
-                             "a StreamRunner has exactly one stream — "
-                             'use scope="shared"')
-        self.precision = precision
-        self.model = model
-        self.config = config or ControllerConfig()
-        self.chunk_size = chunk_size
-        self.backend = backend
-        self.block_d = block_d
-        self.t_detection = (model.t_detection if t_detection is None
-                            else t_detection)
-        self.adc_bits = adc_bits
-        self.adc_sigma = adc_sigma
-        self._adc_key = (jax.random.PRNGKey(adc_key)
-                         if isinstance(adc_key, int) else adc_key)
-        self.adapt = adapt
-        self.control = control
-        self._decim = (None if control is None
-                       else (decimation(self.config) if control.subsample
-                             else 1))
-        self._geom = None       # (W, ScoreGeometry) — class-independent
-        self._tiles = None      # (W, class_hvs-ref, ScoreTiles) frozen path
-        self._state = init_stream_state(model.class_hvs, 1)
-        self._n_seen = 0        # absolute frame index (keys the ADC noise)
-        self._log_sampled: list[np.ndarray] = []
-        self._log_gated: list[np.ndarray] = []
-        self._frame_pixels = 0
-        self._frame_hw: tuple[int, int] | None = None
-        self._hp_idx: list[int] = []
-        self._hp_frames: list[np.ndarray] = []
-        self.hp_dropped = 0     # burst frames lost to a full HP buffer
-
-    def reset(self) -> None:
-        self._state = init_stream_state(self.model.class_hvs, 1)
-        self._n_seen = 0
-        self._tiles = None
-        self._log_sampled = []
-        self._log_gated = []
-        self._hp_idx = []
-        self._hp_frames = []
-        self.hp_dropped = 0
-
-    @property
-    def class_hvs(self) -> Array:
-        """The live classifier (updates under ``adapt``)."""
-        return self._state.class_hvs
-
-    @property
-    def _hold(self) -> Array:   # back-compat scalar view of the gate state
-        return self._state.holds[0]
-
-    def set_class_hvs(self, class_hvs: Array) -> None:
-        """Install an externally updated classifier mid-stream.
-
-        Device-side cost only: the next chunk re-tiles via the jitted
-        ``retile_classes`` gather against the cached geometry (the frozen
-        tile cache self-invalidates — it is keyed on class-hv identity).
-        """
-        class_hvs = jnp.asarray(class_hvs)
-        self.model = self.model._replace(class_hvs=class_hvs)
-        self._state = dataclasses.replace(self._state,
-                                          class_hvs=class_hvs)
-
-    def _ensure_geom(self, W: int):
-        if self._geom is None or self._geom[0] != W:
-            self._geom = (W, model_geometry(self.model, W, self.block_d,
-                                            self.precision))
-        return self._geom[1]
-
-    def _ensure_tiles(self, W: int):
-        """Frozen-path tile cache, keyed on (width, class-hv identity)."""
-        from repro.kernels import ops as kops
-        retile = (kops.retile_classes_int
-                  if self.precision in adc_sim.INT_PRECISIONS
-                  else kops.retile_classes)
-        chvs = self._state.class_hvs
-        if (self._tiles is None or self._tiles[0] != W
-                or self._tiles[1] is not chvs):
-            self._tiles = (W, chvs, retile(self._ensure_geom(W), chvs))
-        return self._tiles[2]
-
-    @property
-    def _adc_lsb(self) -> float:
-        return (adc_sim.lsb(self.adc_bits)
-                if self.precision in adc_sim.INT_PRECISIONS else 1.0)
-
-    @property
-    def capture_log(self) -> CaptureLog:
-        """What the ADC actually converted so far (across ``process``
-        calls; cleared by :meth:`reset`) — the billing ground truth for
-        :func:`repro.core.energy.from_capture_log`."""
-        return assemble_capture_log(self._log_sampled, self._log_gated,
-                                    lp_bits=self.adc_bits,
-                                    control=self.control,
-                                    frame_pixels=self._frame_pixels)
-
-    def drain_hp(self) -> tuple[np.ndarray, np.ndarray]:
-        """Take the high-precision burst frames captured so far.
-
-        Returns ``(indices (M,) — absolute frame indices, frames
-        (M, H, W) at control.hp_bits)`` and empties the buffer; frames a
-        full per-chunk buffer dropped are counted in ``hp_dropped``. An
-        empty drain keeps the real ``(0, H, W)`` frame shape
-        (:func:`hp_drain_arrays`) so cross-drain concatenation works.
-        """
-        idx, frames = hp_drain_arrays(
-            list(zip(self._hp_idx, self._hp_frames)), self._frame_hw)
-        self._hp_idx, self._hp_frames = [], []
-        return idx, frames
-
-    def process(self, frames, labels=None
-                ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """(n, H, W) frames -> (scores (n,), fired (n,), gated (n,)).
-
-        With ``adc_bits`` set, the scorer sees the low-precision ADC
-        capture of each frame (:func:`adc_view`) — the paper's always-on
-        path — while the caller keeps the raw high-precision frames for
-        whatever the gate lets through. With an integer precision the
-        capture stays *integer codes* end to end (:func:`adc_view_codes`
-        into the fused int kernel; raw integer input is treated as
-        already-converted codes — ``"int4"`` additionally nibble-packs
-        at the kernel boundary). ``labels`` (``(n,)`` ints) feeds
-        ``adapt.mode == "label"`` updates.
-        """
-        frames = jnp.asarray(frames)
-        raw = frames
-        self._frame_pixels = int(frames.shape[-2] * frames.shape[-1])
-        self._frame_hw = (int(frames.shape[-2]), int(frames.shape[-1]))
-        hp_k = resolve_hp_buffer(self.control, self.chunk_size,
-                                 frames.dtype)
-        base = self._n_seen
-        if self.adapt is not None and self.adapt.mode == "label":
-            if labels is None:
-                raise ValueError('adapt.mode == "label" needs per-frame '
-                                 "labels passed to process()")
-            labels = jnp.asarray(labels, jnp.int32)
-            if labels.shape != frames.shape[:1]:
-                raise ValueError(f"labels shape {labels.shape} != "
-                                 f"(n,) = {frames.shape[:1]}")
-        if self.precision in adc_sim.INT_PRECISIONS:
-            from repro.kernels import ops as kops
-            kops.assert_int_datapath_fits(self.adc_bits, *frames.shape[-2:],
-                                          self.model.h, self.model.w,
-                                          stride=self.model.stride,
-                                          block_d=self.block_d,
-                                          packed=self.precision == "int4")
-            frames = adc_view_codes(frames, self.adc_bits,
-                                    sigma=self.adc_sigma,
-                                    key=self._adc_key,
-                                    start_index=self._n_seen)
-        elif self.adc_bits is not None:
-            frames = adc_view(frames, self.adc_bits, sigma=self.adc_sigma,
-                              key=self._adc_key, start_index=self._n_seen)
-        n = frames.shape[0]
-        self._n_seen += n
-        m = self.model
-        if (self.backend == "pallas"
-                or self.precision in adc_sim.INT_PRECISIONS):
-            tiles = (self._ensure_geom(frames.shape[-1])
-                     if self.adapt is not None
-                     else self._ensure_tiles(frames.shape[-1]))
-        else:
-            tiles = None
-        scores = np.empty(n, np.float32)
-        fired = np.empty(n, bool)
-        gated = np.empty(n, bool)
-        for start in range(0, n, self.chunk_size):
-            chunk = frames[start:start + self.chunk_size]
-            lab = (labels[start:start + self.chunk_size]
-                   if labels is not None
-                   else jnp.zeros(chunk.shape[0], jnp.int32))
-            n_valid = chunk.shape[0]
-            if n_valid < self.chunk_size:
-                pad = self.chunk_size - n_valid
-                chunk = jnp.pad(chunk, ((0, pad), (0, 0), (0, 0)))
-                lab = jnp.pad(lab, (0, pad))
-            s, f, g, smp, new_state = super_chunk_step(
-                chunk[None], self._state, m.B0, m.b, tiles,
-                jnp.float32(m.t_score), jnp.int32(n_valid), lab[None],
-                h=m.h, w=m.w, stride=m.stride,
-                nonlinearity=m.nonlinearity, t_detection=self.t_detection,
-                hold_frames=self.config.hold_frames, backend=self.backend,
-                adapt=self.adapt, precision=self.precision,
-                adc_lsb=self._adc_lsb, decim=self._decim)
-            if self.adapt is None:
-                # keep the ORIGINAL class-hv ref: values are untouched and
-                # the identity-keyed tile cache must not churn
-                new_state = dataclasses.replace(
-                    new_state, class_hvs=self._state.class_hvs)
-            self._state = new_state
-            sl = slice(start, start + n_valid)
-            scores[sl] = np.asarray(s)[0, :n_valid]
-            fired[sl] = np.asarray(f)[0, :n_valid]
-            gated[sl] = np.asarray(g)[0, :n_valid]
-            self._log_sampled.append(np.asarray(smp)[0, :n_valid])
-            self._log_gated.append(gated[sl].copy())
-            if hp_k > 0:
-                raw_chunk = raw[start:start + self.chunk_size]
-                if n_valid < self.chunk_size:
-                    raw_chunk = jnp.pad(
-                        raw_chunk,
-                        ((0, self.chunk_size - n_valid), (0, 0), (0, 0)))
-                entries, dropped = collect_hp(
-                    raw_chunk[None], g, n_valid, hp_k,
-                    self.control.hp_bits, base + start)
-                self._hp_idx.extend(i for i, _ in entries[0])
-                self._hp_frames.extend(f for _, f in entries[0])
-                self.hp_dropped += dropped
-        return scores, fired, gated
-
-
-def simulate_stream_batched(model: HyperSenseModel, frames, labels,
-                            config: ControllerConfig | None = None, *,
-                            chunk_size: int = 32, backend: str = "jnp",
-                            t_detection: int | None = None,
-                            block_d: int = 512,
-                            adc_bits: int | None = None,
-                            adc_sigma: float = 0.0,
-                            adc_key: Array | int = 0,
-                            adapt: AdaptConfig | None = None,
-                            precision: str = "float32",
-                            control: CaptureConfig | None = None
-                            ) -> StreamStats:
-    """Chunked-batched twin of ``sensor_control.simulate_stream``.
-
-    Produces identical :class:`StreamStats` to replaying
-    ``hypersense.detect`` frame-at-a-time through ``SensorController``,
-    but runs ``len(frames)/chunk_size`` jitted steps instead of
-    ``len(frames)`` dispatches (one kernel launch per chunk on the
-    ``pallas`` backend). ``adc_bits`` puts the simulated low-precision
-    ADC in front of the gate (pass raw frames). ``adapt`` switches on
-    online learning — in ``"label"`` mode the ground-truth ``labels``
-    double as the feedback signal.
-    """
-    runner = StreamRunner(model, config, chunk_size=chunk_size,
-                          backend=backend, t_detection=t_detection,
-                          block_d=block_d, adc_bits=adc_bits,
-                          adc_sigma=adc_sigma, adc_key=adc_key,
-                          adapt=adapt, precision=precision,
-                          control=control)
-    feed = (labels if adapt is not None and adapt.mode == "label"
-            else None)
-    _, fired, gated = runner.process(frames, labels=feed)
-    return stats_from(fired, gated, labels)

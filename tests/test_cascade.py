@@ -5,7 +5,7 @@ repro/launch/steps.py detector cell, repro/launch/cascade.py service):
 
 * ``drain_hp()`` ALWAYS returns frames shaped ``(M, H, W)`` — an empty
   drain after any processed frame is ``(0, H, W)``, never ``(0, 0, 0)``,
-  on all three runners (StreamRunner, FleetRunner, FleetService), so
+  on the service and both replay drivers (StreamRunner, FleetRunner), so
   consumers can concatenate drains blindly;
 * drained indices are ABSOLUTE frame numbers, strictly increasing
   across drains, and chunked drain+concat == one-shot drain bitwise;
@@ -31,9 +31,10 @@ from repro.core.sensor_control import (CaptureConfig, CaptureLog,
                                        assemble_capture_log)
 from repro.launch import steps
 from repro.launch.cascade import CascadeService
+from repro.launch.replay import FleetRunner, StreamRunner
 from repro.launch.serve import FleetService
-from repro.sensing.fleet import FleetRunner
-from repro.sensing.stream import StreamRunner, hp_drain_arrays
+from repro.sensing import adc
+from repro.sensing.stream import hp_drain_arrays
 
 C = 4          # chunk size
 HW = (16, 16)  # frame shape (divisible by the detector patch)
@@ -211,21 +212,21 @@ def test_drain_checkpoint_restore_preserves_undrained(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# assemble_capture_log (the runners' shared log assembly)
+# assemble_capture_log (one stream's log from its per-tick blocks)
 # ---------------------------------------------------------------------------
 
 def test_assemble_capture_log_empty_and_axis():
     log = assemble_capture_log([], [], lp_bits=4, control=CTL,
                                frame_pixels=64)
     assert log.sampled.shape == (0,) and log.hp_bits == CTL.hp_bits
-    fleet = assemble_capture_log([], [], lp_bits=None, control=None,
-                                 frame_pixels=64, axis=1)
-    assert fleet.sampled.shape == (0, 0) and fleet.hp_bits is None
-    two = assemble_capture_log([np.ones((2, 3), bool)] * 2,
-                               [np.zeros((2, 3), bool)] * 2,
+    open_loop = assemble_capture_log([], [], lp_bits=None, control=None,
+                                     frame_pixels=64)
+    assert open_loop.gated.shape == (0,) and open_loop.hp_bits is None
+    two = assemble_capture_log([np.ones(3, bool), np.ones(2, bool)],
+                               [np.zeros(3, bool), np.zeros(2, bool)],
                                lp_bits=None, control=None,
-                               frame_pixels=64, axis=1)
-    assert two.sampled.shape == (2, 6)
+                               frame_pixels=64)
+    assert two.sampled.shape == (5,) and two.gated.shape == (5,)
 
 
 # ---------------------------------------------------------------------------
@@ -331,26 +332,30 @@ def test_cascade_matches_eager_across_ragged_drains(detector):
 
 
 def test_cascade_pump_closes_the_loop(detector):
-    """StreamRunner gate → pump → backbone: the full paper loop, with
-    results keyed by the gate's absolute frame indices."""
+    """FleetService gate → pump → backbone: the full paper loop, with
+    results keyed by (sensor, the gate's absolute frame index)."""
     cfg, params = detector
     model = make_model(ALWAYS)
-    r = StreamRunner(model, CFG, chunk_size=C, block_d=64, control=CTL)
+    svc = FleetService(model, CFG, n_slots=1, chunk_size=C, block_d=64,
+                       control=CTL)
+    svc.attach("radar")
     casc = CascadeService(params, cfg, batch_size=4, frame_hw=HW)
     trace = frames_of(3 * C, seed=8)
-    hp = {}
+    taken = 0
     for t in range(0, len(trace), C):
-        r.process(trace[t:t + C])
-        idx, frames = r.drain_hp()
-        hp.update({int(i): f for i, f in zip(idx, frames)})
-        casc.submit(0, idx, frames)      # what pump() does per drain
-    assert len(hp)
+        svc.dispatch({"radar": trace[t:t + C]})
+        svc.flush()
+        taken += casc.pump(svc)
+    assert taken
     batches = casc.flush()
+    assert {s for b in batches for s in b.sids} == {"radar"}
     got = {int(i): row for b in batches
            for i, row in zip(b.frame_idx, b.logits)}
-    assert set(got) == set(hp)
-    eager = casc.eager(np.stack([hp[i] for i in sorted(hp)]))
-    for j, i in enumerate(sorted(hp)):
+    assert len(got) == taken
+    idx = np.array(sorted(got))
+    # the HP captures are the raw frames at hp_bits
+    eager = casc.eager(np.asarray(adc.quantize(trace[idx], CTL.hp_bits)))
+    for j, i in enumerate(idx):
         np.testing.assert_array_equal(got[i], eager[j])
     assert casc.compile_count() == 1
 

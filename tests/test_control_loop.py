@@ -34,9 +34,10 @@ from repro.core import encoding, energy, hypersense
 from repro.core.sensor_control import (CaptureConfig, CaptureLog,
                                        ControllerConfig, RateController,
                                        decimation, stats_from)
+from repro.launch.replay import FleetRunner, StreamRunner
 from repro.sensing import adc, synthetic
-from repro.sensing.fleet import FleetRunner, fleet_report
-from repro.sensing.stream import StreamRunner, control_scan, hp_capture
+from repro.sensing.fleet import fleet_report
+from repro.sensing.stream import control_scan, hp_capture
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -173,7 +174,7 @@ def test_disabled_control_bitwise_identical(backend, precision):
         np.testing.assert_array_equal(f, f0)
         np.testing.assert_array_equal(g, g0)
     assert off.capture_log.sampled.all()
-    assert same._decim == 1 and off._decim == 1
+    assert same.service._decim == 1 and off.service._decim == 1
 
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])

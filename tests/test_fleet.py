@@ -1,6 +1,6 @@
 """Fleet streaming runtime: S-stream batched path == S independent runners.
 
-The contract (ISSUE 2 acceptance): ``FleetRunner`` over S streams returns
+The contract: ``FleetRunner`` over S streams returns
 per-stream results/``StreamStats`` identical to S independent
 ``StreamRunner`` instances — on both the ``jnp`` and ``pallas`` backends,
 with and without the ADC in the loop, and unchanged under sensor-axis
@@ -17,9 +17,11 @@ from repro.core import encoding, energy, hypersense
 from repro.core.sensor_control import ControllerConfig
 from repro.distributed import sharding as shlib
 from repro.launch.mesh import make_mesh
+from repro.launch.replay import (FleetRunner, StreamRunner, simulate_fleet,
+                                 simulate_stream_batched)
 from repro.sensing import adc, synthetic
-from repro.sensing.fleet import (FleetRunner, fleet_report, simulate_fleet)
-from repro.sensing.stream import StreamRunner, simulate_stream_batched
+from repro.sensing.fleet import fleet_report
+from repro.sensing.stream import adc_view
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -134,8 +136,9 @@ def test_fleet_adc_internal_equals_prequantized():
 
 
 def test_fleet_noisy_adc_matches_independent_runners():
-    """Per-(stream, frame-index) noise keys: the fleet's ADC captures are
-    exactly the ones S independent runners with folded keys would see."""
+    """Per-(stream, frame-index) noise keys: stream s's ADC captures are
+    exactly ``adc_view`` keyed by ``fold_in(adc_key, s)`` — an
+    independent noiseless runner fed those captures sees the same."""
     model = make_model()
     frames, _ = make_fleet(S=3, N=11)
     cfg = ControllerConfig(hold_frames=2)
@@ -145,10 +148,10 @@ def test_fleet_noisy_adc_matches_independent_runners():
     out = fr.process(frames)
     singles = []
     for s in range(3):
-        r = StreamRunner(model, cfg, chunk_size=4, adc_bits=4,
-                         adc_sigma=0.02,
-                         adc_key=jax.random.fold_in(base, s))
-        singles.append(r.process(frames[s]))
+        captured = adc_view(frames[s], 4, sigma=0.02,
+                            key=jax.random.fold_in(base, s))
+        r = StreamRunner(model, cfg, chunk_size=4, adc_bits=4)
+        singles.append(r.process(captured))
     assert_streams_equal(out, singles)
 
 
@@ -295,7 +298,7 @@ def test_fleet_non_divisible_sensor_axis_pads_and_shards(S):
         axes, k = fleet_mod._sensor_axes(mesh)
         assert axes == ("data",) and k == jax.device_count()
         s1, f1, g1 = r.process(frames)
-        assert r._step_key[1] == ("data",)   # the built step is sharded
+        assert r.service._step_axes[0] == ("data",)   # the step is sharded
     np.testing.assert_array_equal(s0, s1)
     np.testing.assert_array_equal(f0, f1)
     np.testing.assert_array_equal(g0, g1)
@@ -321,7 +324,7 @@ def test_fleet_shared_adapt_sharded_no_fallback():
     with shlib.use_mesh(mesh):
         r = FleetRunner(model, cfg, chunk_size=4, adapt=ad)
         s1, f1, g1 = r.process(frames, labels=labels)
-        assert r._step_key[1] == ("data",)   # sharded, no fallback
+        assert r.service._step_axes[0] == ("data",)   # no fallback
     np.testing.assert_array_equal(s0, s1)
     np.testing.assert_array_equal(f0, f1)
     np.testing.assert_array_equal(g0, g1)

@@ -27,9 +27,9 @@ from repro.core import encoding, hypersense
 from repro.core.online import AdaptConfig
 from repro.core.sensor_control import ControllerConfig, stats_from
 from repro.launch.mesh import make_mesh
+from repro.launch.replay import FleetRunner, StreamRunner
 from repro.sensing import synthetic
-from repro.sensing.fleet import FleetRunner, fleet_report
-from repro.sensing.stream import StreamRunner
+from repro.sensing.fleet import fleet_report
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -194,7 +194,7 @@ def scenario_fleet_sharded():
         scores, fired, gated = r.process(frames, labels=labels)
     # the step must really have sharded BOTH axes — a fallback would
     # freeze fallback numbers into the fixture
-    assert r._step_key[1] == ("data",) and r._step_key[2] == ("model",)
+    assert r.service._step_axes == (("data",), ("model",))
     _assert_decision_margin(scores, model.t_score)
     rep = fleet_report(fired, gated, labels, capture=r.capture_log)
     return {

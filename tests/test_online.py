@@ -9,8 +9,7 @@ Contracts:
 * ``adapt=None`` runners stay bitwise-identical to the frozen pipeline
   (batched kernel scoring + ``gate_scan``) on the pallas backend;
 * installing a new classifier is ``retile_classes`` (bitwise-equal to the
-  host ``precompute_tiles``) and the runners' tile caches are keyed on
-  class-hv *identity* — a mutated model can never score via stale tiles;
+  host ``precompute_tiles``);
 * the fleet's per-stream adaptation (one launch, stream-indexed class
   tiles) matches S independent adaptive runners.
 """
@@ -31,10 +30,10 @@ from repro.core.sensor_control import ControllerConfig
 from repro.kernels import ops as kops
 from repro.kernels import sliding_scores as k_ss
 from repro.launch.mesh import make_mesh
+from repro.launch.replay import FleetRunner, StreamRunner
 from repro.sensing import synthetic
-from repro.sensing.fleet import FleetRunner
-from repro.sensing.stream import (StreamRunner, _top_fragment_hvs,
-                                  gate_scan, model_geometry)
+from repro.sensing.stream import (_top_fragment_hvs, gate_scan,
+                                  model_geometry)
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -311,7 +310,7 @@ def test_stream_state_frame_idx_advances():
     r = StreamRunner(m, ControllerConfig(hold_frames=2), chunk_size=4)
     frames, _ = make_fleet(S=1, N=11)
     r.process(frames[0])
-    assert int(np.asarray(r._state.frame_idx)) == 11
+    assert int(np.asarray(r.service._state.frame_idx)) == 11
 
 
 # ---------------------------------------------------------------------------
@@ -398,7 +397,7 @@ def test_fleet_shared_adapt_sharded_folds_time_ordered():
     mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     fr = run(mesh)
     # the step really sharded: no shared-scope fallback survives
-    assert fr._step_key[1] == ("data",)
+    assert fr.service._step_axes[0] == ("data",)
     np.testing.assert_array_equal(np.asarray(fr.class_hvs),
                                   np.asarray(run(None).class_hvs))
 
@@ -452,75 +451,21 @@ def test_fleet_frozen_still_bitwise_after_refactor():
 
 
 # ---------------------------------------------------------------------------
-# tile-cache identity keying (stale-precompute impossibility)
+# the frozen tiles are built once
 # ---------------------------------------------------------------------------
 
-def test_set_class_hvs_refreshes_tiles_mid_stream():
-    m = make_model()
-    frames, _ = make_fleet(S=1, N=8)
-    frames = frames[0]
-    r = StreamRunner(m, ControllerConfig(hold_frames=2), chunk_size=4,
-                     backend="pallas", block_d=64)
-    s_before, _, _ = r.process(frames)
-    chvs2 = jax.random.normal(key(30), (2, 128))
-    r.set_class_hvs(chvs2)
-    s_after, _, _ = r.process(frames)
-    fresh = StreamRunner(m._replace(class_hvs=chvs2),
-                         ControllerConfig(hold_frames=2), chunk_size=4,
-                         backend="pallas", block_d=64)
-    s_fresh, _, _ = fresh.process(frames)
-    np.testing.assert_array_equal(s_after, s_fresh)
-    assert not np.array_equal(s_before, s_after)
-
-
-def test_fleet_set_class_hvs_refreshes_tiles_mid_stream():
-    m = make_model()
-    frames, _ = make_fleet(S=2, N=8)
-    fr = FleetRunner(m, ControllerConfig(hold_frames=2), chunk_size=4,
-                     backend="pallas", block_d=64)
-    fr.process(frames)
-    chvs2 = jax.random.normal(key(31), (2, 128))
-    fr.set_class_hvs(chvs2)
-    s_after, _, _ = fr.process(frames)
-    fresh = FleetRunner(m._replace(class_hvs=chvs2),
-                        ControllerConfig(hold_frames=2), chunk_size=4,
-                        backend="pallas", block_d=64)
-    s_fresh, _, _ = fresh.process(frames)
-    np.testing.assert_array_equal(s_after, s_fresh)
-
-
-def test_fleet_set_per_stream_class_hvs_before_first_process():
-    """An (S, 2, D) classifier installed before any process() call must
-    be honored (not silently replaced by the model's on first chunk)."""
-    m = make_model()
-    frames, labels = make_fleet(S=2, N=8)
-    ad = AdaptConfig(mode="label", lr=0.0, scope="per-stream")
-    chvs = jax.random.normal(key(32), (2, 2, 128))
-    fr = FleetRunner(m, ControllerConfig(hold_frames=2), chunk_size=4,
-                     adapt=ad)
-    fr.set_class_hvs(chvs)
-    s_got, _, _ = fr.process(frames, labels=labels)
-    for s in range(2):
-        r = StreamRunner(m._replace(class_hvs=chvs[s]),
-                         ControllerConfig(hold_frames=2), chunk_size=4)
-        s_i, _, _ = r.process(frames[s])
-        np.testing.assert_allclose(s_got[s], s_i, rtol=1e-5, atol=1e-5)
-    # ...and a per-stream stack without per-stream scope is rejected
-    with pytest.raises(ValueError):
-        FleetRunner(m, adapt=AdaptConfig(mode="label")).set_class_hvs(chvs)
-
-
 def test_frozen_tile_cache_does_not_churn():
-    """adapt=None: repeated process() calls must reuse the cached tiles
-    object (identity key stable across chunks)."""
+    """adapt=None: the service builds its tiles once — every later tick
+    and process() call reuses the same tiles object."""
     m = make_model()
     frames, _ = make_fleet(S=1, N=8)
     r = StreamRunner(m, ControllerConfig(hold_frames=2), chunk_size=4,
                      backend="pallas", block_d=64)
     r.process(frames[0])
-    first = r._tiles
+    first = r.service._tiles
+    assert first is not None
     r.process(frames[0])
-    assert r._tiles is first
+    assert r.service._tiles is first
 
 
 # ---------------------------------------------------------------------------

@@ -43,9 +43,8 @@ from repro.core import fragment_model as fm, hypersense
 from repro.core.online import AdaptConfig
 from repro.core.sensor_control import ControllerConfig
 from repro.launch.mesh import make_mesh
+from repro.launch.replay import FleetRunner, StreamRunner
 from repro.sensing import fragments, synthetic
-from repro.sensing.fleet import FleetRunner
-from repro.sensing.stream import StreamRunner
 
 jax.config.update("jax_platform_name", "cpu")
 

@@ -2,10 +2,13 @@
 
 The serving layer's contracts (repro/launch/serve.py):
 
-* churn-free service == synchronous FleetRunner, bitwise, per backend;
+* churn-free, a service collected tick by tick == the same service
+  pipelined by the FleetRunner replay, bitwise, per backend;
 * ANY attach/detach/ragged-arrival schedule == independent StreamRunner
   per sensor, bitwise — including adapted per-stream classifiers and
   ADC noise keyed by persistent sensor uid (property-based);
+* a short tick (L < chunk_size frames per arrival) == the frame-at-a-time
+  reference, and never recompiles; a tick that mixes lengths is refused;
 * detach -> reattach restores a sensor's adapted classifier, gate hold,
   and capture log exactly, through intervening slot tenants;
 * churn never recompiles the fleet step (fixed shapes, mask-only);
@@ -28,12 +31,13 @@ import pytest
 
 from repro.core import encoding, hypersense
 from repro.core.online import AdaptConfig
-from repro.core.sensor_control import CaptureConfig, ControllerConfig
+from repro.core.sensor_control import (CaptureConfig, ControllerConfig,
+                                       simulate_stream)
 from repro.launch.mesh import make_mesh
+from repro.launch.replay import FleetRunner, StreamRunner
 from repro.launch.serve import FleetService
 from repro.sensing import adc as adc_sim
-from repro.sensing.fleet import FleetRunner
-from repro.sensing.stream import StreamRunner
+from repro.sensing.stream import adc_view
 
 
 def make_model(h=6, w=6, stride=3, D=64, t_score=-0.05, t_detection=2):
@@ -64,7 +68,7 @@ def cat(got_sid):
 
 
 # ---------------------------------------------------------------------------
-# churn-free == FleetRunner, bitwise, per backend
+# churn-free: tick-by-tick collect == the pipelined FleetRunner replay
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
@@ -75,6 +79,10 @@ def cat(got_sid):
     {"control": CaptureConfig(hp_bits=12, hp_buffer=2)},
 ], ids=["frozen", "adc-noise", "adapt-shared", "closed-loop"])
 def test_churn_free_bitwise_vs_fleet_runner(backend, kw):
+    """The FleetRunner replay keeps up to max_inflight ticks in flight
+    (donated state, rotating host buffers, HP capture at collect); a
+    service collected after every dispatch never does. Both are bitwise
+    equal: pipelining is invisible to every output, log and capture."""
     model = make_model()
     S, T = 3, 4
     trace = make_trace(S, T * C)
@@ -89,7 +97,10 @@ def test_churn_free_bitwise_vs_fleet_runner(backend, kw):
     got = {}
     for t in range(T):
         svc.dispatch({i: trace[i, t * C:(t + 1) * C] for i in range(S)})
-    drain(svc, got)
+        ch = svc.collect()                # synchronous: nothing in flight
+        for sid, out in ch.outputs.items():
+            got.setdefault(sid, []).append(out)
+    assert svc.collect() is None
     for i in range(S):
         s, f, g = cat(got[i])
         np.testing.assert_array_equal(s, s_ref[i])
@@ -158,12 +169,14 @@ def test_any_churn_schedule_matches_independent_runners(seed, adapt_on):
 
     base_key = jax.random.PRNGKey(0)   # FleetService's default adc_key
     for sid, n in fed.items():
+        # the sensor's ADC captures: noise keyed by its persistent uid
+        captured = adc_view(trace[sid, :n], 5, sigma=0.02,
+                            key=jax.random.fold_in(base_key, svc.uid(sid)))
         ref = StreamRunner(
-            model, CFG,
+            model, CFG, chunk_size=C, backend="jnp", adc_bits=5,
             adapt=(AdaptConfig(mode="pseudo", scope="shared", lr=0.3)
-                   if adapt_on else None),
-            adc_key=jax.random.fold_in(base_key, svc.uid(sid)), **kw)
-        s_ref, f_ref, g_ref = ref.process(trace[sid, :n])
+                   if adapt_on else None))
+        s_ref, f_ref, g_ref = ref.process(captured)
         s, f, g = cat(got[sid])
         np.testing.assert_array_equal(s, s_ref)
         np.testing.assert_array_equal(f, f_ref)
@@ -230,6 +243,96 @@ def test_churn_never_recompiles_the_step():
     svc.flush()
     assert svc.compile_count() == c0, \
         "slot churn must only flip slot_mask bits, never retrace"
+
+
+# ---------------------------------------------------------------------------
+# short ticks: L < chunk_size frames per arrival
+# ---------------------------------------------------------------------------
+
+C_LONG = 8   # a chunk long enough for L in {1, 5, C_LONG - 1}
+
+
+@pytest.mark.parametrize("L", [1, 5, C_LONG - 1])
+def test_short_tick_matches_frame_at_a_time_reference(L):
+    """Ticks of L, C and L frames — a short first tick fixes the frame
+    shape, a short tick follows a full one — give each sensor the
+    frame-at-a-time reference's scores, decisions and gate, with
+    outputs, logs and HP captures of exactly its delivered frames."""
+    n = 2 * L + C_LONG
+    trace = make_trace(2, n)
+    base = make_model()
+    score = jax.jit(lambda f: hypersense.frame_detection_score(
+        hypersense.score_frame(base, f), base.t_detection))
+    # threshold in the widest gap of the middle half of the scores: both
+    # gate outcomes occur, and no score sits near the threshold
+    mid = np.sort([float(score(fr)) for fr in trace.reshape(
+        -1, *trace.shape[2:])])[n // 2:-(n // 2)]
+    gap = int(np.argmax(np.diff(mid)))
+    model = base._replace(t_score=float(mid[gap:gap + 2].mean()))
+    svc = FleetService(model, CFG, n_slots=2, chunk_size=C_LONG,
+                       backend="jnp",
+                       control=CaptureConfig(subsample=False, hp_bits=12))
+    for sid in range(2):
+        svc.attach(sid)
+    got = {}
+    for a, z in [(0, L), (L, L + C_LONG), (L + C_LONG, n)]:
+        svc.dispatch({sid: trace[sid, a:z] for sid in range(2)})
+        (ch,) = svc.flush()
+        for sid, out in ch.outputs.items():
+            assert [o.shape for o in out] == [(z - a,)] * 3
+            assert ch.sampled[sid].shape == (z - a,)
+            got.setdefault(sid, []).append(out)
+    decide = jax.jit(lambda f: hypersense.detect(model, f))
+    for sid in range(2):
+        ref = simulate_stream(lambda f: bool(decide(f)), trace[sid],
+                              np.zeros(n), CFG)
+        s, f, g = cat(got[sid])
+        np.testing.assert_allclose(
+            s, [float(score(fr)) for fr in trace[sid]], rtol=1e-5,
+            atol=1e-5)
+        np.testing.assert_array_equal(f, ref.decisions)
+        np.testing.assert_array_equal(g, ref.gated_on)
+        assert svc.capture_log(sid).sampled.shape == (n,)
+        idx, hp = svc.drain_hp(sid)
+        np.testing.assert_array_equal(idx, np.flatnonzero(ref.gated_on))
+        np.testing.assert_array_equal(
+            hp, np.asarray(adc_sim.quantize(trace[sid][idx], 12)))
+    assert f.any() and not f.all(), "the gate must see both outcomes"
+
+
+def test_tick_mixing_lengths_is_refused():
+    model = make_model()
+    trace = make_trace(2, C)
+    svc = FleetService(model, CFG, n_slots=2, chunk_size=C, backend="jnp")
+    svc.attach(0)
+    svc.attach(1)
+    with pytest.raises(ValueError, match="one length"):
+        svc.dispatch({0: trace[0], 1: trace[1, :C - 1]})
+    # refused before any state moved: the next tick is the streams' first
+    svc.dispatch({0: trace[0], 1: trace[1]})
+    (ch,) = svc.flush()
+    assert ch.seq == 0
+    s_ref, _, g_ref = StreamRunner(model, CFG, chunk_size=C,
+                                   backend="jnp").process(trace[1])
+    np.testing.assert_array_equal(ch.outputs[1][0], s_ref)
+    np.testing.assert_array_equal(ch.outputs[1][2], g_ref)
+
+
+def test_short_tick_never_recompiles_the_step():
+    model = make_model()
+    trace = make_trace(1, 3 * C)
+    svc = FleetService(model, CFG, n_slots=1, chunk_size=C, backend="jnp",
+                       adc_bits=5, control=CaptureConfig(hp_bits=12))
+    svc.attach(0)
+    svc.dispatch({0: trace[0, :C]})       # warmup fixes the trace
+    svc.flush()
+    c0 = svc.compile_count()
+    svc.dispatch({0: trace[0, C:C + 1]})
+    svc.dispatch({0: trace[0, C + 1:2 * C + 1]})
+    svc.dispatch({0: trace[0, 2 * C + 1:]})
+    svc.flush()
+    assert svc.compile_count() == c0, \
+        "the tick length is the traced n_valid operand, never a retrace"
 
 
 # ---------------------------------------------------------------------------

@@ -21,9 +21,9 @@ import pytest
 from repro.core import encoding, hypersense
 from repro.core.sensor_control import (ControllerConfig, SensorController,
                                        simulate_stream)
+from repro.launch.replay import StreamRunner, simulate_stream_batched
 from repro.sensing import adc, synthetic
-from repro.sensing.stream import (StreamRunner, gate_scan,
-                                  simulate_stream_batched)
+from repro.sensing.stream import gate_scan
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -252,23 +252,12 @@ def test_runner_adc_internal_equals_prequantized():
     np.testing.assert_array_equal(g_i, g_p)
     # ...and feeding an already-quantized stream through the ADC runner
     # changes nothing (idempotence end-to-end)
-    internal.reset()
-    s_q, f_q, g_q = internal.process(adc.quantize(frames, 4))
+    again = StreamRunner(model, ControllerConfig(hold_frames=2),
+                         chunk_size=4, adc_bits=4)
+    s_q, f_q, g_q = again.process(adc.quantize(frames, 4))
     np.testing.assert_array_equal(s_q, s_i)
     np.testing.assert_array_equal(f_q, f_i)
     np.testing.assert_array_equal(g_q, g_i)
-
-
-def test_runner_reset():
-    model = make_model(t_detection=0, t_score=-10.0)  # fires on everything
-    frames = jnp.asarray(np.random.RandomState(0).rand(4, 24, 24),
-                         jnp.float32)
-    r = StreamRunner(model, ControllerConfig(hold_frames=3), chunk_size=4)
-    _, fired, _ = r.process(frames)
-    assert fired.all()
-    assert int(np.asarray(r._hold)) == 3
-    r.reset()
-    assert int(np.asarray(r._hold)) == 0
 
 
 def test_runner_rejects_bad_chunk_size():

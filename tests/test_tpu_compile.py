@@ -6,9 +6,10 @@ memory than a kernel may use. These tests compile — without a chip, for
 a described v5e topology — the scoring kernel of one gate chunk at the
 paper's operating point (128x128 frames, 96x96 fragments, stride 8,
 D=5000) in every precision the fleet serves, the float32 kernel on
-frames two band chunks wide, and the hubert-xlarge detector step at its
-published width. Nothing runs; a compile that
-passes is not a chip run.
+frames two band chunks wide, the whole gate step that the serving
+runtime builds for a 16-sensor tick, and the hubert-xlarge detector step
+at its published width. Nothing runs; a compile that passes is not a
+chip run.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and test workers each
@@ -22,9 +23,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import hubert_xlarge, hypersense as hs_config
 from repro.core import encoding
+from repro.core.hypersense import HyperSenseModel
+from repro.core.sensor_control import CaptureConfig, ControllerConfig
+from repro.kernels import ops as kops
 from repro.kernels import sliding_scores as ss
 from repro.kernels import sliding_scores_int as ssi
 from repro.launch import steps
+from repro.launch.serve import FleetService
 
 CFG = hs_config.config()
 CHUNK = 32                      # frames in one gate step of one sensor
@@ -108,6 +113,38 @@ def test_float_kernel_compiles_two_chunks_wide(one_chip):
     my = (H - CFG.fragment) // CFG.stride + 1
     mx = (W - CFG.fragment) // CFG.stride + 1
     assert compiled.out_info.shape == (CHUNK, my, mx)
+
+
+@pytest.mark.parametrize("capture", [False, True])
+def test_served_gate_step_compiles_and_rolls_its_state(one_chip, monkeypatch,
+                                                       capture):
+    """The step the service owns — every slot parked by its mask, the
+    carried state donated — compiled for a 16-sensor tick of paper-size
+    frames, with and without the closed capture loop."""
+    S, H, W = 16, CFG.frame_h, CFG.frame_w
+    B0, b = encoding.make_perm_base_rows(jax.random.PRNGKey(0),
+                                         CFG.fragment, CFG.dim)
+    model = HyperSenseModel(jnp.ones((2, CFG.dim), jnp.float32), B0, b,
+                            CFG.fragment, CFG.fragment, CFG.stride,
+                            t_score=0.0, t_detection=0)
+    svc = FleetService(model, ControllerConfig(10.0, 60.0, 6), n_slots=S,
+                       chunk_size=CHUNK, backend="pallas", adc_bits=4,
+                       control=CaptureConfig(hp_bits=12) if capture else None)
+    step, tiles = svc._ensure_step(W)
+    # the kernels pick interpret mode from the default backend (the CPU
+    # here); the described chip gets the compiled kernel
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    args = (jnp.zeros((S, CHUNK, H, W), jnp.float32), svc._state, B0, b,
+            tiles, jnp.float32(0.0), jnp.int32(CHUNK),
+            jnp.zeros((S, CHUNK), jnp.int32), jnp.ones((S,), bool))
+    compiled = step.func.lower(*_on(one_chip, args),
+                               **step.keywords).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    scores, fired, gated, sampled, _ = compiled.out_info
+    assert scores.shape == fired.shape == gated.shape == sampled.shape \
+        == (S, CHUNK)
+    # the donated state is aliased into the new state, not reallocated
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
 
 
 def test_detector_step_compiles_at_full_width(one_chip):

@@ -103,3 +103,18 @@ def test_large_w_kernel_matches_oracle():
                                                stride=stride)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
+
+
+def test_d16384_fits_only_when_sharded_over_hyperdim():
+    """The hyperdim axis's reason to exist, certified by the byte model:
+    at D=16384 one device would hold the whole hypervector per grid step
+    (block_d = D) and busts the VMEM budget, while an 8-way D-shard's
+    2048-wide tile fits (64x64 frames, 8x8 fragments, stride 8, 8-bit
+    codes)."""
+    cfg = dict(adc_bits=8, H=64, W=64, h=8, w=8, stride=8)
+    single = k_int.int_datapath_bounds(**cfg, block_d=16384)
+    shard = k_int.int_datapath_bounds(**cfg, block_d=16384 // 8)
+    assert not single["fits"]
+    assert single["vmem_bytes"] > single["vmem_limit_bytes"]
+    assert shard["fits"]
+    assert shard["vmem_bytes"] <= shard["vmem_limit_bytes"]
